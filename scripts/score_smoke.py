@@ -2,7 +2,7 @@
 """Scoring smoke: the streaming inference engine end-to-end on CPU
 (ISSUE 3 satellite, next to ``chaos_smoke``/``obs_smoke``).
 
-Two CHILD scoring processes share one ``SPARKDL_COMPILE_CACHE`` dir. Each
+Two CHILD scoring processes share one ``JAX_COMPILATION_CACHE_DIR``. Each
 scores a synthetic image frame through ``XlaImageTransformer`` — parallel
 host decode, one continuous cross-partition device stream, overlap-worker
 Arrow encode — and prints examples/s plus the per-stage time breakdown
@@ -18,9 +18,9 @@ Run: ``JAX_PLATFORMS=cpu python scripts/score_smoke.py``
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -78,9 +78,13 @@ def child() -> int:
 
 
 def main() -> int:
-    cache_dir = tempfile.mkdtemp(prefix="sparkdl-score-cache-")
+    # A fixed sub-directory of the checkout's cache, emptied first: the
+    # first child must MISS and the second HIT, and a cache path made from
+    # a temporary name could never be found again by anyone.
+    cache_dir = os.path.join(_REPO, ".jax_cache", "score_smoke")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     env = dict(os.environ)
-    env["SPARKDL_COMPILE_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
 
     def run_child() -> dict:
         proc = subprocess.run(

@@ -236,15 +236,13 @@ def _dense_slot_attention(q, k_all, v_all, qpos, pads, cfg, dtype):
 # copy of the cache ever exists in HBM.
 # ---------------------------------------------------------------------------
 
-KV_QUANT_DTYPES: dict = {"int8": (jnp.int8, 127.0)}
-if hasattr(jnp, "float8_e4m3fn"):
-    KV_QUANT_DTYPES["fp8"] = (jnp.float8_e4m3fn, 448.0)
+KV_QUANT_DTYPES: dict = {"int8": (jnp.int8, 127.0),
+                         "fp8": (jnp.float8_e4m3fn, 448.0)}
 
 
 def kv_quant_spec(name: str):
     """(storage dtype, qmax) for a KV quant mode name — raises with the
-    available modes on a miss (e.g. ``fp8`` on a jax without
-    ``float8_e4m3fn``), never silently falls back."""
+    available modes on a miss, never silently falls back."""
     try:
         return KV_QUANT_DTYPES[name]
     except KeyError:
@@ -604,14 +602,18 @@ class LlamaAttention(nn.Module):
                 if flash is not None:
                     kf = jnp.repeat(k, rep, axis=1) if rep != 1 else k
                     vf = jnp.repeat(v, rep, axis=1) if rep != 1 else v
-                    # Shape constraints (e.g. a ring attn_fn whose sp
-                    # axis doesn't divide S) surface at TRACE time as
-                    # ValueError/TypeError — fall back to the dense path
-                    # instead of turning a previously working generate()
-                    # into a crash. Other exception types (a genuinely
-                    # broken attn_fn) propagate: silently densifying
-                    # those would OOM the long-prompt case the fn was
-                    # configured to avoid.
+                    # Shape constraints of a sequence-parallel attn_fn
+                    # (e.g. a ring whose sp axis doesn't divide S)
+                    # surface at TRACE time as ValueError/TypeError —
+                    # fall back to the dense path instead of turning a
+                    # previously working generate() into a crash. The
+                    # Pallas kernels are exempt: an error from them is
+                    # a lowering failure, and densifying it would make
+                    # a run without the kernel look like one with it.
+                    # Other exception types (a genuinely broken
+                    # attn_fn) propagate too.
+                    from ..ops.flash_attention import (
+                        adaptive_attention, flash_attention)
                     try:
                         if valid_extra is None:
                             o = flash(q, kf, vf, causal=True)
@@ -622,6 +624,8 @@ class LlamaAttention(nn.Module):
                             o = flash(q, kf, vf, causal=True,
                                       kv_mask=kv_mask)
                     except (TypeError, ValueError) as e:
+                        if flash in (adaptive_attention, flash_attention):
+                            raise
                         _warn_prefill_fallback(flash, e)
                         o = None
                 if o is None and S == 1:

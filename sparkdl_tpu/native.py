@@ -5,14 +5,16 @@ bridge into TF C++ (SURVEY.md §2.3); here the in-tree native component is
 ``libsparkdl_native.so``: multithreaded resize + channel-reorder + uint8→f32
 NHWC packing, producing the host batch that ``jax.device_put`` ships to HBM.
 
-``pack_images``/``pack_batch`` transparently fall back to numpy/PIL when the
-shared library hasn't been built (``ensure_built`` compiles it with g++ on
-first use; pybind11 is unavailable in this image, hence the C ABI).
+``pack_images``/``pack_batch`` fall back to numpy/PIL with a warning when the
+shared library cannot be built (``ensure_built`` compiles it with g++ on
+first use; pybind11 is unavailable in this image, hence the C ABI);
+``require`` raises instead.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,75 +28,95 @@ _log = logging.getLogger(__name__)
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libsparkdl_native.so")
+_SOURCES = ("packing.cpp", "Makefile")
 
 _lock = threading.RLock()  # reentrant: _load holds it while calling ensure_built
 _lib = None
-_build_failed = False
+_failure: str | None = None  # why the library is unusable (build or ABI)
+
+
+def _source_digest() -> str | None:
+    """sha256 over the sources the library is built from, or None when
+    the tree ships no sources (a prebuilt-only install)."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        try:
+            with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+        except FileNotFoundError:
+            return None
+    return h.hexdigest()
 
 
 def ensure_built() -> bool:
     """Compile the .so if missing/stale. Returns availability.
 
+    Freshness is keyed on the CONTENT of the sources (their digest is
+    written next to the library after each build), not on mtimes: a copied
+    or archived tree carries arbitrary mtimes, and a library left over from
+    other sources must never be trusted.
+
     Thread-safe: the build runs under ``_lock`` so concurrent first-use from
     multiple threads cannot race two ``make`` processes, and success is only
     reported after re-checking that the .so actually exists (make exiting 0
     with no artifact — e.g. a stale Makefile target — must not be trusted)."""
-    global _build_failed
-    src = os.path.join(_NATIVE_DIR, "packing.cpp")
-    if not os.path.exists(src):
+    global _failure
+    digest = _source_digest()
+    if digest is None:
         return os.path.exists(_SO_PATH)
+    stamp = _SO_PATH + ".src"
 
     def fresh() -> bool:
-        return (os.path.exists(_SO_PATH)
-                and os.path.getmtime(_SO_PATH) >= os.path.getmtime(src))
+        try:
+            with open(stamp) as f:
+                return os.path.exists(_SO_PATH) and f.read() == digest
+        except FileNotFoundError:
+            return False
 
     if fresh():
         return True
     with _lock:
         if fresh():          # another thread built it while we waited
             return True
-        if _build_failed:
+        if _failure is not None:
             return False
         try:
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+            subprocess.run(["make", "-B", "-C", _NATIVE_DIR], check=True,
                            capture_output=True, timeout=120)
-            if not fresh():
+            if not os.path.exists(_SO_PATH):
                 raise OSError("make succeeded but produced no "
                               f"{os.path.basename(_SO_PATH)}")
+            with open(stamp, "w") as f:
+                f.write(digest)
             return True
         except (subprocess.SubprocessError, OSError) as e:
-            _build_failed = True
+            detail = getattr(e, "stderr", b"") or b""
+            _failure = f"build failed ({e}) {detail[-500:]!r}"
             # Loud once: the PIL fallback resizes through uint8, so resized
             # batches differ (<1 level per value) from native-built hosts.
             _log.warning(
-                "sparkdl_tpu native packer build failed (%s); using the "
-                "pure-python fallback — resized image batches will differ "
-                "slightly from native-enabled hosts", e)
+                "sparkdl_tpu native packer %s; using the pure-python "
+                "fallback — resized image batches will differ slightly "
+                "from native-enabled hosts", _failure)
             return False
 
 
-_lib_failed = False  # loaded but unusable (ABI mismatch) — don't re-dlopen
-
-
 def _load():
-    global _lib, _lib_failed
+    global _lib, _failure
     with _lock:
         if _lib is not None:
             return _lib
-        if _lib_failed:
-            return None
-        if not ensure_built():
+        if _failure is not None or not ensure_built():
             return None
         lib = ctypes.CDLL(_SO_PATH)
         lib.sdl_abi_version.restype = ctypes.c_int
         if lib.sdl_abi_version() != 2:
             # Cache the mismatch: without this every pack call would redo
             # dlopen+probe on the hot path, silently, forever.
-            _lib_failed = True
-            _log.warning(
-                "libsparkdl_native.so has ABI %d (want 2) — prebuilt "
-                "library is stale; using the pure-python fallback",
-                lib.sdl_abi_version())
+            _failure = (f"libsparkdl_native.so has ABI "
+                        f"{lib.sdl_abi_version()} (want 2)")
+            _log.warning("%s — prebuilt library is stale; using the "
+                         "pure-python fallback", _failure)
             return None
         _common = [
             ctypes.POINTER(ctypes.c_void_p),           # srcs
@@ -128,6 +150,15 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def require() -> None:
+    """Build-and-load the packer or raise with the reason. For callers to
+    whom the pure-python fallback would be a silent change of path
+    (``chip_smoke.py``): everywhere else a missing toolchain degrades to
+    PIL with a warning."""
+    if _load() is None:
+        raise RuntimeError(f"native packer unavailable: {_failure}")
 
 
 def pack_images(buffers: Sequence, heights: Sequence[int],

@@ -71,9 +71,9 @@ def _paged_decode_kernel(tbl_ref, cur_ref, pad_ref, q_ref, k_ref, v_ref,
     the last query and are sliced off outside).
 
     ``quant`` (ISSUE 18): K/V are int8/fp8 CODES and ``rest`` leads
-    with a (1, 2) SMEM ref holding this block's (K, V) scales for this
-    kv head. Dequant folds AFTER each contraction — ``(q·kᵀ)·s_k`` and
-    ``(p·v)·s_v``, exact because the scale is constant over the block —
+    with a (1, 1, 2) SMEM ref holding this block's (K, V) scales for
+    this kv head. Dequant folds AFTER each contraction — ``(q·kᵀ)·s_k``
+    and ``(p·v)·s_v``, exact because the scale is constant over the block —
     so the kernel reads quantized bytes from HBM and no dequantized
     block ever exists outside VMEM."""
     if quant:
@@ -101,7 +101,7 @@ def _paged_decode_kernel(tbl_ref, cur_ref, pad_ref, q_ref, k_ref, v_ref,
         v = v_ref[0, 0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (R, bs)
         if quant:
-            s = s * scl_ref[0, 0]
+            s = s * scl_ref[0, 0, 0]
         rows = q.shape[0]
         col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         qi = jnp.minimum(
@@ -120,7 +120,7 @@ def _paged_decode_kernel(tbl_ref, cur_ref, pad_ref, q_ref, k_ref, v_ref,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
         if quant:
-            pv = pv * scl_ref[0, 1]
+            pv = pv * scl_ref[0, 0, 1]
         acc_ref[:] = acc_ref[:] * alpha[:, None] + pv
         m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
@@ -179,7 +179,7 @@ def paged_flash_decode(q, k_pool, v_pool, tables, slot_cur, pad_lens=None,
     ``kv_scales`` (ISSUE 18): the quantized pool's
     ``[pool_blocks, Hkv, 2]`` f32 scale plane — required exactly when
     the pool leaves hold int8/fp8 codes. Each grid step's (K, V) scale
-    pair rides a (1, 2) SMEM block whose index map chases the table
+    pair rides a (1, 1, 2) SMEM block whose index map chases the table
     like the KV specs, and dequant folds after the two dots in-kernel:
     the HBM read stays quantized end to end.
 
@@ -246,12 +246,18 @@ def paged_flash_decode(q, k_pool, v_pool, tables, slot_cur, pad_lens=None,
     ]
     operands = [tbl, cur_arr, pad_arr, q3, k_pool, v_pool]
     if quant:
-        # Pre-gather the scale pairs into grid order — [B·Hkv·MB, 2]
-        # f32, a few KB riding SMEM two floats per grid step (scalars
-        # stay 2-D there). The index map mirrors kv_index's dead-step
-        # clamp so repeat fetches are skipped the same way.
+        # Pre-gather the scale pairs into grid order — [B·Hkv·MB, 1, 2]
+        # f32, two floats riding SMEM per grid step. The pair is 3-D so
+        # that the (1, 1, 2) block's trailing dims EQUAL the array's:
+        # the TPU lowering refuses a (1, 2) block over [N, 2] (sublane
+        # 1 is neither an 8-multiple nor N) — found on the chip, the
+        # interpreter takes either (the lse layout in flash_attention
+        # is the same rule). All of it as a scalar-prefetch operand
+        # lowers too, but overflows the 1 MB SMEM at 32 slots x 8k
+        # context. The index map mirrors kv_index's dead-step clamp so
+        # repeat fetches are skipped the same way.
         scl = kv_scales[tables]                  # [B, MB, Hkv, 2]
-        scl = scl.transpose(0, 2, 1, 3).reshape(b * h_kv * mb, 2)
+        scl = scl.transpose(0, 2, 1, 3).reshape(b * h_kv * mb, 1, 2)
         scl = scl.astype(jnp.float32)
 
         def scl_index(bh, j, tbl_ref, cur_ref, pad_ref):
@@ -259,9 +265,9 @@ def paged_flash_decode(q, k_pool, v_pool, tables, slot_cur, pad_lens=None,
             last_live = jnp.maximum(
                 pl.cdiv(cur_ref[slot] + s_q, bs) - 1, 0)
             jc = jnp.minimum(j, last_live)
-            return (bh * mb + jc, 0)
+            return (bh * mb + jc, 0, 0)
 
-        in_specs.append(pl.BlockSpec((1, 2), scl_index,
+        in_specs.append(pl.BlockSpec((1, 1, 2), scl_index,
                                      memory_space=pltpu.SMEM))
         operands.append(scl)
 

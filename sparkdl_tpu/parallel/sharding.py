@@ -242,8 +242,6 @@ def head_sharded_kernel(fn, mesh: Mesh, axis: str = "tp"):
     the K operand's is a quantized pool's ``[pool, Hkv, 2]`` scale
     plane (ISSUE 18) — it shards with its heads like the codes it
     scales."""
-    from jax.experimental.shard_map import shard_map
-
     spec_h = P(None, axis, None, None)
 
     def rest_spec(r, k):
@@ -253,11 +251,11 @@ def head_sharded_kernel(fn, mesh: Mesh, axis: str = "tp"):
 
     def wrapped(q, k, v, *rest, **kw):
         inner = functools.partial(fn, **kw) if kw else fn
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(spec_h, spec_h, spec_h)
             + tuple(rest_spec(r, k) for r in rest),
-            out_specs=spec_h, check_rep=False)(q, k, v, *rest)
+            out_specs=spec_h, check_vma=False)(q, k, v, *rest)
 
     wrapped.__name__ = f"head_sharded_{getattr(fn, '__name__', 'kernel')}"
     wrapped.__wrapped__ = fn

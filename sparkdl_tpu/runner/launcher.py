@@ -59,6 +59,7 @@ CLI: ``python -m sparkdl_tpu.runner.launcher --np 2 [--restarts R]
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import logging
 import os
@@ -300,6 +301,16 @@ def host_device_flags(flags: str, n: int) -> str:
     return (flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
+def _first_platform(merged_env: dict) -> str:
+    """First entry of the (possibly comma-separated fallback)
+    ``JAX_PLATFORMS`` list — it decides the regime: "tpu,cpu" initializes
+    the TPU backend, so it is the accelerator regime (a substring test
+    would route it to virtual devices and leave every rank meshing over
+    the same first chips); "" / unset is jax's own choice."""
+    return (merged_env.get("JAX_PLATFORMS") or "").lower() \
+        .split(",")[0].strip()
+
+
 def tp_placement_env(rank: int, tp: int, merged_env: dict) -> dict:
     """Topology-aware per-rank device placement for a gang hosting
     tensor-parallel serving engines (ISSUE 14): each rank must end up
@@ -326,13 +337,7 @@ def tp_placement_env(rank: int, tp: int, merged_env: dict) -> dict:
     if tp <= 1:
         return {}
     add: dict = {}
-    # First entry of the (possibly comma-separated fallback) platform
-    # list decides the regime: JAX_PLATFORMS="tpu,cpu" initializes the
-    # TPU backend, so it must take the chip-visibility branch — a
-    # substring test would route it to virtual devices and leave every
-    # rank meshing over the same first chips.
-    platform = (merged_env.get("JAX_PLATFORMS") or "").lower() \
-        .split(",")[0].strip()
+    platform = _first_platform(merged_env)
     explicit_off = TP_OFFSET_ENV in merged_env
     if platform == "cpu":
         flags = merged_env.get("XLA_FLAGS", "")
@@ -368,6 +373,44 @@ def _tp_degree(env: dict) -> int:
     return tp
 
 
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from the device nodes libtpu
+    opens (``/dev/accel<n>``, or ``/dev/vfio/<n>`` on newer hosts) —
+    the supervisor may not ask jax (that would take the chips its own
+    workers need)."""
+    return len(glob.glob("/dev/accel[0-9]*")) \
+        or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_shared_chips(np: int, env: dict | None) -> None:
+    """A chip belongs to one process. A gang of ``np`` ranks on a TPU host
+    is refused BEFORE spawn unless every rank gets its own chips: without
+    that, each rank opens every chip, all but one die at backend start-up
+    (on the v5e: "ABORTED: ... libtpu multi-process lockfile"; elsewhere
+    "device or resource busy"), and ``supervise`` spends its restart
+    budget on an error that recurs deterministically (``failures``
+    classifies both strings retryable — rightly, for a chip a dying
+    predecessor still holds). Only the tp-serving placement (``tp_placement_env``) hands
+    out disjoint chips today; a plain gang has no such scheme."""
+    merged = {**os.environ, **(env or {})}
+    chips = local_tpu_chips()
+    if np <= 1 or _first_platform(merged) == "cpu" or not chips:
+        return
+    tp = _tp_degree(env or {})
+    if tp > 1 and "TPU_VISIBLE_CHIPS" not in merged and np * tp <= chips:
+        return
+    raise ValueError(
+        f"refusing to launch {np} ranks on a host with {chips} TPU "
+        f"chip(s): the ranks would open the same chips and all but one "
+        f"would die at backend start-up (a chip belongs to one "
+        f"process). One process drives "
+        f"every local chip — use the single-controller form "
+        f"XlaRunner(np=-1) (under supervise(np=1) to keep restarts), "
+        f"or set JAX_PLATFORMS=cpu for a CPU gang"
+        + (f"; a tp={tp} serving gang needs np*tp <= {chips} chips and "
+           f"no caller-pinned TPU_VISIBLE_CHIPS" if tp > 1 else ""))
+
+
 def _spawn_gang(script: str, np: int, args, env, coordinator: str | None,
                 capture: bool, heartbeat_dir: str | None = None,
                 event_dir: str | None = None):
@@ -386,16 +429,6 @@ def _spawn_gang(script: str, np: int, args, env, coordinator: str | None,
             penv["SPARKDL_HEARTBEAT_DIR"] = heartbeat_dir
         if event_dir:
             penv["SPARKDL_EVENT_DIR"] = event_dir
-        # Persistent XLA compilation cache: a supervised gang restart pays
-        # the 20-40s compile once, ever — relaunched workers load the
-        # executable from disk. SPARKDL_COMPILE_CACHE flows to workers
-        # that import sparkdl_tpu (core.runtime arms it + hit/miss
-        # telemetry); the raw JAX var is ALSO set so jax-only worker
-        # scripts get the cache without the framework import. Never
-        # overrides a caller's explicit JAX_COMPILATION_CACHE_DIR.
-        cache_dir = penv.get("SPARKDL_COMPILE_CACHE")
-        if cache_dir and not penv.get("JAX_COMPILATION_CACHE_DIR"):
-            penv["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         # Tensor-parallel serving gang (ISSUE 14): give this rank its
         # disjoint tp-device group (virtual-device flag on CPU, chip
         # visibility / in-process offset on real accelerators). Gated
@@ -765,6 +798,7 @@ def launch(script: str, np: int = 2, args: list[str] | None = None,
     """
     if np < 1:
         raise ValueError(f"np must be >= 1, got {np}")
+    _refuse_shared_chips(np, env)
     adopted_dir = None
     if event_dir is None:
         # Same isolation rule as supervise(): an env-var-sourced dir may
@@ -849,10 +883,10 @@ def supervise(script: str, np: int = 2, args: list[str] | None = None,
     plan without a ``state_dir`` gets a temp one so ``once`` faults stay
     once across relaunches.
 
-    With ``SPARKDL_COMPILE_CACHE`` set (supervisor env or ``env=``), every
-    rank gets JAX's persistent compilation cache pointed at it
-    (``JAX_COMPILATION_CACHE_DIR``), so restart N+1 loads its compiled
-    programs from disk instead of re-paying the 20-40s XLA compile that
+    Every rank that imports ``sparkdl_tpu`` has JAX's persistent
+    compilation cache armed (``core.runtime``: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``<checkout>/.jax_cache``), so restart N+1 loads its
+    compiled programs from disk instead of re-paying the XLA compile that
     would otherwise dominate each recovery.
 
     The flight recorder is armed in every supervised rank: ``event_dir``
@@ -904,6 +938,7 @@ def supervise(script: str, np: int = 2, args: list[str] | None = None,
     """
     if np < 1:
         raise ValueError(f"np must be >= 1, got {np}")
+    _refuse_shared_chips(np, env)
     env = dict(env or {})
     tmp_dirs = []  # created-by-us scratch, removed on success only
     if plan is not None:

@@ -190,9 +190,9 @@ class LlamaSlotBackend:
         from ..ops import flash_decode as fd
         reason = fd.support_reason(self.max_len)
         if reason is not None:
-            log.info("flash-decode kernel stands down for this config "
-                     "(%s); decode steps use dense cache attention",
-                     reason)
+            log.warning("flash-decode kernel stands down for this "
+                        "config (%s); decode steps use dense cache "
+                        "attention", reason)
         self.cache = self._make_cache(self.model)
         self._tokens = np.zeros(self.num_slots, np.int32)
         # Idle slots park at fill index 0 — their write frontier: the
@@ -579,9 +579,9 @@ class PagedLlamaSlotBackend(LlamaSlotBackend):
         from ..ops import paged_flash_decode as pfd
         reason = pfd.support_reason(self.block_size, kv_dtype=kv_dtype)
         if reason is not None:
-            log.info("paged flash-decode kernel stands down for this "
-                     "config (%s); decode steps use the dense gather "
-                     "view", reason)
+            log.warning("paged flash-decode kernel stands down for "
+                        "this config (%s); decode steps use the dense "
+                        "gather view", reason)
         if pool_blocks is None and kv_pool_mb is not None:
             # PER-DEVICE budget → block count: on the single-device
             # backend a block's device cost is its full K/V bytes; the

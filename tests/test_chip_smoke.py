@@ -1,0 +1,108 @@
+"""chip_smoke.py on the CPU: the command refuses, the phases run tiny.
+
+The no-argument command line is the chip contract (a TPU or a non-zero
+exit); what tier-1 can hold is (a) that it refuses the CPU quickly and
+prints no result, (b) that every phase function runs the real entry points
+at a tiny size on the virtual CPU mesh — kernels interpreted by explicit
+argument, never by auto-select — and (c) that a failing phase is not caught
+and reported as success.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+from sparkdl_tpu.models.llama import LlamaConfig
+
+_SMOKE = chip_smoke.__file__
+
+
+def test_command_refuses_cpu_and_prints_no_result():
+    proc = subprocess.run([sys.executable, _SMOKE], capture_output=True,
+                          text=True, timeout=120,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr and "nothing was run" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_trainer_phase_tiny():
+    rec = chip_smoke.phase_trainer(model_name="ResNet18", image_size=32,
+                                   per_chip=2, steps=3, platform="cpu")
+    assert rec["steps"] == 3 and rec["chips"] == 8
+    assert rec["param_devices"] == rec["batch_shard_devices"] == 8
+
+
+def test_scorer_phase_tiny():
+    rec = chip_smoke.phase_scorer(
+        model_name="ResNet18", rows=10, batch=4,
+        sizes=((224, 224), (40, 60), (300, 200)))
+    assert rec["rows_out"] == 10 and rec["feature_dim"] == 512
+    assert rec["native_packer"] is True
+
+
+def test_kernel_phase_tiny_interpreted():
+    rec = chip_smoke.phase_kernels(
+        interpret=True, seq=256, slots=3, heads=4, kv_heads=2,
+        head_dim=32, max_len=128, block_size=8, verify_window=3)
+    assert rec["interpret"] is True
+    assert {"flash_attention", "flash_decode", "paged_flash_decode_bf16_S1",
+            "paged_flash_decode_int8_S3"} <= set(rec)
+
+
+def test_server_phase_tiny():
+    rec = chip_smoke.phase_server(
+        cfg=LlamaConfig.tiny(), num_slots=3, max_len=128,
+        prompt_lens=(5, 20, 40, 70), new_tokens=(6, 9), block_size=8,
+        spec_k=2, tp_degrees=(2,), expect_kernel=False, platform="cpu")
+    assert rec["unpaged"]["paged"] is False and rec["paged"]["paged"]
+    tp = rec["paged_tp2"]
+    assert tp["tp"] == 2
+    assert tp["kv_pool_device_bytes"] * 2 == tp["kv_pool_global_bytes"]
+    # off the chip the kernels stand down and the record says so
+    assert not any(rec["paged"]["mosaic_in_lowered"].values())
+
+
+def test_a_failing_phase_is_not_reported_as_success(monkeypatch, capsys):
+    """Past the platform gate (a stand-in device), with the second phase
+    made to fail: main() must raise — exit code non-zero, no result
+    line — and must not run the phases after it."""
+    import jax
+
+    class FakeTpu:
+        platform, device_kind = "tpu", "fake"
+
+    ran = []
+
+    def boom():
+        raise RuntimeError("scorer broke")
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
+    monkeypatch.setattr(chip_smoke, "phase_trainer",
+                        lambda: ran.append("trainer") or {})
+    monkeypatch.setattr(chip_smoke, "phase_scorer", boom)
+    monkeypatch.setattr(chip_smoke, "phase_kernels",
+                        lambda **kw: ran.append("kernels") or {})
+    monkeypatch.setattr(chip_smoke, "phase_server",
+                        lambda **kw: ran.append("server") or {})
+    with pytest.raises(RuntimeError, match="scorer broke"):
+        chip_smoke.main([])
+    assert ran == ["trainer"]
+    assert capsys.readouterr().out.strip() == ""
+
+    # the same wiring with every phase passing prints the report, then
+    # as the LAST line the result with exactly the contract's keys
+    monkeypatch.setattr(chip_smoke, "phase_scorer", lambda: {})
+    assert chip_smoke.main([]) == 0
+    report, last = capsys.readouterr().out.strip().splitlines()[-2:]
+    assert json.loads(last) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "fake", "count": 1}}
+    report = json.loads(report)["report"]
+    assert set(report) == {"server_layers", "compile_cache", "phases"}
+    assert set(report["phases"]) == {"trainer", "scorer", "kernels",
+                                     "server"}

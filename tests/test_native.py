@@ -169,7 +169,7 @@ def test_ensure_built_thread_safe_single_make(monkeypatch, tmp_path):
         return R()
 
     monkeypatch.setattr(nat, "_SO_PATH", str(tmp_path / "never_built.so"))
-    monkeypatch.setattr(nat, "_build_failed", False)
+    monkeypatch.setattr(nat, "_failure", None)
     monkeypatch.setattr(nat.subprocess, "run", fake_run)
 
     results = []
@@ -184,6 +184,6 @@ def test_ensure_built_thread_safe_single_make(monkeypatch, tmp_path):
     for t in threads:
         t.join()
     # make "succeeded" but produced no .so -> failure, and only ONE make ran
-    # (the rest short-circuited on _build_failed under the lock).
+    # (the rest short-circuited on _failure under the lock).
     assert results == [False] * 4
     assert len(calls) == 1

@@ -21,6 +21,12 @@ import pytest
 
 from sparkdl_tpu.ops import paged_flash_decode as pfd
 from sparkdl_tpu.ops.flash_decode import flash_decode
+from sparkdl_tpu.utils.platform import is_tpu_backend
+
+#: the tp kernel gate's DEFAULTS are platform policy (auto = TPU only);
+#: these tests pin the CPU side of it on the virtual 8-device mesh
+cpu_mesh_only = pytest.mark.skipif(
+    is_tpu_backend(), reason="pins the CPU-mesh defaults of the tp gate")
 
 
 def _pool_and_tables(seed=0, *, b=4, h_kv=2, bs=8, mb=4, pool=13, d=16):
@@ -151,6 +157,7 @@ class TestResolverAndKnob:
         assert pfd.paged_decode_fn_for(flash_attention) is None
         assert pfd.kernel_mode() == "off"
 
+    @cpu_mesh_only
     def test_mesh_routes_through_shard_map_gate(self, monkeypatch):
         from sparkdl_tpu.serving.backend import tp_mesh
         mesh = tp_mesh(2)
@@ -176,6 +183,7 @@ class TestResolverAndKnob:
         fn = pfd.paged_decode_fn_for(None, mesh)
         assert fn is not None and fn.__wrapped__ is pfd.paged_flash_decode
 
+    @cpu_mesh_only
     def test_dense_decode_fn_for_mesh_gating(self, monkeypatch):
         from sparkdl_tpu.ops import flash_decode as fd
         from sparkdl_tpu.serving.backend import tp_mesh
@@ -190,6 +198,7 @@ class TestResolverAndKnob:
         assert fd.decode_fn_for(None, mesh) is None
 
 
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs two devices")
 def test_head_sharded_kernel_matches_unsharded():
     """shard_map over the tp head axis must be a pure layout change:
     per-head attention needs no collective, so the sharded dispatch is
@@ -329,3 +338,29 @@ class TestKernelOnEngine:
             "serve_decode_step") == sig_d
         assert GLOBAL_COMPILE_CACHE.signatures(
             "serve_verify_step") == sig_v
+
+
+@pytest.mark.skipif(
+    not is_tpu_backend(),
+    reason="compiled-mode kernel needs a real TPU "
+           "(run with SPARKDL_TEST_PLATFORM=tpu)")
+@pytest.mark.parametrize("kv_dtype", [None, "int8", "fp8"])
+@pytest.mark.parametrize("block_size", [8, 16, 32])
+def test_compiled_paged_flash_decode_on_tpu(block_size, kv_dtype):
+    """COMPILED (non-interpret) kernel on the chip, at the shapes the
+    engine serves (head_dim 128, GQA 16/8, bf16 queries, 8 slots of
+    2048): the decode step and the S=5 verify window against the dense
+    reference, over a float pool and over int8/fp8 codes + scale plane,
+    at every block size ``support_reason()`` admits — what it refuses
+    is skipped by name, so this test is the record of what lowers."""
+    import chip_smoke
+
+    reason = pfd.support_reason(block_size, kv_dtype)
+    if reason is not None:
+        pytest.skip(reason)
+    checks = chip_smoke.check_paged_flash_decode(
+        np.random.RandomState(block_size), interpret=False, slots=8,
+        heads=16, kv_heads=8, head_dim=128, max_len=2048,
+        block_size=block_size, kv_dtype=kv_dtype, windows=(1, 5))
+    for name, rec in checks.items():
+        assert rec["max_err"] <= chip_smoke.KERNEL_ATOL, (name, rec)

@@ -592,7 +592,7 @@ class TestReportClis:
         """Satellite: run_engine_leg's record carries the SLO
         compliance numbers, the slowest-trace phase breakdown, and the
         attribution residual — the fields _serve_headline forwards into
-        BOTH the healthy and backend_unavailable bench records."""
+        the bench record."""
         import importlib.util
         spec = importlib.util.spec_from_file_location(
             "serve_bench",
@@ -626,8 +626,7 @@ class TestReportClis:
     def test_serve_bench_survivability_leg_and_gating(self):
         """ISSUE 19 satellite: the survivability leg reports one
         injected failover's recovery latency + the exactly-once
-        token-identity float, _serve_headline forwards both (riding
-        healthy AND backend_unavailable records), and bench_trend's
+        token-identity float, _serve_headline forwards both, and bench_trend's
         name-shape rules gate them in the right direction."""
         import importlib.util
         spec = importlib.util.spec_from_file_location(

@@ -146,6 +146,33 @@ class TestTpPlacement:
         env = {"TPU_VISIBLE_CHIPS": "0,1", "SPARKDL_TP_DEVICE_OFFSET": "6"}
         assert tp_placement_env(1, 2, env) == {}
 
+    def test_plain_gang_on_a_chip_host_is_refused_before_spawn(
+            self, monkeypatch, tmp_path):
+        """A chip belongs to one process: np>1 plain ranks on a host that
+        exposes TPU device nodes would all open the same chips, all but
+        one dying on a 'busy' error that the taxonomy calls retryable —
+        launch()/supervise() refuse BEFORE spawning anything, naming the
+        single-controller form. CPU gangs and a tp gang that fits its
+        disjoint chip groups pass."""
+        from sparkdl_tpu.runner import launcher
+        marker = tmp_path / "spawned"
+        script = tmp_path / "w.py"
+        script.write_text(f"open({str(marker)!r}, 'w').close()\n")
+        monkeypatch.setattr(launcher, "local_tpu_chips", lambda: 4)
+        for fn in (launcher.launch, launcher.supervise):
+            with pytest.raises(ValueError, match=r"XlaRunner\(np=-1\)"):
+                fn(str(script), np=2, env={"JAX_PLATFORMS": "tpu,cpu"})
+        assert not marker.exists()
+        refuse = launcher._refuse_shared_chips
+        refuse(1, {"JAX_PLATFORMS": ""})            # one rank owns the host
+        refuse(2, {"JAX_PLATFORMS": "cpu"})         # CPU gang
+        refuse(2, {"JAX_PLATFORMS": "", "SPARKDL_SERVE_TP": "2"})
+        with pytest.raises(ValueError, match="np\\*tp <= 4"):
+            refuse(4, {"JAX_PLATFORMS": "", "SPARKDL_SERVE_TP": "2"})
+        # no TPU device nodes (this sandbox): nothing to refuse
+        monkeypatch.setattr(launcher, "local_tpu_chips", lambda: 0)
+        refuse(2, {"JAX_PLATFORMS": ""})
+
     def test_tp_degree_parse(self):
         assert _tp_degree({"SPARKDL_SERVE_TP": "4"}) == 4
         assert _tp_degree({}) == 0
