@@ -20,6 +20,11 @@ utilization breakdown:
 - **idle_s** — wall seconds where *no* stage was active (gaps the spans
   do not explain: GC, scheduling, untraced work).
 
+Beside the host's spans, :func:`device_time_by_scope` reads a profile's
+device plane: seconds per ``jax.named_scope`` / flax module prefix and per
+phase (forward, backward, ``optimizer_update``), so that ``fusion.106`` has
+a name. ``python -m sparkdl_tpu.runner.analysis --profile DIR`` prints it.
+
 Attribution names the **dominant stage** (highest busy fraction) and the
 Amdahl-style projection: with the dominant stage wall-busy fraction f,
 perfecting everything else yields at most **1/f** speedup ("decode pool
@@ -30,6 +35,7 @@ CLI over it.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
@@ -37,7 +43,8 @@ from typing import Iterable
 
 __all__ = ["intervals_from_events", "read_span_stream", "load_event_dir",
            "union_seconds", "analyze", "utilization_from_events",
-           "format_report", "request_summary", "format_request_summary"]
+           "format_report", "request_summary", "format_request_summary",
+           "scope_seconds", "device_time_by_scope", "format_scope_report"]
 
 _EVENT_FILE_RE = re.compile(r"events_rank(\d+)\.jsonl$")
 # Span names that are not pipeline *stages*: whole-run envelopes whose
@@ -404,3 +411,219 @@ def format_report(rep: dict) -> str:
         f"{dom}'s exclusive time yields <= "
         f"{rep['max_speedup_fixing_dominant']}x")
     return "\n".join(lines)
+
+
+# -- device time by named scope (ISSUE 27) -------------------------------------
+# The profiler names a device operation by its HLO line (``%fusion.106 = ...``);
+# the scope it was traced under (``jit(step)/jvp(ResNet)/stage1_block1/...``,
+# or the step's own ``optimizer_update``) is the ``tf_op`` stat of the
+# operation's METADATA. ``jax.profiler.ProfileData`` (jax 0.9.0) hands out an
+# event's own stats only (``device_offset_ps``, ``device_duration_ps``), so
+# the ``.xplane.pb`` is read here from the wire: five messages of
+# tsl/profiler/protobuf/xplane.proto, stdlib only.
+
+_OPS_LINE = "XLA Ops"
+_SCOPE_STAT = "tf_op"
+_PHASES = ("forward", "backward", "optimizer_update", "grad_allreduce",
+           "unscoped")
+_WRAPPER = re.compile(r"^(?:jit|pjit|shard_map|checkpoint|remat)\b")
+
+
+def _varint(buf, i: int):
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        if b < 0x80:
+            return val, i
+        shift += 7
+
+
+def _msg(buf) -> dict:
+    """``{field number: [values]}`` of one protobuf message: ints for varint
+    fields, memoryviews for length-delimited and fixed ones."""
+    out: dict = {}
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+        else:
+            if wire == 2:
+                size, i = _varint(buf, i)
+            elif wire in (1, 5):
+                size = 8 if wire == 1 else 4
+            else:
+                raise ValueError(f"xplane: wire type {wire} at byte {i}")
+            val = buf[i:i + size]
+            i += size
+        out.setdefault(key >> 3, []).append(val)
+    return out
+
+
+def _text(msg: dict, field: int) -> str:
+    return str(msg[field][0], "utf-8", "replace") if field in msg else ""
+
+
+def _int(msg: dict, field: int) -> int:
+    """An int64 field (two's complement on the wire), 0 where absent."""
+    v = msg.get(field, [0])[0]
+    return v - (1 << 64) if v >= 1 << 63 else v
+
+
+def _scope_stat(stats: list, stat_names: dict) -> str:
+    """The ``tf_op`` among XStat messages, "" if none: a string, or a
+    reference to another stat's metadata whose name is the string."""
+    for raw in stats:
+        st = _msg(raw)
+        if stat_names.get(_int(st, 1)) == _SCOPE_STAT:
+            return _text(st, 5) or stat_names.get(_int(st, 7), "")
+    return ""
+
+
+def read_xplane_ops(path: str) -> dict:
+    """``{plane: [(op, scope, start_ns, dur_ns), ...]}`` for every device
+    plane of an ``.xplane.pb`` that has an ``XLA Ops`` line. ``scope`` is the
+    operation's ``tf_op`` stat, "" where it carries none."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out = {}
+    for raw_plane in _msg(space).get(1, []):
+        plane = _msg(raw_plane)
+        if not _text(plane, 2).startswith("/device:"):
+            continue    # a host plane's lines hold millions of events
+        lines = [ln for ln in map(_msg, plane.get(3, []))
+                 if _text(ln, 2) == _OPS_LINE]
+        if not lines:
+            continue
+        # the two maps' entries are (key = 1, value = 2) messages
+        stat_names = {}
+        for entry in plane.get(5, []):
+            meta = _msg(_msg(entry)[2][0])
+            stat_names[_int(meta, 1)] = _text(meta, 2)
+        ops_meta = {}
+        for entry in plane.get(4, []):
+            meta = _msg(_msg(entry)[2][0])
+            ops_meta[_int(meta, 1)] = (
+                _text(meta, 2), _scope_stat(meta.get(5, []), stat_names))
+        ops = []
+        for ln in lines:
+            t0 = _int(ln, 3)
+            for raw in ln.get(4, []):
+                ev = _msg(raw)
+                name, scope = ops_meta.get(_int(ev, 1), ("", ""))
+                ops.append((name, scope,
+                            t0 + _int(ev, 2) / 1e3, _int(ev, 3) / 1e3))
+        out[_text(plane, 2)] = ops
+    return out
+
+
+def _scope_parts(scope: str) -> list:
+    """``jit(step)/jit(main)/jvp(ResNet)/Block_3/conv:`` ->
+    ``["jvp(ResNet)", "Block_3"]``: the jit wrappers in front and the
+    primitive at the end say nothing about where the time went."""
+    parts = [p for p in scope.rstrip(":").split("/") if p]
+    while parts and _WRAPPER.match(parts[0]):
+        parts.pop(0)
+    return parts[:-1]
+
+
+def _phase(parts: list) -> str:
+    for name in ("optimizer_update", "grad_allreduce"):
+        if name in parts:
+            return name
+    if any(p.startswith("transpose(") for p in parts):
+        return "backward"
+    if any(p.startswith("jvp(") for p in parts):
+        return "forward"
+    return "unscoped"
+
+
+def scope_seconds(triples: Iterable[tuple], depth: int = 2) -> dict:
+    """Device seconds by scope prefix and by phase, from ``(scope, start_ns,
+    dur_ns)`` triples of ONE device line. An operation that encloses others
+    (a loop and its body) counts its self time only, so the sums never pass
+    the line's busy time. A pure function of its triples.
+
+    Returns ``{"total_s", "by_scope": {prefix: s}, "by_phase": {phase: s}}``
+    with prefixes cut to ``depth`` parts (jit wrappers and the primitive's
+    own name dropped) and phases forward (``jvp(...)``), backward
+    (``transpose(jvp(...))``), ``optimizer_update``, ``grad_allreduce`` and
+    unscoped."""
+    evs = sorted(((s, s + d, sc) for sc, s, d in triples),
+                 key=lambda e: (e[0], -e[1]))
+    self_ns = [e[1] - e[0] for e in evs]
+    stack = []
+    for i, (s, e, _) in enumerate(evs):
+        while stack and evs[stack[-1]][1] <= s:
+            stack.pop()
+        if stack:
+            self_ns[stack[-1]] -= min(e, evs[stack[-1]][1]) - s
+        stack.append(i)
+    by_scope: dict = {}
+    by_phase = dict.fromkeys(_PHASES, 0.0)
+    for (_, _, scope), ns in zip(evs, self_ns):
+        parts = _scope_parts(scope)
+        key = "/".join(parts[:depth]) or "(unscoped)"
+        by_scope[key] = by_scope.get(key, 0.0) + ns / 1e9
+        by_phase[_phase(parts)] += ns / 1e9
+    return {"total_s": sum(self_ns) / 1e9, "by_scope": by_scope,
+            "by_phase": by_phase}
+
+
+def device_time_by_scope(profile_dir: str, depth: int = 2) -> dict:
+    """:func:`scope_seconds` of the newest ``.xplane.pb`` under
+    ``profile_dir`` (what ``fit(profile_dir=...)`` or ``runner.trace``
+    wrote), averaged over the device planes that have an ``XLA Ops`` line.
+    Adds ``"planes"`` and ``"path"``."""
+    hits = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)
+    if not hits:
+        raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
+    planes = read_xplane_ops(hits[-1])
+    if not planes:
+        raise ValueError(f"{hits[-1]} has no {_OPS_LINE!r} line: the profile "
+                         "holds no device operations")
+    reps = [scope_seconds(((sc, s, d) for _, sc, s, d in ops), depth)
+            for ops in planes.values()]
+    n = len(reps)
+    out = {"total_s": sum(r["total_s"] for r in reps) / n,
+           "by_scope": {}, "by_phase": dict.fromkeys(_PHASES, 0.0),
+           "planes": sorted(planes), "path": hits[-1]}
+    for r in reps:
+        for k, v in r["by_scope"].items():
+            out["by_scope"][k] = out["by_scope"].get(k, 0.0) + v / n
+        for k, v in r["by_phase"].items():
+            out["by_phase"][k] += v / n
+    return out
+
+
+def format_scope_report(rep: dict, top: int = 20) -> str:
+    total = rep["total_s"] or 1.0
+    lines = [f"device seconds by scope ({rep.get('path', '')}; "
+             f"{len(rep.get('planes', []))} device plane(s), "
+             f"{rep['total_s']:.4f} s busy per plane)"]
+    for k, v in sorted(rep["by_scope"].items(), key=lambda kv: -kv[1])[:top]:
+        lines.append(f"  {100 * v / total:6.2f}%  {v:9.5f} s  {k}")
+    lines.append("by phase:")
+    for k in _PHASES:
+        v = rep["by_phase"][k]
+        lines.append(f"  {100 * v / total:6.2f}%  {v:9.5f} s  {k}")
+    return "\n".join(lines)
+
+
+if __name__ == "__main__":
+    import argparse
+    ap = argparse.ArgumentParser(
+        description="Device time by named scope, from a jax profile")
+    ap.add_argument("--profile", required=True,
+                    help="directory given to fit(profile_dir=...)")
+    ap.add_argument("--depth", type=int, default=2)
+    ap.add_argument("--top", type=int, default=20)
+    ap.add_argument("--json", action="store_true")
+    args = ap.parse_args()
+    report = device_time_by_scope(args.profile, args.depth)
+    print(json.dumps(report) if args.json
+          else format_scope_report(report, args.top))

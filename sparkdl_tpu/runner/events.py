@@ -9,8 +9,9 @@ injected faults, profiler traces, restarts) becomes a structured event:
   with the measured duration (and the exception, when the region fails)
 
 Events land in a bounded in-memory **ring buffer** (``SPARKDL_EVENT_RING``
-entries, default 512). With ``SPARKDL_EVENT_DIR`` unset the hot-path cost is
-a dict build + deque append — no I/O, no host sync, no jax import. With it
+entries, default 4096: a ten-second ``fit`` window of ~220 steps is ~1,800
+records). With ``SPARKDL_EVENT_DIR`` unset the hot-path cost is a dict
+build + deque append — no I/O, no host sync, no jax import. With it
 set, each event is also streamed as one JSON line to
 ``$SPARKDL_EVENT_DIR/events_rank{i}.jsonl`` (line-buffered, so a SIGKILLed
 rank's trace survives up to its last completed event).
@@ -21,6 +22,11 @@ as a **crash postmortem** — last N events + the exception — to
 merges all ranks' event files, postmortems, and heartbeats into a single
 time-ordered **gang timeline** (:func:`merge_timeline`) naming which rank
 failed or stalled first, at what step, and at which site.
+
+Spans are also mirrored onto the profiler's clock: ``runner.metrics``
+installs ``jax.profiler.TraceAnnotation`` as the span mirror
+(:func:`set_span_mirror`), so a host-traced profile shows ``shard_put``,
+``step_compute``, ``loss_fetch`` … beside the device's ``XLA Ops``.
 
 This module is stdlib-only at import time (the supervising launcher must
 stay jax-free); :class:`Timer` lazily imports jax only when asked to block
@@ -46,6 +52,7 @@ __all__ = ["FlightRecorder", "Timer", "RECORDER_DIR_ENV", "RING_ENV",
            "enable_flight_recorder", "merge_timeline", "format_timeline",
            "write_gang_postmortem", "clear_rank_files",
            "collect_degradations", "add_tee", "remove_tee",
+           "set_span_mirror",
            "trace_armed", "new_trace_id", "new_span_id", "current_span_id"]
 
 log = logging.getLogger("sparkdl_tpu.runner")
@@ -61,7 +68,7 @@ STREAM_CAP_ENV = "SPARKDL_EVENT_MAX_MB"
 # with zero protocol.
 TRACE_ID_ENV = "SPARKDL_TRACE_ID"
 TRACE_PARENT_ENV = "SPARKDL_TRACE_PARENT"
-_DEFAULT_RING = 512
+_DEFAULT_RING = 4096
 _DEFAULT_STREAM_CAP_MB = 256  # per-rank JSONL cap; ring keeps recording
 _POSTMORTEM_TAIL = 128  # events carried in a crash postmortem
 
@@ -94,6 +101,20 @@ def remove_tee(cb) -> None:
         _TEES.remove(cb)
     except ValueError:
         pass
+
+
+# Span mirror: ``factory(name)`` -> a context manager entered and exited with
+# every span, on the span's thread. ``runner.metrics`` installs
+# ``jax.profiler.TraceAnnotation`` (this module imports no jax), which puts
+# the program's phases on the profiler's timeline under the span's own name.
+# Same rule as the tees: a mirror that raises never reaches the caller.
+_MIRROR = None
+
+
+def set_span_mirror(factory) -> None:
+    """Install (or, with None, remove) the span mirror."""
+    global _MIRROR
+    _MIRROR = factory
 
 
 # -- trace context (ISSUE 17) -------------------------------------------------
@@ -188,7 +209,7 @@ class _Span(Timer):
     """Begin/end event pair around a region; duration and (on failure) the
     exception ride the end event."""
 
-    __slots__ = ("_rec", "_name", "_attrs", "_span_id")
+    __slots__ = ("_rec", "_name", "_attrs", "_span_id", "_mirror")
 
     def __init__(self, rec: "FlightRecorder", name: str, block_on=None,
                  **attrs):
@@ -197,8 +218,16 @@ class _Span(Timer):
         self._name = name
         self._attrs = attrs
         self._span_id = None
+        self._mirror = None
 
     def __enter__(self):
+        if _MIRROR is not None:
+            try:
+                m = _MIRROR(self._name)
+                m.__enter__()
+                self._mirror = (m, threading.get_ident())
+            except Exception:  # noqa: BLE001 — see _MIRROR
+                self._mirror = None
         super().__enter__()
         if trace_armed():
             # span_id/parent_id land in _attrs so BOTH the B and the E
@@ -236,6 +265,16 @@ class _Span(Timer):
             # event (with the error) before the exception propagates.
             self.seconds = time.perf_counter() - self._t0
             block_err = be
+        if self._mirror is not None:
+            m, tid = self._mirror
+            self._mirror = None
+            # the profiler files an annotation under the thread that closes
+            # it: a span that exits elsewhere is not mirrored
+            if tid == threading.get_ident():
+                try:
+                    m.__exit__(exc_type, exc, tb)
+                except Exception:  # noqa: BLE001 — see _MIRROR
+                    pass
         end = dict(self._attrs)
         end["dur_s"] = round(self.seconds, 6)
         if exc is not None:

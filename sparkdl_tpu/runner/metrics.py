@@ -25,6 +25,10 @@ from . import telemetry
 
 log = logging.getLogger("sparkdl_tpu.runner")
 
+# Every flight-recorder span also opens a profiler annotation of the same
+# name (idle when no trace is running): events.py itself stays jax-free.
+events.set_span_mirror(jax.profiler.TraceAnnotation)
+
 
 @dataclass
 class RunStats:

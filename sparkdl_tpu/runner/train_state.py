@@ -60,11 +60,14 @@ class TrainState:
                    apply_fn=apply_fn, tx=tx)
 
     def apply_gradients(self, grads: Any) -> "TrainState":
-        updates, new_opt = self.tx.update(grads, self.opt_state, self.params)
-        return dataclasses.replace(
-            self, step=self.step + 1,
-            params=optax.apply_updates(self.params, updates),
-            opt_state=new_opt)
+        # flax names the model's operations in the profile; the step's own
+        # work gets its scope here, once for every step variant
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt = self.tx.update(grads, self.opt_state,
+                                              self.params)
+            params = optax.apply_updates(self.params, updates)
+        return dataclasses.replace(self, step=self.step + 1, params=params,
+                                   opt_state=new_opt)
 
 
 def state_sharding(state: TrainState, mesh: Mesh,
@@ -318,7 +321,8 @@ def make_shard_map_step(loss_fn: Callable, mesh: Mesh,
                 loss_wrapped = jax.checkpoint(loss_wrapped)
             (loss, (aux, new_ms)), grads = jax.value_and_grad(
                 loss_wrapped, has_aux=True)(state.params)
-            new_ms = jax.lax.pmean(new_ms, axis_name=data_axis)
+            with jax.named_scope("grad_allreduce"):
+                new_ms = jax.lax.pmean(new_ms, axis_name=data_axis)
         else:
             def loss_wrapped(params):
                 loss, aux = loss_fn(params, state.apply_fn, batch, **kw)
@@ -330,9 +334,10 @@ def make_shard_map_step(loss_fn: Callable, mesh: Mesh,
                 loss_wrapped, has_aux=True)(state.params)
             new_ms = None
         # THE collective: gradient mean over the data axis (ICI ring).
-        grads = jax.lax.pmean(grads, axis_name=data_axis)
-        loss = jax.lax.pmean(loss, axis_name=data_axis)
-        aux = jax.lax.pmean(aux, axis_name=data_axis)
+        with jax.named_scope("grad_allreduce"):
+            grads = jax.lax.pmean(grads, axis_name=data_axis)
+            loss = jax.lax.pmean(loss, axis_name=data_axis)
+            aux = jax.lax.pmean(aux, axis_name=data_axis)
         new_state = state.apply_gradients(grads)
         if mutable:
             new_state = dataclasses.replace(new_state, model_state=new_ms)

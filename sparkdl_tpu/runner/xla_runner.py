@@ -245,8 +245,12 @@ class RunnerContext:
         run) and should keep the inline feed for exact error-path resume.
 
         The loop is flight-recorded (``runner.events``): per-step
-        ``data_fetch``/``shard_put``/``step_compute`` spans, checkpoint and
-        eval spans, a ``compile`` event from first-step timing, and — on
+        ``data_fetch``/``shard_put``/``step_compute`` spans (``step_compute``
+        is the host's time to DISPATCH the step, not the step's compute), a
+        ``loss_fetch`` span around the metrics fetch at each ``log_every``
+        boundary (the loop's only device sync: it ends with the device queue
+        drained), checkpoint and eval spans, a ``compile`` event from
+        first-step timing, and — on
         any failure — a crash postmortem carrying the last events plus the
         exception. Ring-buffer only (no I/O, no host sync) unless
         ``SPARKDL_EVENT_DIR`` is set. ``flops_per_step`` (GLOBAL FLOPs per
@@ -483,7 +487,12 @@ class RunnerContext:
                 # steps stay enqueued and transfers overlap compute.
                 last_m = m
                 if (i + 1) % log_every == 0 or i + 1 == num_steps:
-                    m = {k: float(v) for k, v in m.items()}
+                    # The loop's only device sync: the span's duration is
+                    # how long the host was blocked on the device, its end
+                    # the moment the device queue is empty.
+                    with events.span("loss_fetch", step=i + 1,
+                                     every=log_every):
+                        m = {k: float(v) for k, v in m.items()}
                     _assert_finite_loss(m, i + 1)
                     meter.update(n)
                     m["examples_per_sec_per_chip"] = \

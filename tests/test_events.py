@@ -692,6 +692,219 @@ class TestTraceSatellite:
         assert ev and ev[0]["trace_dir"] == "/tmp/sparkdl_trace_test"
 
 
+class _Mirror:
+    """A span mirror that records what it is asked to do."""
+
+    def __init__(self, log, fail=()):
+        self.log, self.fail = log, fail
+
+    def __call__(self, name):
+        if "make" in self.fail:
+            raise RuntimeError("mirror factory broke")
+        mirror = self
+
+        class _Ctx:
+            def __enter__(self):
+                mirror.log.append(("enter", name))
+                if "enter" in mirror.fail:
+                    raise RuntimeError("mirror enter broke")
+
+            def __exit__(self, *exc):
+                mirror.log.append(("exit", name))
+                if "exit" in mirror.fail:
+                    raise RuntimeError("mirror exit broke")
+        return _Ctx()
+
+
+class TestSpanMirror:
+    """ISSUE 27: every span also opens a profiler annotation of its own
+    name, through a hook that events.py (jax-free) does not know."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_mirror(self):
+        saved = events._MIRROR
+        yield
+        events.set_span_mirror(saved)
+
+    def test_mirror_entered_and_exited_once_per_span_with_its_name(self):
+        log = []
+        events.set_span_mirror(_Mirror(log))
+        with events.span("outer", step=1):
+            with events.span("inner"):
+                pass
+        assert log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+        # the region's own exception still closes the mirror, and escapes
+        log.clear()
+        with pytest.raises(ValueError):
+            with events.span("bad"):
+                raise ValueError("user bug")
+        assert log == [("enter", "bad"), ("exit", "bad")]
+
+    @pytest.mark.parametrize("fail", ["make", "enter", "exit"])
+    def test_raising_mirror_never_reaches_the_caller(self, fail):
+        log = []
+        events.set_span_mirror(_Mirror(log, fail={fail}))
+        rec = events.get_recorder()
+        with events.span("region", step=3) as sp:
+            pass
+        assert sp.seconds >= 0
+        ends = [e for e in rec.tail() if e["ph"] == "E"]
+        assert [e["name"] for e in ends] == ["region"]
+        assert "error" not in ends[0]
+
+    def test_span_closed_on_another_thread_is_not_mirrored(self):
+        import threading
+        log = []
+        events.set_span_mirror(_Mirror(log))
+        sp = events.span("handed_over")
+        sp.__enter__()
+        t = threading.Thread(target=sp.__exit__, args=(None, None, None))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert log == [("enter", "handed_over")]
+        assert [e["ph"] for e in events.get_recorder().tail()] == ["B", "E"]
+
+    def test_no_mirror_means_plain_spans(self):
+        events.set_span_mirror(None)
+        with events.span("plain"):
+            pass
+        assert [e["ph"] for e in events.get_recorder().tail()] == ["B", "E"]
+
+    def test_runner_installs_the_profilers_annotation(self):
+        assert events._MIRROR is jax.profiler.TraceAnnotation
+
+    def test_events_module_alone_imports_no_jax(self):
+        """The launcher's rule: events.py by itself is stdlib-only — the
+        mirror is installed from runner.metrics, which has jax anyway."""
+        import subprocess
+        path = os.path.join(_REPO, "sparkdl_tpu", "runner", "events.py")
+        code = (
+            "import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('ev', {path!r})\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "sys.modules['ev'] = m\n"
+            "spec.loader.exec_module(m)\n"
+            "with m.span('s'):\n    pass\n"
+            "assert m._MIRROR is None and len(m.get_recorder().tail()) == 2\n"
+            "bad = [k for k in sys.modules if k.split('.')[0] in"
+            " ('jax', 'jaxlib', 'numpy')]\n"
+            "assert not bad, bad\n")
+        p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=60)
+        assert p.returncode == 0, p.stderr
+
+    def test_default_ring_holds_a_whole_benchmark_window(self):
+        assert events.FlightRecorder().ring.maxlen == 4096
+
+
+class TestLossFetchSpan:
+    """ISSUE 27: the loop's only device sync carries a span."""
+
+    def test_fit_emits_loss_fetch_at_each_log_boundary(self):
+        rec = events.get_recorder()
+        XlaRunner(np=8).run(lambda ctx: _fit(ctx, num_steps=25, log_every=10))
+        ends = [e for e in rec.tail()
+                if e["name"] == "loss_fetch" and e["ph"] == "E"]
+        assert [e["step"] for e in ends] == [10, 20, 25]
+        assert all(e["every"] == 10 and e["dur_s"] >= 0 for e in ends)
+        # nothing else in the loop was renamed: the benchmark reads these
+        names = {e["name"] for e in rec.tail()}
+        assert {"data_fetch", "shard_put", "step_compute"} <= names
+        # each fetch follows the dispatch of the step it names
+        order = [(e["name"], e.get("step")) for e in rec.tail()
+                 if e["ph"] == "E" and e["name"] in ("step_compute",
+                                                     "loss_fetch")]
+        assert order.index(("loss_fetch", 10)) == \
+            order.index(("step_compute", 9)) + 1
+
+
+class TestDeviceTimeByScope:
+    """ISSUE 27: device seconds by named scope, a pure function of
+    (scope, start, duration) triples, and the .xplane.pb reader under it."""
+
+    def test_scope_seconds_by_prefix_and_phase(self):
+        from sparkdl_tpu.runner import analysis
+        ms = 1_000_000
+        triples = [
+            ("jit(step)/jit(main)/jvp(Net)/Block_0/Conv_0/conv:", 0, 4 * ms),
+            ("jit(step)/jit(main)/jvp(Net)/Block_0/BatchNorm_0/reduce:",
+             4 * ms, 1 * ms),
+            ("jit(step)/jit(main)/transpose(jvp(Net))/Block_0/Conv_0/conv:",
+             5 * ms, 8 * ms),
+            ("jit(step)/jit(main)/optimizer_update/mul:", 13 * ms, 2 * ms),
+            ("jit(step)/shard_map/grad_allreduce/psum:", 15 * ms, 1 * ms),
+            ("", 16 * ms, 3 * ms),
+            ("jit(step)/jit(main)/add:", 19 * ms, 1 * ms),
+        ]
+        rep = analysis.scope_seconds(triples, depth=2)
+        assert rep["total_s"] == pytest.approx(0.020)
+        assert rep["by_scope"] == pytest.approx({
+            "jvp(Net)/Block_0": 0.005, "transpose(jvp(Net))/Block_0": 0.008,
+            "optimizer_update": 0.002, "grad_allreduce": 0.001,
+            "(unscoped)": 0.004})
+        assert rep["by_phase"] == pytest.approx({
+            "forward": 0.005, "backward": 0.008, "optimizer_update": 0.002,
+            "grad_allreduce": 0.001, "unscoped": 0.004})
+        deep = analysis.scope_seconds(triples, depth=3)["by_scope"]
+        assert deep["jvp(Net)/Block_0/Conv_0"] == pytest.approx(0.004)
+        assert deep["jvp(Net)/Block_0/BatchNorm_0"] == pytest.approx(0.001)
+
+    def test_an_enclosing_operation_counts_its_self_time(self):
+        from sparkdl_tpu.runner import analysis
+        triples = [("jit(f)/jvp(Net)/scan/while:", 0, 100),
+                   ("jit(f)/jvp(Net)/scan/Dense_0/dot:", 10, 30),
+                   ("jit(f)/optimizer_update/mul:", 50, 40)]
+        rep = analysis.scope_seconds(triples, depth=1)
+        assert rep["total_s"] == pytest.approx(100e-9)
+        assert rep["by_scope"] == pytest.approx(
+            {"jvp(Net)": 60e-9, "optimizer_update": 40e-9})
+
+    def test_reads_the_scope_off_an_operations_metadata(self, tmp_path):
+        """The scope is a stat of the operation's METADATA (as a string, or
+        as a reference to another stat's name), which ProfileData's event
+        stats do not carry: read from the wire."""
+        from jax.profiler import ProfileData
+        from sparkdl_tpu.runner import analysis
+        text = """
+        planes { name: "/device:TPU:0"
+          lines { name: "XLA Ops" timestamp_ns: 100
+            events { metadata_id: 1 offset_ps: 1000000 duration_ps: 2000000
+                     stats { metadata_id: 2 int64_value: 7 } }
+            events { metadata_id: 2 offset_ps: 4000000 duration_ps: 3000000 }
+            events { metadata_id: 3 offset_ps: 8000000 duration_ps: 1000000 } }
+          lines { name: "XLA Modules" timestamp_ns: 100
+            events { metadata_id: 1 offset_ps: 0 duration_ps: 9000000 } }
+          event_metadata { key: 1 value { id: 1 name: "fusion.1" stats {
+            metadata_id: 1 str_value: "jit(f)/optimizer_update/mul:" } } }
+          event_metadata { key: 2 value { id: 2 name: "fusion.2" stats {
+            metadata_id: 1 ref_value: 3 } } }
+          event_metadata { key: 3 value { id: 3 name: "copy.3" } }
+          stat_metadata { key: 1 value { id: 1 name: "tf_op" } }
+          stat_metadata { key: 2 value { id: 2 name: "run_id" } }
+          stat_metadata { key: 3 value { id: 3
+            name: "jit(f)/transpose(jvp(Net))/Dense_0/dot_general:" } } }
+        planes { name: "/host:CPU" lines { name: "python" } }"""
+        d = tmp_path / "plugins" / "profile" / "run1"
+        d.mkdir(parents=True)
+        (d / "host.xplane.pb").write_bytes(
+            ProfileData.text_proto_to_serialized_xspace(text))
+        ops = analysis.read_xplane_ops(str(d / "host.xplane.pb"))
+        assert list(ops) == ["/device:TPU:0"]
+        assert ops["/device:TPU:0"][0] == (
+            "fusion.1", "jit(f)/optimizer_update/mul:", 1100.0, 2000.0)
+        rep = analysis.device_time_by_scope(str(tmp_path), depth=2)
+        assert rep["planes"] == ["/device:TPU:0"]
+        assert rep["by_phase"]["backward"] == pytest.approx(3e-6)
+        assert rep["by_phase"]["optimizer_update"] == pytest.approx(2e-6)
+        assert rep["by_phase"]["unscoped"] == pytest.approx(1e-6)
+        assert "transpose(jvp(Net))/Dense_0" in rep["by_scope"]
+        assert "optimizer_update" in analysis.format_scope_report(rep)
+        with pytest.raises(FileNotFoundError):
+            analysis.device_time_by_scope(str(tmp_path / "plugins" / "none"))
+
+
 class TestDegradations:
     """ISSUE 4: survived-fault events (retry / quarantine / rollback) are
     timeline NARRATIVE — collected, rendered, never failure evidence."""

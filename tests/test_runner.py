@@ -6,6 +6,8 @@ the sharded SPMD step must match a single-device numpy/jax reference step
 bit-for-bit (same inputs, same update math).
 """
 
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -87,6 +89,25 @@ class TestTrainStep:
             np.testing.assert_allclose(np.asarray(s1.params[k]),
                                        np.asarray(s2.params[k]),
                                        rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("explicit", [False, True])
+    def test_step_names_its_own_work_for_the_profiler(self, runner,
+                                                      explicit):
+        """ISSUE 27: flax names the model's operations; the step's own work
+        (optimizer update, the explicit gradient mean) carries a
+        jax.named_scope, which reaches the compiled HLO's op_name."""
+        ctx = runner.make_context()
+        params, batch = _make_problem()
+        state = TrainState.create(_linear_apply, params,
+                                  optax.sgd(0.1, momentum=0.9))
+        step = ctx.make_train_step(softmax_cross_entropy_loss(),
+                                   explicit_collectives=explicit)
+        with ctx.mesh:
+            text = step.lower(state, ctx.shard_batch(batch)).compile() \
+                .as_text()
+        names = re.findall(r'op_name="([^"]*)"', text)
+        assert any("/optimizer_update/" in n for n in names)
+        assert any("/grad_allreduce/" in n for n in names) == explicit
 
     def test_remat_same_gradients(self, runner):
         """remat=True recomputes activations in the backward pass — a
