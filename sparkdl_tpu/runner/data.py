@@ -50,10 +50,12 @@ batch, bounded by ``SPARKDL_MAX_SKIPPED_BATCHES`` (fatal
 
 With ``SPARKDL_BATCH_LEDGER`` set to a directory, ``fit()`` appends one
 JSON line per step (``{"step", "epoch", "batch_index", "skip_list"}``)
-to ``ledger_rank{i}.jsonl`` — written at step DISPATCH (the loop never
-syncs per step), so a step whose attempt later dies is on record and
-superseded by its replay entry: audit by LAST entry per step, with each
-entry's skip_list giving the remap context. That is exactly what the
+to ``ledger_rank{i}.jsonl`` — written when the step is RETIRED (its
+metrics have arrived: one step behind the dispatch, and always before a
+checkpoint that holds the step is saved), so every checkpointed step is
+on record, a step that diverged is too, and a step whose attempt later
+dies is superseded by its replay entry: audit by LAST entry per step,
+with each entry's skip_list giving the remap context. That is exactly what the
 exactly-once smoke (``scripts/train_resume_smoke.py``) asserts: across
 all restart attempts every step maps to the same batch (deterministic
 replay, modulo batches quarantined in between) and the final step→batch
@@ -398,12 +400,14 @@ def env_skip_list(environ=None) -> list[int]:
 
 
 def append_ledger(step: int, cursor: dict | None):
-    """Batch-id ledger: one JSON line per DISPATCHED step (the train
-    loop is async — a step is ledgered when its batch is fed, which may
-    precede a divergence detected at a later sync; the replayed attempt
-    supersedes it, so audits take the last entry per step). Append-mode:
-    survives SIGKILL up to the last dispatched step and accumulates
-    ACROSS restart attempts (the exactly-once audit needs all lineages).
+    """Batch-id ledger: one JSON line per RETIRED step (``fit`` runs one
+    step ahead of the chip and ledgers a step when its metrics have
+    arrived, before the divergence guard reads them: a diverged step is
+    on record, the step dispatched after it is not; the replayed attempt
+    supersedes an entry, so audits take the last entry per step).
+    Append-mode: survives SIGKILL up to the last retired step and
+    accumulates ACROSS restart attempts (the exactly-once audit needs all
+    lineages).
     No-op unless ``SPARKDL_BATCH_LEDGER`` names a directory.
 
     Each line carries the WORLD SIZE in force when the batch was drawn

@@ -275,14 +275,16 @@ class ThroughputMeter:
     host syncs on the hot path, so by default only every ``sync_every`` steps
     block).
 
-    Step-time caveat (applies to ``step_stats`` and the derived MFU): the
-    recorded dt is host wall time between ``update`` calls, never forcing
-    a sync. On an async backend with fit()'s default cadence, most
-    intervals are dispatch-scale and the ``log_every``-boundary interval
-    absorbs the queued compute — so ``mean_s`` (and thus MFU, which uses
-    it) is honest over any sync-bounded window, while p50/p95/p99 describe
-    the *host-observed* cadence, not the device step distribution. For
-    true per-step device latency use bench.py's fetch-closed protocol.
+    Step times (``step_stats`` and the derived MFU): the recorded dt is
+    host wall time between ``update`` calls; the meter itself never forces
+    a sync. ``fit()`` calls ``update`` as it *retires* a step — once that
+    step's metrics have arrived, one step behind the dispatch — so each
+    interval is the time between two steps finishing: the step's device
+    time when the chip sets the pace, the host's time per step when
+    ``data`` does. p50/p95/p99 are therefore real step times, whatever
+    ``log_every`` is. A caller that updates after a bare dispatch still
+    meters dispatch-scale intervals, with the queued compute absorbed
+    wherever it syncs (only ``mean_s`` is honest there).
     """
     n_chips: int = 1
     warmup_steps: int = 1  # first step includes XLA compile; exclude it

@@ -26,7 +26,7 @@ import logging
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterable
+from typing import Any, Callable, Iterable, NamedTuple
 
 import jax
 import numpy as np
@@ -45,6 +45,15 @@ from .train_state import (TrainState, make_eval_step, make_shard_map_step,
                           make_train_step)
 
 log = logging.getLogger("sparkdl_tpu.runner")
+
+
+class _Dispatched(NamedTuple):
+    """A train step ``fit`` has dispatched and not yet retired."""
+    index: int             # 0-based: the step's number is index + 1
+    metrics: dict          # the step's metrics, device values
+    examples: int          # global examples the step consumed
+    cursor: dict | None    # data-plane position after the step's batch
+
 
 _CURRENT_CONTEXT: list["RunnerContext"] = []
 _DISTRIBUTED_INITIALIZED = False
@@ -247,9 +256,9 @@ class RunnerContext:
         The loop is flight-recorded (``runner.events``): per-step
         ``data_fetch``/``shard_put``/``step_compute`` spans (``step_compute``
         is the host's time to DISPATCH the step, not the step's compute), a
-        ``loss_fetch`` span around the metrics fetch at each ``log_every``
-        boundary (the loop's only device sync: it ends with the device queue
-        drained), checkpoint and eval spans, a ``compile`` event from
+        ``step_retire`` span each step (below), a ``loss_fetch`` span around
+        the conversion of a ``log_every`` boundary step's metrics to floats,
+        checkpoint and eval spans, a ``compile`` event from
         first-step timing, and — on
         any failure — a crash postmortem carrying the last events plus the
         exception. Ring-buffer only (no I/O, no host sync) unless
@@ -257,6 +266,22 @@ class RunnerContext:
         step) feeds the meter's MFU; leave None and set
         ``SPARKDL_MFU_ESTIMATE=1`` to ask XLA's cost analysis instead (one
         extra host-side trace at startup).
+
+        The loop runs ONE step ahead of the chip, whatever ``log_every``
+        is: after dispatching step n+1 it *retires* step n — waits for
+        step n's metrics under a ``step_retire`` span (``dur_s`` = how long
+        the host waited for the chip: about a step when the chip sets the
+        pace, about nothing when ``data`` does) and only then meters it
+        and, at a log boundary, logs it. When the wait returns step n+1 is
+        running, so the host has a whole device step to fetch, put and
+        dispatch step n+2, and the device queue never empties; two or
+        three batches sit in HBM, not ``log_every`` of them. ``history``
+        and the logged values are those of a loop that synced at each
+        boundary; an entry appears one iteration later, and a divergence
+        is raised one iteration later, naming the step (and, at
+        ``log_every=1``, the batch) that produced it. A checkpoint never
+        runs ahead: the step it holds is retired (ledgered, metered,
+        logged) before the save, which syncs on that step anyway.
         """
         state = TrainState.create(apply_fn or (lambda p, x: p), params, tx,
                                   model_state=model_state)
@@ -434,6 +459,46 @@ class RunnerContext:
         # checkpoint manifest persists).
         cur_cursor: dict | None = None
         last_cursor: dict | None = None
+        # The loop's run-ahead, depth 1: the one dispatched step not yet
+        # retired.
+        in_flight: _Dispatched | None = None
+
+        def _retire(d: _Dispatched | None):
+            """Wait for step ``d``'s metrics (never its state: that was
+            donated to the next step), ledger and meter it, and at a log
+            boundary convert, guard, log and record it. None: nothing in
+            flight. A failure here belongs to step ``d`` and the batch it
+            carried, not to the loop's iteration, whose batch went into
+            the step dispatched after it: the exception says so."""
+            if d is None:
+                return
+            step = d.index + 1
+            try:
+                with events.span("step_retire", step=step):
+                    jax.block_until_ready(d.metrics)
+                # The step ran to its end: its batch-id ledger line when
+                # SPARKDL_BATCH_LEDGER is set (the exactly-once audit
+                # trail across restart attempts). Before the guard, so a
+                # diverged step is on record, and never for the step
+                # dispatched after it.
+                data_lib.append_ledger(d.index, d.cursor)
+                meter.update(d.examples)
+                if step % log_every == 0 or step == num_steps:
+                    # The step is finished: the span times the device→host
+                    # copy of its scalars alone, once per boundary (the
+                    # benchmark reads log_every off consecutive `step`s).
+                    with events.span("loss_fetch", step=step,
+                                     every=log_every):
+                        m = {k: float(v) for k, v in d.metrics.items()}
+                    _assert_finite_loss(m, step)
+                    m["examples_per_sec_per_chip"] = \
+                        meter.recent_examples_per_sec() / max(self.size, 1)
+                    logger.log(step, m)
+                    history.append({"step": step, **m})
+            except BaseException as e:
+                e._sparkdl_retiring = (d.index, d.cursor)
+                raise
+
         try:
             for i in range(start_step, num_steps):
                 # Cleared BEFORE anything this iteration can raise (the
@@ -477,33 +542,27 @@ class RunnerContext:
                 # the watchdog and then let a >watchdog_s compile read as
                 # a hang, deterministically burning the restart budget.
                 metrics_lib.touch_heartbeat(i)
-                # Step i consumed its batch: the cursor to persist, and a
-                # batch-id ledger line when SPARKDL_BATCH_LEDGER is set
-                # (the exactly-once audit trail across restart attempts).
+                # Step i consumed its batch: the cursor to persist (it goes
+                # with `state`, the newest step's, into a checkpoint).
                 if cur_cursor is not None:
                     last_cursor = cur_cursor
-                    data_lib.append_ledger(i, cur_cursor)
-                # Host sync only at metering/logging boundaries; otherwise
-                # steps stay enqueued and transfers overlap compute.
+                # last_m stays the NEWEST dispatched step's metrics (device
+                # values): the finalize guard and the benchmark's driver,
+                # which reads this frame, both want the newest step's loss.
                 last_m = m
-                if (i + 1) % log_every == 0 or i + 1 == num_steps:
-                    # The loop's only device sync: the span's duration is
-                    # how long the host was blocked on the device, its end
-                    # the moment the device queue is empty.
-                    with events.span("loss_fetch", step=i + 1,
-                                     every=log_every):
-                        m = {k: float(v) for k, v in m.items()}
-                    _assert_finite_loss(m, i + 1)
-                    meter.update(n)
-                    m["examples_per_sec_per_chip"] = \
-                        meter.recent_examples_per_sec() / max(self.size, 1)
-                    logger.log(i + 1, m)
-                    history.append({"step": i + 1, **m})
-                    last_m = m
-                else:
-                    meter.update(n)
+                # One step behind the dispatch: step i+1 is queued, so
+                # waiting for step i here never empties the device queue.
+                _retire(in_flight)
+                in_flight = _Dispatched(i, m, n, cur_cursor)
                 if checkpoint_every and self.checkpoints and \
                         (i + 1) % checkpoint_every == 0:
+                    # A save takes the newest state, so the newest step is
+                    # retired first: nothing is checkpointed that is not
+                    # ledgered, metered and logged (a resume starts past
+                    # it and would never replay it). The guard below syncs
+                    # on this step anyway, so the retire costs nothing.
+                    _retire(in_flight)
+                    in_flight = None
                     # Divergence guard BEFORE the save: a NaN checkpoint
                     # would poison every subsequent resume (the host sync
                     # it costs rides the checkpoint's own sync cadence).
@@ -516,6 +575,8 @@ class RunnerContext:
                         evm = _run_eval(eval_step, state, eval_data,
                                         self.shard_batch)
                     logger.log(i + 1, {f"eval_{k}": v for k, v in evm.items()})
+            # num_steps reached or the data ran out: retire the last step.
+            _retire(in_flight)
         except BaseException as e:
             failed = True
             # Crash postmortem (ISSUE 2 tentpole): the ring tail + the
@@ -533,14 +594,17 @@ class RunnerContext:
             # at a log_every > 1 boundary, where the NaN-producing batch
             # is anywhere in the window and naming the detection step's
             # batch would be a guess.
+            # A failure raised while RETIRING a step carries that step's
+            # index and cursor (`_retire`'s tag), not iteration i's.
+            at, at_cursor = getattr(e, "_sparkdl_retiring", (i, cur_cursor))
             bi = getattr(e, "_sparkdl_batch_index", None)
             ep = getattr(e, "_sparkdl_batch_epoch", None)
-            if bi is None and cur_cursor is not None and not (
+            if bi is None and at_cursor is not None and not (
                     isinstance(e, TrainingDivergedError)
                     and log_every != 1):
-                bi = cur_cursor["batch_index"] - 1
-                ep = cur_cursor.get("epoch")
-            events.postmortem(e, site="fit", step=i,
+                bi = at_cursor["batch_index"] - 1
+                ep = at_cursor.get("epoch")
+            events.postmortem(e, site="fit", step=at,
                               batch_index=bi, epoch=ep)
             # The dying rank's last telemetry snapshot is failure
             # evidence too (which stage was starving when the gang died)

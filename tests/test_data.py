@@ -18,6 +18,7 @@ from sparkdl_tpu.runner import (CheckpointManager, ListDataset, XlaRunner,
                                 softmax_cross_entropy_loss)
 from sparkdl_tpu.runner import chaos, events
 from sparkdl_tpu.runner import data as data_lib
+from sparkdl_tpu.runner import metrics as metrics_lib
 from sparkdl_tpu.runner.chaos import Fault, FaultPlan, InjectedPreemption
 from sparkdl_tpu.runner.data import (ArrowDataset, FactoryDataset,
                                      as_dataset, env_skip_list, read_ledger)
@@ -304,6 +305,40 @@ class TestFitCursorThreading:
                 == e["batch_index"], "replay diverged"
         assert sorted(by_step.items()) == [(i, i) for i in range(8)]
 
+    @pytest.mark.parametrize("at_step", [2, 3, 4, 5])
+    @pytest.mark.parametrize("log_every", [1, 3])
+    def test_every_checkpointed_step_is_ledgered_and_logged(
+            self, tmp_path, monkeypatch, at_step, log_every):
+        """fit runs one step ahead of the chip, but never past a save:
+        the step a checkpoint holds is retired (ledgered, logged) before
+        the save, so an attempt that dies on the very next iteration
+        (at_step 2 and 4, checkpoint_every=2) leaves no step that neither
+        lineage ledgers or logs. At the odd steps the in-flight step dies
+        unledgered and the resume replays it."""
+        monkeypatch.setenv(data_lib.LEDGER_ENV, str(tmp_path / "led"))
+        batches = _batches(8)
+        logged = []
+        monkeypatch.setattr(
+            metrics_lib.MetricsLogger, "log",
+            lambda self, step, m: "loss" in m and logged.append(step))
+        chaos.install(FaultPlan(
+            [Fault("step_start", "preempt", at_step=at_step)]))
+        try:
+            with pytest.raises(InjectedPreemption):
+                _fit(tmp_path / "ck", ListDataset(batches), 8,
+                     log_every=log_every)
+        finally:
+            chaos.uninstall()
+        saved = at_step - at_step % 2
+        led = read_ledger(str(tmp_path / "led"))
+        assert [e["step"] for e in led] == list(range(saved))
+        _fit(tmp_path / "ck", ListDataset(batches), 8, log_every=log_every)
+        led = read_ledger(str(tmp_path / "led"))
+        assert [(e["step"], e["batch_index"]) for e in led] == \
+            [(i, i) for i in range(8)]
+        assert logged == [s for s in range(1, 9)
+                          if s % log_every == 0 or s == 8]
+
     def test_fit_honors_env_skip_list(self, tmp_path, monkeypatch):
         monkeypatch.setenv(data_lib.LEDGER_ENV, str(tmp_path / "led"))
         monkeypatch.setenv(data_lib.SKIP_ENV, "[1]")
@@ -397,6 +432,82 @@ class TestFitCursorThreading:
             events.reset()
         pm = json.load(open(tmp_path / "ev2" / "postmortem_rank0.json"))
         assert pm["batch_index"] == 2
+
+    def _diverge(self, tmp_path, monkeypatch, poison_at, num_steps,
+                 n_batches=8, **kw):
+        """fit over ``n_batches`` with batch ``poison_at`` poisoned;
+        returns (the TrainingDivergedError, the postmortem)."""
+        from sparkdl_tpu.runner.failures import TrainingDivergedError
+        monkeypatch.setenv(events.RECORDER_DIR_ENV, str(tmp_path / "ev"))
+        events.reset()
+        chaos.install(FaultPlan(
+            [Fault("data_fetch", "poison", at_step=poison_at, once=False)]))
+        try:
+            with pytest.raises(TrainingDivergedError) as ei:
+                _fit(tmp_path / "ck", ListDataset(_batches(n_batches)),
+                     num_steps, **kw)
+        finally:
+            chaos.uninstall()
+            monkeypatch.delenv(events.RECORDER_DIR_ENV)
+            events.reset()
+        return ei.value, json.load(
+            open(tmp_path / "ev" / "postmortem_rank0.json"))
+
+    @pytest.mark.parametrize("num_steps,log_every,batch_index", [
+        (7, 1, 6),       # num_steps reached, exact attribution
+        (7, 3, None),    # boundary because last: detected, not attributed
+        (100, 1, 6),     # the data ran out first
+    ], ids=["reached-log1", "reached-log3", "data_out-log1"])
+    def test_poison_at_the_last_step_is_still_raised(
+            self, tmp_path, monkeypatch, num_steps, log_every, batch_index):
+        """ISSUE 28: the last step has no successor whose iteration would
+        retire it — fit retires it after the loop, under the same guard
+        and the same postmortem (step 7 is no checkpoint boundary here:
+        checkpoint_every=2, so only the retire can catch it)."""
+        err, pm = self._diverge(tmp_path, monkeypatch, poison_at=6,
+                                num_steps=num_steps, n_batches=7,
+                                log_every=log_every)
+        assert err.step == 7
+        assert pm["site"] == "fit" and pm["step"] == 6
+        assert pm["batch_index"] == batch_index
+
+    def test_diverged_attribution_names_the_batch_not_its_successor(
+            self, tmp_path, monkeypatch):
+        """ISSUE 28: step 4's NaN is seen while step 5 (batch 4) is
+        already dispatched — the postmortem must name batch 3 and step
+        index 3, or the supervisor quarantines an innocent batch."""
+        err, pm = self._diverge(tmp_path, monkeypatch, poison_at=3,
+                                num_steps=8, log_every=1)
+        assert err.step == 4
+        assert pm["step"] == 3 and pm["batch_index"] == 3 \
+            and pm["epoch"] == 0
+
+    def test_ledger_holds_the_diverged_step_and_not_its_successor(
+            self, tmp_path, monkeypatch):
+        """ISSUE 28: step 4 (batch 3) is dispatched before step 3's NaN
+        is seen. A ledger line for it would read, after the quarantine of
+        batch 2, as step 4 moving off a batch nobody quarantined — so a
+        step is ledgered when it is retired, not when it is dispatched."""
+        monkeypatch.setenv(data_lib.LEDGER_ENV, str(tmp_path / "led"))
+        self._diverge(tmp_path, monkeypatch, poison_at=2, num_steps=8,
+                      log_every=1)
+        led = read_ledger(str(tmp_path / "led"))
+        assert [(e["step"], e["batch_index"]) for e in led] == \
+            [(i, i) for i in range(3)]
+
+    @pytest.mark.parametrize("poison_at,log_every", [
+        (3, 100),   # step 4 is a checkpoint boundary: its own guard syncs
+        (3, 1),     # the same, with every step retired and checked
+        (2, 1),     # step 3 diverges; step 4's save must never be reached
+    ])
+    def test_nan_checkpoint_is_never_saved(self, tmp_path, monkeypatch,
+                                           poison_at, log_every):
+        err, _ = self._diverge(tmp_path, monkeypatch, poison_at=poison_at,
+                               num_steps=8, log_every=log_every)
+        assert err.step == poison_at + 1
+        saved = sorted(int(p.stem.rsplit("_", 1)[1])
+                       for p in (tmp_path / "ck").glob("manifest_step_*.json"))
+        assert saved == [2]
 
     def test_bare_iterator_keeps_legacy_path(self, tmp_path, monkeypatch):
         """A generator (not replayable) must train exactly as before —

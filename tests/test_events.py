@@ -291,8 +291,9 @@ class TestPostmortem:
 class TestOverheadBounded:
     def test_recorder_off_is_ring_only_no_sync(self, tmp_path, monkeypatch):
         """Acceptance: with SPARKDL_EVENT_DIR unset, a recorded fit() does
-        no event I/O and introduces no extra host syncs — exactly the one
-        pre-existing block_until_ready at the end of fit()."""
+        no event I/O and introduces no extra host syncs — exactly the
+        loop's own: one wait per retired step (ISSUE 28) and the one
+        block_until_ready at the end of fit()."""
         rec = events.reset()
         calls = []
         orig = jax.block_until_ready
@@ -301,7 +302,7 @@ class TestOverheadBounded:
             lambda tree: (calls.append(1), orig(tree))[1])
         res = XlaRunner(np=8).run(_fit)
         assert int(res["state"].step) == 4
-        assert len(calls) == 1  # fit()'s final sync only
+        assert len(calls) == 4 + 1  # 4 steps retired + fit()'s final sync
         assert rec._file is None  # no stream was ever opened
         assert list(tmp_path.iterdir()) == []
         assert any(e["name"] == "step_compute" for e in rec.tail())
@@ -800,7 +801,7 @@ class TestSpanMirror:
 
 
 class TestLossFetchSpan:
-    """ISSUE 27: the loop's only device sync carries a span."""
+    """ISSUE 27: the metrics fetch at a log boundary carries a span."""
 
     def test_fit_emits_loss_fetch_at_each_log_boundary(self):
         rec = events.get_recorder()
@@ -812,12 +813,68 @@ class TestLossFetchSpan:
         # nothing else in the loop was renamed: the benchmark reads these
         names = {e["name"] for e in rec.tail()}
         assert {"data_fetch", "shard_put", "step_compute"} <= names
-        # each fetch follows the dispatch of the step it names
+        # each fetch follows the dispatch of the step AFTER the one it
+        # names (ISSUE 28: step_compute counts from 0, loss_fetch from 1)
         order = [(e["name"], e.get("step")) for e in rec.tail()
                  if e["ph"] == "E" and e["name"] in ("step_compute",
                                                      "loss_fetch")]
         assert order.index(("loss_fetch", 10)) == \
-            order.index(("step_compute", 9)) + 1
+            order.index(("step_compute", 10)) + 1
+
+
+class TestStepRetire:
+    """ISSUE 28: fit retires each step one step behind its dispatch. Ring
+    order only — no timing."""
+
+    @staticmethod
+    def _ends(rec):
+        return [(e["name"], e.get("step")) for e in rec.tail()
+                if e["ph"] == "E"]
+
+    def test_retire_sits_between_next_dispatch_and_the_fetch_after(self):
+        rec = events.get_recorder()
+        XlaRunner(np=8).run(lambda ctx: _fit(ctx, num_steps=6, log_every=2))
+        ends = self._ends(rec)
+        # step_retire and loss_fetch count steps from 1; step_compute and
+        # data_fetch count batches from 0: step n+1 is step_compute n
+        for n in range(1, 5):
+            at = ends.index(("step_retire", n))
+            assert ends.index(("step_compute", n)) < at \
+                < ends.index(("data_fetch", n + 1)), n
+        # step 1 is retired after the SECOND dispatch, not after its own
+        assert ends.index(("step_retire", 1)) > \
+            ends.index(("step_compute", 1))
+        # a boundary step's fetch follows its own retire directly
+        for n in (2, 4, 6):
+            assert ends.index(("loss_fetch", n)) == \
+                ends.index(("step_retire", n)) + 1
+
+    @pytest.mark.parametrize("num_steps,n_batches", [(6, 64), (100, 6)],
+                             ids=["num_steps_reached", "data_ran_out"])
+    def test_last_step_is_retired_and_logged(self, num_steps, n_batches):
+        rec = events.get_recorder()
+        res = XlaRunner(np=8).run(lambda ctx: ctx.fit(
+            loss_fn=softmax_cross_entropy_loss(), params=_params(),
+            tx=optax.sgd(0.1), apply_fn=_linear_apply,
+            data=_data(n_batches=n_batches), num_steps=num_steps,
+            log_every=3))
+        assert int(res["state"].step) == 6
+        ends = self._ends(rec)
+        assert [s for name, s in ends if name == "step_retire"] == \
+            [1, 2, 3, 4, 5, 6]
+        assert [s for name, s in ends if name == "loss_fetch"] == [3, 6]
+        assert [h["step"] for h in res["history"]] == [3, 6]
+        assert res["meter"].steps == 6
+        # the last retire comes after everything the loop dispatched
+        assert ends.index(("step_retire", 6)) > \
+            ends.index(("step_compute", 5))
+
+    def test_one_step_fit_retires_it_after_the_loop(self):
+        rec = events.get_recorder()
+        res = XlaRunner(np=8).run(
+            lambda ctx: _fit(ctx, num_steps=1, log_every=10))
+        assert [h["step"] for h in res["history"]] == [1]
+        assert ("step_retire", 1) in self._ends(rec)
 
 
 class TestDeviceTimeByScope:

@@ -299,6 +299,37 @@ class TestFitLoop:
         assert losses[-1] < losses[0]
         assert res["meter"].steps == 12
 
+    @pytest.mark.parametrize("log_every", [1, 3, 10])
+    def test_history_equals_a_loop_that_syncs_at_each_boundary(
+            self, log_every):
+        """ISSUE 28: retiring a step one step behind its dispatch changes
+        WHEN an entry appears, never which steps are logged or their
+        values — pinned against the former loop's order (dispatch, then
+        convert the boundary step's metrics at once) on a fixed seed."""
+        params, _ = _make_problem(seed=9)
+        loss_fn, tx, num_steps = softmax_cross_entropy_loss(), \
+            optax.sgd(0.1), 11
+        batches = list(self._data(n_batches=num_steps, seed=2))
+
+        def synced(ctx):
+            state = ctx.put_replicated(
+                TrainState.create(_linear_apply, params, tx))
+            step_fn = ctx.make_train_step(loss_fn)
+            history = []
+            for i, batch in enumerate(batches):
+                state, m = step_fn(state, ctx.shard_batch(batch))
+                if (i + 1) % log_every == 0 or i + 1 == num_steps:
+                    history.append((i + 1, float(m["loss"])))
+            return history
+
+        want = XlaRunner(np=8).run(synced)
+        res = XlaRunner(np=8).run(lambda ctx: ctx.fit(
+            loss_fn=loss_fn, params=params, tx=tx, apply_fn=_linear_apply,
+            data=iter(batches), num_steps=num_steps, log_every=log_every))
+        assert [(h["step"], h["loss"]) for h in res["history"]] == want
+        assert all(np.isfinite(h["examples_per_sec_per_chip"])
+                   for h in res["history"])
+
     def test_fit_feed_lookahead_matches_inline(self):
         """feed_lookahead=2 (threaded shard-ahead) must consume the same
         batches in the same order and land on bitwise-identical params as
