@@ -1,7 +1,8 @@
 from .bert import (BertConfig, BertEncoder, BertForSequenceClassification,
                    bert_finetune_loss, glue_loss_fn)
-from .llama import (LlamaConfig, LlamaModel, causal_lm_loss_fn, lora_mask,
-                    lora_optimizer)
+from .lfm2 import Lfm2Config, Lfm2ForCausalLM
+from .llama import LlamaConfig, LlamaModel, lora_mask, lora_optimizer
+from .lm_loss import causal_lm_loss_fn
 from .pretrained import (CheckpointMismatch, cast_float_leaves,
                          import_hf_bert, import_hf_llama,
                          import_keras_inception, import_keras_resnet,
@@ -20,7 +21,7 @@ __all__ = [
     "BertConfig", "BertEncoder", "BertForSequenceClassification",
     "glue_loss_fn", "bert_finetune_loss",
     "LlamaConfig", "LlamaModel", "causal_lm_loss_fn", "lora_mask",
-    "lora_optimizer",
+    "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM",
     "load_pretrained", "import_hf_llama", "import_hf_bert",
     "import_keras_resnet", "import_keras_vgg", "import_keras_inception",
     "import_keras_xception",

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .lm_loss import causal_lm_loss_fn  # noqa: F401  (re-exported)
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -1717,19 +1719,3 @@ def lora_optimizer(learning_rate: float = 1e-4):
     return optax.multi_transform(
         {"lora": optax.adam(learning_rate), "frozen": optax.set_to_zero()},
         labels)
-
-
-def causal_lm_loss_fn():
-    """Next-token loss for RunnerContext.fit: batch = {input_ids} (labels =
-    input_ids shifted left; last position dropped)."""
-    import optax
-
-    def loss_fn(params, apply_fn, batch):
-        ids = batch["input_ids"]
-        logits = apply_fn(params, ids)[:, :-1].astype(jnp.float32)
-        targets = ids[:, 1:]
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets).mean()
-        return loss, {"perplexity": jnp.exp(loss)}
-
-    return loss_fn

@@ -149,7 +149,7 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
     from jax.experimental.pallas import tpu as pltpu
 
     grid = (b * h, s_pad // bq, s_pad // bk)
-    o3, lse2 = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal,
                           sm_scale=sm_scale, seq_len=s),
         grid=grid,
@@ -178,7 +178,10 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, m4)
+        name="flash_attention_fwd",
+    )
+    with jax.named_scope("flash_attention_fwd"):
+        o3, lse2 = call(q3, k3, v3, m4)
     return (o3[:, :s].reshape(b, h, s, d),
             lse2.reshape(b * h, s_pad)[:, :s].reshape(b, h, s))
 
@@ -249,8 +252,10 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
                             sm_scale=sm_scale)
     # vmap over batch then heads; the kv mask is per-batch (broadcast over
     # heads via in_axes=None on the inner vmap)
-    dq, dk, dv = jax.vmap(jax.vmap(bwd, in_axes=(0, 0, 0, 0, 0, 0, None)))(
-        q, k, v, o, lse, do, kv_mask)
+    with jax.named_scope("flash_attention_bwd"):
+        dq, dk, dv = jax.vmap(jax.vmap(
+            bwd, in_axes=(0, 0, 0, 0, 0, 0, None)))(
+                q, k, v, o, lse, do, kv_mask)
     return dq, dk, dv, jnp.zeros_like(kv_mask)
 
 

@@ -12,10 +12,16 @@ Top-1 (Switch) routing with capacity: each token goes to its argmax expert;
 tokens beyond ``capacity_factor * tokens/experts`` at an expert are dropped
 (pass through the residual). The load-balancing auxiliary loss is sowed
 into the ``intermediates`` collection as ``moe_aux_loss``.
+
+:class:`RoutedExperts` is its successor: top-k over sigmoid scores, no token
+ever dropped, told which experts it holds, grouped matrix products over the
+rows sorted by expert. Its cost goes with the assignments that land on the
+held experts, not with tokens x experts x capacity.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -92,6 +98,150 @@ class SwitchMoE(nn.Module):
         self.sow("intermediates", "moe_aux_loss",
                  e * jnp.sum(frac_tokens * mean_probs))
 
+        return out.reshape(b, t, d).astype(x.dtype)
+
+
+def sigmoid_topk_route(h, w_router, expert_bias, k: int, *,
+                        norm_topk_prob: bool = True, scaling: float = 1.0):
+    """``(idx [N, k] int32, w [N, k] float32)`` of ``h [N, D]``: sigmoid
+    scores over every expert in float32 (the product at ``highest``: it is
+    a thousandth of the layer's work, and near-ties decide which expert
+    runs); the top ``k`` of score + ``expert_bias``, the bias taking part
+    in the selection only; the weights are the scores themselves, over
+    their sum plus 1e-6 where ``norm_topk_prob``."""
+    s = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    pick = s if expert_bias is None else \
+        s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    _, idx = jax.lax.top_k(pick, k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * scaling
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv_perm):
+    """``x[perm]`` for a permutation whose inverse the caller has: the
+    gradient is a gather by ``inv_perm``, never a scatter-add."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv_perm):
+    return x[perm], (perm, inv_perm)
+
+
+def _permute_rows_bwd(saved, g):
+    perm, inv_perm = saved
+    return g[inv_perm], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
+    """The held experts' part of a routed SwiGLU layer, and its counters.
+
+    ``h [N, D]``; ``idx, w [N, k]`` from the router, over ALL experts;
+    ``w1, w3 [H, D, F]`` and ``w2 [H, F, D]`` are the H held experts
+    ``first_held .. first_held + H - 1``. Returns ``sum over the picks e of
+    a token that are held of w_e * E_e(h)``, ``[N, D]`` in ``h``'s type:
+    what the absent experts would add is left out.
+
+    No token is dropped. The ``N x k`` assignments are sorted by expert
+    (those of absent experts last), their rows gathered, and each
+    projection is ONE grouped product over the held experts' groups
+    (``jax.lax.ragged_dot``). Shapes are static: the row buffer holds all
+    ``N x k`` assignments, the worst case, and the rows past the held
+    groups belong to no group.
+    """
+    n, k = idx.shape
+    held = w1.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        flat = idx.reshape(n * k) - first_held
+        is_held = (flat >= 0) & (flat < held)
+        local = jnp.where(is_held, flat, held)          # absent sort last
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv_order = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
+            jnp.int32)
+        n_held = jnp.sum(group_sizes)
+        live = (jnp.arange(n * k, dtype=jnp.int32) < n_held)[:, None]
+        # a grouped product leaves the rows past its groups unwritten, going
+        # forward and going backward: what enters and what leaves the experts
+        # is masked, so neither the result nor h's gradient reads them
+        rows = jnp.where(live, _permute_rows(
+            jnp.repeat(h, k, axis=0), order, inv_order), 0)
+    with jax.named_scope("moe_experts"):
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
+                                preferred_element_type=h.dtype)
+        a = dot(rows, w1.astype(h.dtype))
+        b = dot(rows, w3.astype(h.dtype))
+        y = dot(jax.nn.silu(a) * b, w2.astype(h.dtype))
+    with jax.named_scope("moe_combine"):
+        y = jnp.where(live, y, 0)
+        y = _permute_rows(y, inv_order, order).reshape(n, k, -1)
+        wk = jnp.where(is_held.reshape(n, k), w, 0.0)
+        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), wk)
+    sizes = group_sizes.astype(jnp.float32)
+    assigned_here = jnp.sum(is_held)
+    counters = {
+        "moe_assignments": jnp.float32(n * k),
+        "moe_assignments_held": assigned_here.astype(jnp.float32),
+        "moe_held_load_max": jnp.max(sizes),
+        "moe_held_load_mean": jnp.mean(sizes),
+        # every held assignment has a row in a group: the buffer is N x k
+        "moe_dropped": (assigned_here - n_held).astype(jnp.float32)}
+    return out.astype(h.dtype), counters
+
+
+class RoutedExperts(nn.Module):
+    """No-drop top-k routed SwiGLU experts: ``(B, T, D) -> (B, T, D)``.
+
+    The router scores all ``num_experts``; this layer holds the experts
+    ``held[0] .. held[0] + held[1] - 1`` (``None``: all of them) and returns
+    their part of the result (:func:`held_experts_ffn`). ``expert_bias``
+    moves the selection only, takes no gradient, and no rule here moves it.
+    Parameters: ``router/kernel``, ``expert_bias``, and ``experts/{w1,w3,w2}``
+    with a leading held-experts axis (``moe_rules`` shards it over ``ep``).
+    The layer's counters are summed into the ``counters`` collection.
+    """
+    num_experts: int
+    top_k: int
+    d_ff: int
+    held: tuple | None = None
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        first, held = self.held or (0, self.num_experts)
+        init = nn.initializers.normal(0.02)
+        xf = x.reshape(b * t, d).astype(self.dtype)
+        w_router = self.param("router", lambda k, s: {
+            "kernel": init(k, s)}, (d, self.num_experts))["kernel"]
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (self.num_experts,)) if self.use_expert_bias \
+            else None
+        experts = self.param("experts", lambda k, _: {
+            n: init(kk, s) for n, kk, s in zip(
+                ("w1", "w3", "w2"), jax.random.split(k, 3),
+                ((held, d, self.d_ff), (held, d, self.d_ff),
+                 (held, self.d_ff, d)))}, None)
+        with jax.named_scope("moe_router"):
+            idx, w = sigmoid_topk_route(
+                xf, w_router, bias, self.top_k,
+                norm_topk_prob=self.norm_topk_prob,
+                scaling=self.routed_scaling_factor)
+        out, counters = held_experts_ffn(
+            xf, idx, w, experts["w1"], experts["w3"], experts["w2"], first)
+        for name, v in counters.items():
+            self.sow("counters", name, v, init_fn=lambda: jnp.float32(0),
+                     reduce_fn=lambda a, c: a + c)
         return out.reshape(b, t, d).astype(x.dtype)
 
 

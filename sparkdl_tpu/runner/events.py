@@ -59,6 +59,9 @@ log = logging.getLogger("sparkdl_tpu.runner")
 
 RECORDER_DIR_ENV = "SPARKDL_EVENT_DIR"
 RING_ENV = "SPARKDL_EVENT_RING"
+# what every record says of itself: an attribute of these names would
+# overwrite it (``event(name, step=..., **metrics)`` filters a user's keys)
+RECORD_KEYS = ("name", "ph", "t", "rank", "step")
 STREAM_CAP_ENV = "SPARKDL_EVENT_MAX_MB"
 # Causal trace context (ISSUE 17): the driver mints one run-level trace id
 # and ships it to every rank; each gang attempt/resize gets a parent span

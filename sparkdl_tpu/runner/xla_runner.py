@@ -494,6 +494,12 @@ class RunnerContext:
                     m["examples_per_sec_per_chip"] = \
                         meter.recent_examples_per_sec() / max(self.size, 1)
                     logger.log(step, m)
+                    # the boundary's metrics in the ring too, the loss
+                    # function's own counters among them: per-layer readers
+                    # take them from there
+                    events.event("step_metrics", step=step, **{
+                        k: v for k, v in m.items()
+                        if k not in events.RECORD_KEYS})
                     history.append({"step": step, **m})
             except BaseException as e:
                 e._sparkdl_retiring = (d.index, d.cursor)

@@ -822,6 +822,33 @@ class TestLossFetchSpan:
             order.index(("step_compute", 10)) + 1
 
 
+class TestStepMetricsEvent:
+    """ISSUE 29: each log boundary's metrics land in the ring, the loss
+    function's own keys among them."""
+
+    def test_fit_records_one_step_metrics_event_per_boundary(self):
+        def loss_fn(params, apply_fn, batch):
+            loss, aux = softmax_cross_entropy_loss()(params, apply_fn, batch)
+            # "name" would overwrite the record's own: it is left out
+            return loss, {**aux, "rows_seen": np.float32(16), "name": 7.0}
+
+        rec = events.get_recorder()
+        res = XlaRunner(np=8).run(lambda ctx: ctx.fit(
+            loss_fn=loss_fn, params=_params(), tx=optax.sgd(0.1),
+            apply_fn=_linear_apply, data=_data(), num_steps=7, log_every=3))
+        got = [e for e in rec.tail() if e["name"] == "step_metrics"]
+        assert [e["step"] for e in got] == [3, 6, 7]
+        assert all(e["ph"] == "P" and e["rows_seen"] == 16.0 for e in got)
+        assert [e["loss"] for e in got] == [h["loss"] for h in res["history"]]
+        assert all("examples_per_sec_per_chip" in e for e in got)
+        # each follows its boundary's loss_fetch directly
+        names = [(e["name"], e.get("step")) for e in rec.tail()
+                 if e["name"] == "step_metrics" or
+                 (e["name"] == "loss_fetch" and e["ph"] == "E")]
+        assert names == [(n, s) for s in (3, 6, 7)
+                         for n in ("loss_fetch", "step_metrics")]
+
+
 class TestStepRetire:
     """ISSUE 28: fit retires each step one step behind its dispatch. Ring
     order only — no timing."""
