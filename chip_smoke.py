@@ -252,10 +252,22 @@ def _cache_ref(q, k_all, v_all, qpos, pads):
             qpos, pads, cfg, jnp.float32)
 
 
-def check_flash_attention(rng, *, interpret, seq, heads, head_dim) -> dict:
-    """Causal prefill at S=seq. No default engine path reaches this
-    kernel — chunked prefill attends dense-vs-cache — so it is called
-    directly."""
+def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
+                          grad_seqs=(2048, 8192), grad_heads=64,
+                          grad_head_dim=64, dense_heads=2) -> dict:
+    """Causal prefill at S=seq, and the gradient through the backward
+    kernel pair at the training cell's head shape (``grad_heads`` heads of
+    ``grad_head_dim``, bf16) at each of ``grad_seqs``. No default engine
+    path reaches this kernel — chunked prefill attends dense-vs-cache — so
+    it is called directly.
+
+    The gradient's reference is dense float32 attention over the same bf16
+    values; its scores are [heads, S, S] float32 (17 GB for 64 heads at
+    8192), so it is taken over the first ``dense_heads`` heads — heads do
+    not mix, so those heads of the full call are held to it exactly. The
+    record gives each gradient's largest gap as a share of the reference's
+    largest entry (``max_err``, held to the kernels' tolerance) and the
+    ratio of the 2-norms."""
     import jax
     import jax.numpy as jnp
 
@@ -267,7 +279,34 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim) -> dict:
     with jax.default_matmul_precision("highest"):
         want = dense_attention(*(jnp.asarray(x, jnp.float32)
                                  for x in (q, k, v)), True)
-    return {"flash_attention": {"S": seq, "max_err": _max_err(got, want)}}
+    out = {"flash_attention": {"S": seq, "max_err": _max_err(got, want)}}
+
+    def grads(attn, *qkvw):   # all four are arguments: none a constant
+        return jax.jit(jax.grad(
+            lambda a, b, c, w: (attn(a, b, c).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2)))(*qkvw)
+
+    for s in grad_seqs:
+        q, k, v, w = (jnp.asarray(rng.randn(1, grad_heads, s, grad_head_dim),
+                                  jnp.bfloat16) for _ in range(4))
+        got = grads(lambda a, b, c: flash_attention(
+            a, b, c, True, interpret=interpret), q, k, v, w)
+        with jax.default_matmul_precision("highest"):
+            want = grads(lambda a, b, c: dense_attention(a, b, c, True),
+                         *(jnp.asarray(x[:, :dense_heads], jnp.float32)
+                           for x in (q, k, v, w)))
+        rec = {"S": s, "heads": grad_heads, "max_err": 0.0}
+        for name, g, r in zip(("dq", "dk", "dv"), got, want):
+            assert g.dtype == jnp.bfloat16 and np.isfinite(
+                np.asarray(g, np.float32)).all(), name
+            r = np.asarray(r)
+            g = np.asarray(g[:, :dense_heads], np.float32)
+            rec[f"{name}_norm_ratio"] = float(
+                np.linalg.norm(g) / np.linalg.norm(r))
+            rec["max_err"] = max(rec["max_err"],
+                                 float(np.abs(g - r).max() / np.abs(r).max()))
+        out[f"flash_attention_grad_S{s}"] = rec
+    return out
 
 
 def check_flash_decode(rng, *, interpret, slots, heads, kv_heads, head_dim,
@@ -330,16 +369,19 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                   heads: int = 16, kv_heads: int = 8, head_dim: int = 128,
                   max_len: int = 2048, block_size: int = 16,
                   verify_window: int = 5, kv_dtypes=(None, "int8"),
-                  atol: float = KERNEL_ATOL) -> dict:
+                  atol: float = KERNEL_ATOL, **flash_grad) -> dict:
     """Each Pallas kernel against the dense reference at the shapes the
-    server phase serves. ``interpret`` is passed to every call explicitly:
+    server phase serves (and, for the flash kernel's backward pair, the
+    training cell's: ``flash_grad`` overrides ``check_flash_attention``'s
+    ``grad_*`` sizes). ``interpret`` is passed to every call explicitly:
     False compiles through Mosaic, True is the CPU test's interpreter."""
     rng = np.random.RandomState(0)
     shape = dict(interpret=interpret, slots=slots, heads=heads,
                  kv_heads=kv_heads, head_dim=head_dim, max_len=max_len)
     checks = {
         **check_flash_attention(rng, interpret=interpret, seq=seq,
-                                heads=heads, head_dim=head_dim),
+                                heads=heads, head_dim=head_dim,
+                                **flash_grad),
         **check_flash_decode(rng, **shape)}
     for kv in kv_dtypes:
         checks.update(check_paged_flash_decode(

@@ -22,10 +22,21 @@ Design (the standard streaming-softmax factorization, written for the MXU):
 - the logsumexp output is blocked (1, BQ) per q-tile program — every store
   is a full-block write, no dynamic lane-dim slicing (round-1 advisor
   flagged the previous ``pl.ds`` store as a Mosaic alignment risk).
-- backward: custom_vjp with blockwise recompute (lax.scan over KV tiles in
-  plain jax) from the saved (o, logsumexp) — activations are O(S·D), the
-  flash-attention memory contract, and XLA keeps the per-tile recompute on
-  the MXU.
+- backward: custom_vjp over two more kernels, ``flash_attention_bwd_dkv``
+  (grid (batch·heads, KV tiles, Q tiles): float32 dk/dv accumulate in VMEM
+  while Q/dO tiles stream) and ``flash_attention_bwd_dq`` (the forward's
+  grid; dq accumulates). Each recomputes its score tile from the saved
+  logsumexp (``p = exp(q·kᵀ·scale − lse)``), so activations stay O(S·D) —
+  the flash-attention memory contract — and no score-sized array is ever
+  an HBM operand. The MXU takes the inputs' dtype with float32
+  accumulation; ``p`` and ``ds`` are rounded to it before their second
+  products, as ``parallel.ring_attention.dense_attention`` rounds ``p``;
+  lse, ``delta = rowsum(do·o)``, the exponent and the accumulators are
+  float32. Causal dead tile pairs are skipped AND not fetched (their index
+  is clamped to the nearest live tile's); a pair wholly below the diagonal
+  skips the causal compare. The dkv kernel holds its score tile transposed,
+  (BK, BQ), so the row statistics broadcast from their lane-oriented
+  blocks and no score-sized transpose is needed in either kernel.
 
 ``interpret=True`` (or platform != tpu) runs the same kernel through the
 Pallas interpreter — how CPU tests validate kernel semantics; a TPU-gated
@@ -115,37 +126,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
         lse_ref[0, 0] = (m + jnp.log(safe_l))[None, :]
 
 
-def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
-         interpret: bool):
-    b, h, s, d = q.shape
-    # Blocks never shrink below the 128-lane alignment: a sequence shorter
-    # than the block is PADDED up to it instead (the seq_len mask keeps the
-    # math exact). Shrinking to odd sizes (min(block, s) with s=37) would
-    # hand Mosaic 37-wide score tiles — an alignment hazard the interpret-
-    # mode tests cannot catch. Callers may still pass smaller explicit
-    # blocks for interpret-mode tests.
+def _tiles(s: int, block_q: int, block_k: int):
+    """(bq, bk, s_pad) for a sequence of ``s``. Blocks never shrink below
+    the 128-lane alignment: a sequence shorter than the block is PADDED up
+    to it instead (the seq_len mask keeps the math exact). Shrinking to odd
+    sizes (min(block, s) with s=37) would hand Mosaic 37-wide score tiles —
+    an alignment hazard the interpret-mode tests cannot catch. Callers may
+    still pass smaller explicit blocks for interpret-mode tests."""
     bq = min(block_q, pl.cdiv(s, _LANES) * _LANES)
     bk = min(block_k, pl.cdiv(s, _LANES) * _LANES)
     unit = math.lcm(bq, bk)
-    s_pad = pl.cdiv(s, unit) * unit
+    return bq, bk, pl.cdiv(s, unit) * unit
+
+
+def _pad_rows(x3, s_pad: int):
+    """[B·H, S, D] zero-padded to S = ``s_pad``."""
+    return jnp.pad(x3, ((0, 0), (0, s_pad - x3.shape[1]), (0, 0)))
+
+
+def _blocked_rows(x2, s_pad: int, block: int):
+    """[B·H, S] per-position values -> the pre-blocked 4-D float32 stream
+    (B·H, S/block, 1, block), zero-padded: each (1, 1, 1, block) block's
+    trailing dims (1, block) EQUAL the array dims, so the layout is
+    Mosaic-legal for ANY block and a kernel reads or writes a plain 2-D
+    (1, block) lane-oriented tile (see the lse comment in _fwd_kernel for
+    the rejected flat layouts)."""
+    x2 = jnp.pad(x2.astype(jnp.float32), ((0, 0), (0, s_pad - x2.shape[1])))
+    return x2.reshape(x2.shape[0], s_pad // block, 1, block)
+
+
+def _per_head(kv_mask, h: int):
+    """[B, S] 0/1 kv mask -> [B·H, S] (tiny next to K/V tiles)."""
+    b, s = kv_mask.shape
+    return jnp.broadcast_to(kv_mask[:, None, :], (b, h, s)).reshape(b * h, s)
+
+
+def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
+         interpret: bool):
+    b, h, s, d = q.shape
+    bq, bk, s_pad = _tiles(s, block_q, block_k)
     sm_scale = 1.0 / math.sqrt(d)
-    q3 = q.reshape(b * h, s, d)
-    k3 = k.reshape(b * h, s, d)
-    v3 = v.reshape(b * h, s, d)
-    # [B, S] 0/1 kv mask → pre-blocked 4-D (B*H, S/BK, 1, BK) f32 stream
-    # (tiny next to K/V tiles): each (1, 1, 1, BK) block's trailing dims
-    # (1, BK) EQUAL the array dims, so the layout is Mosaic-legal for
-    # ANY BK and the kernel reads a plain 2-D (1, BK) lane-oriented tile
-    # (see the lse comment in _fwd_kernel for the rejected flat layouts).
-    m2 = jnp.broadcast_to(kv_mask.astype(jnp.float32)[:, None, :],
-                          (b, h, s)).reshape(b * h, s)
-    if s_pad != s:
-        padding = ((0, 0), (0, s_pad - s), (0, 0))
-        q3 = jnp.pad(q3, padding)
-        k3 = jnp.pad(k3, padding)
-        v3 = jnp.pad(v3, padding)
-        m2 = jnp.pad(m2, ((0, 0), (0, s_pad - s)))
-    m4 = m2.reshape(b * h, s_pad // bk, 1, bk)
+    q3, k3, v3 = (_pad_rows(t.reshape(b * h, s, d), s_pad) for t in (q, k, v))
+    m4 = _blocked_rows(_per_head(kv_mask, h), s_pad, bk)
     from jax.experimental.pallas import tpu as pltpu
 
     grid = (b * h, s_pad // bq, s_pad // bk)
@@ -186,51 +208,212 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
             lse2.reshape(b * h, s_pad)[:, :s].reshape(b, h, s))
 
 
-def _bwd_one_head(q, k, v, o, lse, do, kv_mask, causal: bool, block_k: int,
-                  sm_scale: float):
-    """Blockwise backward for one (S, D) head, plain jax (runs under vmap).
+def _tile_is_live(qi, ki, block_q: int, block_k: int):
+    """Under ``causal``: whether Q tile ``qi`` has any row at or past KV
+    tile ``ki``'s first column — the one rule both backward kernels'
+    ``pl.when`` and index maps follow (ints in, bool out; traced in, traced
+    out)."""
+    return ki * block_k <= qi * block_q + block_q - 1
 
-    Recomputes P tile-by-tile from the saved logsumexp; O(S·D) residents.
-    """
-    s_len, d = q.shape
-    bk = min(block_k, s_len)
-    n_blocks = s_len // bk if s_len % bk == 0 else s_len // bk + 1
-    pad = n_blocks * bk - s_len
-    if pad:
-        k = jnp.pad(k, ((0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, pad), (0, 0)))
-    kb = k.reshape(n_blocks, bk, d)
-    vb = v.reshape(n_blocks, bk, d)
-    maskp = jnp.pad(kv_mask.astype(jnp.float32), (0, pad)) if pad \
-        else kv_mask.astype(jnp.float32)
-    mb = maskp.reshape(n_blocks, bk)
 
-    qf = q.astype(jnp.float32) * sm_scale
-    dof = do.astype(jnp.float32)
-    delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)   # (S,)
-    row_ids = jnp.arange(s_len)
+def _first_live_q(ki, block_q: int, block_k: int):
+    """Under ``causal`` the Q tiles dead for KV tile ``ki`` are the leading
+    ones: the first live one."""
+    return (ki * block_k) // block_q
 
-    def per_block(dq_acc, j):
-        kj = kb[j].astype(jnp.float32)
-        vj = vb[j].astype(jnp.float32)
-        s_tile = qf @ kj.T                                   # (S, BK)
-        col_ids = j * bk + jnp.arange(bk)
-        mask = (col_ids[None, :] < s_len) & (mb[j][None, :] > 0)
-        if causal:
-            mask = mask & (col_ids[None, :] <= row_ids[:, None])
-        p = jnp.where(mask, jnp.exp(s_tile - lse[:, None]), 0.0)
-        dv_j = p.T @ dof                                     # (BK, D)
-        dp = dof @ vj.T                                      # (S, BK)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_j = ds.T @ (q.astype(jnp.float32))                # (BK, D)
-        dq_acc = dq_acc + ds @ kj
-        return dq_acc, (dk_j, dv_j)
 
-    dq, (dk_b, dv_b) = jax.lax.scan(
-        per_block, jnp.zeros((s_len, d), jnp.float32), jnp.arange(n_blocks))
-    dk = dk_b.reshape(n_blocks * bk, d)[:s_len]
-    dv = dv_b.reshape(n_blocks * bk, d)[:s_len]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+def _last_live_kv(qi, block_q: int, block_k: int):
+    """Under ``causal`` the KV tiles dead for Q tile ``qi`` are the trailing
+    ones: the last live one."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract both operands' last dim
+
+
+def _as_column(row):
+    """(1, N) lane-oriented row -> (N, _LANES) with every lane of row n
+    holding ``row[0, n]``: the per-row statistics arrive in ``lse``'s
+    lane-oriented layout and a score tile needs them down its sublanes.
+    A sublane broadcast and one aligned 2-D transpose, done once per
+    accumulator (not once per tile pair) into VMEM scratch."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _when_live(update, qi, ki, block_q: int, block_k: int, causal: bool):
+    """Run ``update(on_diagonal)`` for a live tile pair and nothing for a
+    dead one. Only a pair that straddles the diagonal (some column past
+    some row) pays for the causal compare; a pair wholly below it is as
+    unmasked as a non-causal one."""
+    if not causal:
+        update(False)
+        return
+    live = _tile_is_live(qi, ki, block_q, block_k)
+    straddles = ki * block_k + block_k - 1 > qi * block_q
+    pl.when(live & straddles)(functools.partial(update, True))
+    # no column past any row: wholly below the diagonal, so live
+    pl.when(jnp.logical_not(straddles))(functools.partial(update, False))
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    mask_ref, dk_ref, dv_ref, dk_acc, dv_acc, valid_ref, *,
+                    causal: bool, sm_scale: float, seq_len: int):
+    """Grid = (B·H, KV tiles, Q tiles), Q innermost: one K/V tile stays in
+    VMEM while the Q/dO tiles stream past it and float32 ``dk``/``dv``
+    accumulate in scratch. The score tile is held TRANSPOSED, (BK, BQ):
+    ``lse`` and ``delta`` then broadcast down the sublanes straight from
+    their (1, BQ) blocks, and all four products are plain ``a @ b`` /
+    ``a @ b.T`` — no score-sized transpose."""
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    ki, qi = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        valid = (cols < seq_len) & (mask_ref[0, 0] > 0)
+        valid_ref[:] = _as_column(valid.astype(jnp.float32))
+
+    def _update(on_diagonal: bool):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        st = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
+        keep = valid_ref[:, :1] > 0                         # (BK, 1)
+        if on_diagonal:
+            shape = (block_k, block_q)
+            keep = keep & (
+                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        # exp first, select after: a masked entry may overflow (a fully
+        # masked row's lse is NEG_INF) and the select drops it
+        pt = jnp.where(keep, jnp.exp(st - lse_ref[0, 0]), 0.0)   # (BK, BQ)
+        dv_acc[:] += jnp.dot(pt.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0])    # sm_scale: once, at the end
+        dk_acc[:] += jnp.dot(dst.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    _when_live(_update, qi, ki, block_q, block_k, causal)
+
+    @pl.when(qi == pl.num_programs(2) - 1)   # the last Q tile is always live
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                   dq_ref, dq_acc, lse_col, delta_col, *, causal: bool,
+                   sm_scale: float, seq_len: int):
+    """Grid = (B·H, Q tiles, KV tiles), KV innermost, as the forward: one
+    Q/dO tile stays while K/V tiles stream and float32 ``dq`` accumulates.
+    The score tile is (BQ, BK) here, so ``kv_mask`` broadcasts from its
+    (1, BK) block and ``lse``/``delta`` are turned into columns once per
+    Q tile."""
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+        lse_col[:] = _as_column(lse_ref[0, 0])
+        delta_col[:] = _as_column(delta_ref[0, 0])
+
+    def _update(on_diagonal: bool):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        keep = (cols < seq_len) & (mask_ref[0, 0] > 0)      # (1, BK)
+        if on_diagonal:
+            shape = (block_q, block_k)
+            keep = keep & (
+                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        p = jnp.where(keep, jnp.exp(s - lse_col[:, :1]), 0.0)    # (BQ, BK)
+        dp = jax.lax.dot_general(
+            do, v, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_col[:, :1])
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    _when_live(_update, qi, ki, block_q, block_k, causal)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+
+def _bwd(q, k, v, kv_mask, o, lse, do, causal: bool, block_q: int,
+         block_k: int, interpret: bool):
+    """dq, dk, dv by the two kernels above, from the forward's residuals.
+    Tiles are recomputed from ``lse``; nothing score-sized touches HBM."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    bq, bk, s_pad = _tiles(s, block_q, block_k)
+    sm_scale = 1.0 / math.sqrt(d)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    q3, k3, v3, do3 = (_pad_rows(t.reshape(b * h, s, d), s_pad)
+                       for t in (q, k, v, do))
+    # padded query rows: lse 0 keeps p finite, do = delta = 0 keep it unused
+    lse4 = _blocked_rows(lse.reshape(b * h, s), s_pad, bq)
+    delta4 = _blocked_rows(delta.reshape(b * h, s), s_pad, bq)
+    m4 = _blocked_rows(_per_head(kv_mask, h), s_pad, bk)
+    n_q, n_kv = s_pad // bq, s_pad // bk
+    kernel_args = dict(causal=causal, sm_scale=sm_scale, seq_len=s)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    # A dead pair does no work and fetches nothing: its index is clamped to
+    # the nearest live tile's, whose block the pipeline already holds.
+    def q_tile(bh, j, i):
+        return (bh, jnp.maximum(i, _first_live_q(j, bq, bk)) if causal else i)
+
+    def kv_tile(bh, i, j):
+        return (bh, jnp.minimum(j, _last_live_kv(i, bq, bk)) if causal else j)
+
+    rows_q = pl.BlockSpec((1, bq, d), lambda bh, j, i: (*q_tile(bh, j, i), 0))
+    stat_q = pl.BlockSpec((1, 1, 1, bq),
+                          lambda bh, j, i: (*q_tile(bh, j, i), 0, 0))
+    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
+    dk3, dv3 = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **kernel_args),
+        grid=(b * h, n_kv, n_q),
+        in_specs=[rows_q, rows_kv, rows_kv, rows_q, stat_q, stat_q,
+                  pl.BlockSpec((1, 1, 1, bk), lambda bh, j, i: (bh, j, 0, 0))],
+        out_specs=[rows_kv, rows_kv],
+        out_shape=[jax.ShapeDtypeStruct((b * h, s_pad, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h, s_pad, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),        # dk
+                        pltpu.VMEM((bk, d), jnp.float32),        # dv
+                        pltpu.VMEM((bk, _LANES), jnp.float32)],  # key validity
+        compiler_params=semantics, interpret=interpret,
+        name="flash_attention_bwd_dkv",
+    )(q3, k3, v3, do3, lse4, delta4, m4)
+
+    rows_q = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+    stat_q = pl.BlockSpec((1, 1, 1, bq), lambda bh, i, j: (bh, i, 0, 0))
+    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, i, j: (*kv_tile(bh, i, j), 0))
+    dq3 = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **kernel_args),
+        grid=(b * h, n_q, n_kv),
+        in_specs=[rows_q, rows_kv, rows_kv, rows_q, stat_q, stat_q,
+                  pl.BlockSpec((1, 1, 1, bk),
+                               lambda bh, i, j: (*kv_tile(bh, i, j), 0, 0))],
+        out_specs=rows_q,
+        out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),        # dq
+                        pltpu.VMEM((bq, _LANES), jnp.float32),   # lse, columns
+                        pltpu.VMEM((bq, _LANES), jnp.float32)],  # delta
+        compiler_params=semantics, interpret=interpret,
+        name="flash_attention_bwd_dq",
+    )(q3, k3, v3, do3, lse4, delta4, m4)
+    return tuple(t[:, :s].reshape(b, h, s, d) for t in (dq3, dk3, dv3))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -247,15 +430,9 @@ def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret):
 
 def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     q, k, v, kv_mask, o, lse = res
-    sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    bwd = functools.partial(_bwd_one_head, causal=causal, block_k=block_k,
-                            sm_scale=sm_scale)
-    # vmap over batch then heads; the kv mask is per-batch (broadcast over
-    # heads via in_axes=None on the inner vmap)
     with jax.named_scope("flash_attention_bwd"):
-        dq, dk, dv = jax.vmap(jax.vmap(
-            bwd, in_axes=(0, 0, 0, 0, 0, 0, None)))(
-                q, k, v, o, lse, do, kv_mask)
+        dq, dk, dv = _bwd(q, k, v, kv_mask, o, lse, do, causal, block_q,
+                          block_k, interpret)
     return dq, dk, dv, jnp.zeros_like(kv_mask)
 
 

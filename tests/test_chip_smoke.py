@@ -48,8 +48,10 @@ def test_scorer_phase_tiny():
 def test_kernel_phase_tiny_interpreted():
     rec = chip_smoke.phase_kernels(
         interpret=True, seq=256, slots=3, heads=4, kv_heads=2,
-        head_dim=32, max_len=128, block_size=8, verify_window=3)
+        head_dim=32, max_len=128, block_size=8, verify_window=3,
+        grad_seqs=(256,), grad_heads=3, grad_head_dim=32)
     assert rec["interpret"] is True
+    assert abs(rec["flash_attention_grad_S256"]["dk_norm_ratio"] - 1) < 1e-2
     assert {"flash_attention", "flash_decode", "paged_flash_decode_bf16_S1",
             "paged_flash_decode_int8_S3"} <= set(rec)
 

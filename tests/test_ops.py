@@ -5,6 +5,7 @@ the semantics the compiled TPU kernel executes.
 """
 
 import os
+import sys
 
 import numpy as np
 import pytest
@@ -15,6 +16,9 @@ import jax.numpy as jnp
 from sparkdl_tpu.ops import flash_attention
 from sparkdl_tpu.parallel.ring_attention import dense_attention
 from sparkdl_tpu.utils.platform import is_tpu_backend
+
+# the module itself: ``sparkdl_tpu.ops.flash_attention`` names the function
+_fa = sys.modules["sparkdl_tpu.ops.flash_attention"]
 
 # Compiled-on-TPU runs (SPARKDL_TEST_PLATFORM=tpu) compare against a dense
 # reference that XLA computes with the MXU's default f32 precision (bf16
@@ -80,6 +84,167 @@ def test_gradients_match_dense(causal):
     gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=BWD_ATOL)
+
+
+def _len_mask(s, lens):
+    return jnp.asarray((np.arange(s)[None, :] < np.asarray(lens)[:, None])
+                       .astype(np.float32))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kvmask"])
+@pytest.mark.parametrize("s,blocks", [
+    (128, (32, 32)), (128, (64, 32)), (128, (32, 64)),   # block multiples
+    (37, (None, None)), (200, (None, None)),             # ragged, defaults
+    (100, (64, 32)),                                     # ragged, explicit
+], ids=lambda x: str(x).replace(" ", ""))
+def test_backward_kernels_match_dense(causal, masked, s, blocks):
+    """dq, dk, dv of the Pallas backward pair, through the public
+    ``flash_attention``, against the dense reference: every mask the
+    forward takes (causal, a padded tail, S padded to the block), equal and
+    unequal blocks. ``kv_mask`` itself gets a zero cotangent."""
+    q, k, v = _rand_qkv(s=s, d=16, seed=s)
+    kv_mask = _len_mask(s, [s, max(1, (2 * s) // 5)]) if masked else None
+    bq, bk = blocks
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+
+    def lf(a, b, c, m):
+        return (flash_attention(a, b, c, causal, kv_mask=m, block_q=bq,
+                                block_k=bk) * w).sum()
+
+    def lr(a, b, c):
+        if kv_mask is None:
+            return (dense_attention(a, b, c, causal) * w).sum()
+        return (_masked_dense(a, b, c, kv_mask, causal) * w).sum()
+
+    argnums = (0, 1, 2, 3) if masked else (0, 1, 2)
+    gf = jax.grad(lf, argnums=argnums)(q, k, v, kv_mask)
+    gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=BWD_ATOL, err_msg=name)
+    if masked:
+        np.testing.assert_array_equal(np.asarray(gf[3]), 0.0)
+
+
+def test_backward_bf16_inputs():
+    """bf16 in, bf16 gradients out; ``p`` and ``ds`` enter their second
+    products rounded to bf16 (the dense path's own rounding of ``p``), so
+    against the float32 dense gradient of the same bf16 values the gap is
+    bf16's: 2**-8 relative on an O(0.3) gradient, with room."""
+    q, k, v = [x.astype(jnp.bfloat16) for x in _rand_qkv(s=256, d=64)]
+
+    def lf(a, b, c):
+        return (flash_attention(a, b, c, True, block_q=128, block_k=128)
+                .astype(jnp.float32) ** 2).sum()
+
+    def lr(a, b, c):
+        return (dense_attention(a, b, c, True) ** 2).sum()
+
+    gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):   # the chip's default
+        gr = jax.grad(lr, argnums=(0, 1, 2))(       # rounds p to bf16 too
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, a, b in zip(("dq", "dk", "dv"), gf, gr):
+        assert a.dtype == jnp.bfloat16, name
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        np.testing.assert_allclose(a, b, atol=2e-2 if is_tpu_backend()
+                                   else 1e-2, err_msg=name)
+        rel = np.linalg.norm(a - b) / np.linalg.norm(b)
+        assert rel < 1e-2, (name, rel)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_backward_fully_masked_rows(causal):
+    """Rows with no attendable key (batch 0: every key masked; batch 1
+    under ``causal``: the first rows see only masked keys) output zeros, so
+    their ``dq`` is zero, they add nothing to ``dk``/``dv`` — and their
+    saved ``lse`` of NEG_INF puts no NaN or inf anywhere."""
+    s = 64
+    q, k, v = _rand_qkv(s=s, d=16, seed=13)
+    kv_mask = jnp.asarray(np.stack([np.zeros(s), np.r_[np.zeros(20),
+                                                       np.ones(s - 20)]])
+                          .astype(np.float32))
+
+    def lf(a, b, c):
+        return (flash_attention(a, b, c, causal, kv_mask=kv_mask,
+                                block_q=32, block_k=16) ** 2).sum()
+
+    dq, dk, dv = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
+    for g in (dq, dk, dv):
+        assert np.isfinite(np.asarray(g)).all()
+    for g in (dq, dk, dv):
+        np.testing.assert_array_equal(np.asarray(g[0]), 0.0)
+    if causal:
+        np.testing.assert_array_equal(np.asarray(dq[1, :, :20]), 0.0)
+    gr = jax.grad(lambda a, b, c: (_masked_dense(
+        a[1:], b[1:], c[1:], kv_mask[1:], causal)[:, :, 20 if causal else 0:]
+        ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip((dq, dk, dv), gr):
+        np.testing.assert_allclose(np.asarray(a[1]), np.asarray(b[1]),
+                                   atol=BWD_ATOL)
+
+
+def test_causal_live_tile_rule():
+    """The one rule both backward kernels' ``pl.when`` and index maps
+    follow: at the cell's shape (S = 8192, 512-blocks) 136 of the 256 tile
+    pairs are live; the clamped index of a dead pair is the nearest live
+    tile's and a live pair's is its own."""
+    n = 8192 // 512
+    assert sum(_fa._tile_is_live(i, j, 512, 512)
+               for i in range(n) for j in range(n)) == 136
+    for bq, bk, s in [(512, 512, 8192), (64, 32, 256), (32, 64, 256)]:
+        for i in range(s // bq):
+            for j in range(s // bk):
+                is_live = _fa._tile_is_live(i, j, bq, bk)
+                # some column of the KV tile is at or before some row
+                assert is_live == (j * bk <= i * bq + bq - 1)
+                assert (max(i, _fa._first_live_q(j, bq, bk)) == i) == is_live
+                assert (min(j, _fa._last_live_kv(i, bq, bk)) == j) == is_live
+                assert _fa._tile_is_live(
+                    max(i, _fa._first_live_q(j, bq, bk)), j, bq, bk)
+                assert _fa._tile_is_live(
+                    i, min(j, _fa._last_live_kv(i, bq, bk)), bq, bk)
+
+
+@pytest.mark.parametrize("clamped", [True, False],
+                         ids=["clamped", "unclamped"])
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 64)])
+def test_backward_skips_dead_causal_tiles(bq, bk, clamped, monkeypatch):
+    """A dead tile pair does no work: NaN planted in the first Q tile's
+    q/dO rows, or in the last KV tile's k/v rows, reaches only the tiles
+    that legitimately read them. A kernel that computed a dead pair and
+    masked it afterwards would multiply p = 0 by NaN and poison every
+    row (0 * NaN = NaN). ``unclamped`` takes the index maps' clamp away,
+    so that a dead pair's own (NaN) blocks ARE fetched and the kernels'
+    ``pl.when`` alone has to keep them out; ``clamped`` is the code as it
+    runs."""
+    if not clamped:
+        monkeypatch.setattr(_fa, "_first_live_q", lambda ki, bq, bk: 0)
+        monkeypatch.setattr(_fa, "_last_live_kv", lambda qi, bq, bk: 1 << 20)
+    s, blk = 256, max(bq, bk)
+    q, k, v = _rand_qkv(s=s, d=16, seed=21)
+    w = jnp.asarray(np.random.RandomState(2).randn(*q.shape), jnp.float32)
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda a, b, c: (flash_attention(
+            a, b, c, True, block_q=bq, block_k=bk) * w).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    clean = grads(q, k, v, w)
+    nan = jnp.full((2, 3, blk, 16), jnp.nan)
+    # the dkv kernel: Q tile 0 is dead for every KV tile past the first
+    got = grads(q.at[:, :, :blk].set(nan), k, v, w.at[:, :, :blk].set(nan))
+    for name, a, b in zip(("dq", "dk", "dv"), got, clean):
+        np.testing.assert_allclose(np.asarray(a[:, :, blk:]),
+                                   np.asarray(b[:, :, blk:]),
+                                   atol=1e-6, err_msg=name)
+    # the dq kernel: the last KV tile is dead for every Q tile before the
+    # last (whose own p is NaN, so dk and dv are NaN throughout, rightly)
+    dq = grads(q, k.at[:, :, -blk:].set(nan), v.at[:, :, -blk:].set(nan),
+               w)[0]
+    np.testing.assert_allclose(np.asarray(dq[:, :, :-blk]),
+                               np.asarray(clean[0][:, :, :-blk]), atol=1e-6)
 
 
 def test_bf16_inputs():
@@ -241,3 +406,14 @@ def test_compiled_flash_on_tpu():
         a, k, v, True, interpret=False) ** 2).sum())(q)
     gr = jax.grad(lambda a: (dense_attention(a, k, v, True) ** 2).sum())(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr), atol=5e-2)
+    # the backward pair at the training cell's head shape (64 heads of 64,
+    # bf16) at S = 2048 and 8192, against dense float32: chip_smoke's check
+    import chip_smoke
+    rec = chip_smoke.check_flash_attention(
+        np.random.RandomState(0), interpret=False, seq=2048, heads=16,
+        head_dim=128)
+    for s in (2048, 8192):
+        grad = rec[f"flash_attention_grad_S{s}"]
+        assert grad["max_err"] <= chip_smoke.KERNEL_ATOL, grad
+        for name in ("dq", "dk", "dv"):
+            assert abs(grad[f"{name}_norm_ratio"] - 1) < 5e-3, grad
