@@ -21,11 +21,6 @@ divisible by every world size the run passes through):
    pinned: the same permanently dead rank death-loops the supervisor
    through its whole restart budget (``GangFailure: giving up``).
 
-Also exports :func:`policy_block` — the jax-free policy-level version of
-leg 1 (stdlib workers, same supervisor/chaos/ledger machinery) that
-``bench.py`` runs to put an ``elastic`` block in failure_stats even when
-the jax backend probe is down.
-
 Prints one JSON line and exits 0 on success.
 
 Run: ``JAX_PLATFORMS=cpu python scripts/elastic_smoke.py``
@@ -88,39 +83,6 @@ with open(os.path.join(out_dir, f"result_rank{{rank}}.jsonl"), "a") as f:
         "world": int(os.environ.get("SPARKDL_NUM_PROCESSES", "1"))}})
         + "\\n")
 """
-
-# Jax-free policy worker (bench's elastic block): the same supervisor /
-# chaos / ledger machinery, progress persisted in a tiny state file
-# instead of an orbax checkpoint. fire("worker") at entry gives a
-# decimated slot its re-kill point even when no steps remain.
-_POLICY_WORKER = """
-import json, os, sys
-sys.path.insert(0, {repo!r})
-from sparkdl_tpu.runner import chaos
-from sparkdl_tpu.runner.data import append_ledger
-
-out_dir = sys.argv[1]
-num_steps = int(sys.argv[2])
-chaos.fire("worker")
-rank = int(os.environ.get("SPARKDL_PROCESS_ID", "0"))
-state_path = os.path.join(out_dir, "progress.json")
-start = 0
-try:
-    with open(state_path) as f:
-        start = int(json.load(f)["step"])
-except (OSError, ValueError, KeyError):
-    pass
-for step in range(start, num_steps):
-    chaos.fire("step_start", step=step)
-    if rank == 0:
-        append_ledger(step, {{"epoch": 0, "batch_index": step + 1,
-                              "skip_list": []}})
-        tmp = state_path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump({{"step": step + 1}}, f)
-        os.replace(tmp, state_path)
-"""
-
 
 def _write(out_dir: str, name: str, body: str, **fmt) -> str:
     path = os.path.join(out_dir, name)
@@ -218,31 +180,6 @@ def main() -> int:
         "out_dir": out_dir,
     }))
     return 0 if ok else 1
-
-
-def policy_block(np_: int = 3, num_steps: int = 8,
-                 dead_rank: int = 1) -> dict:
-    """Jax-free elastic policy exercise for BENCH records: a stdlib
-    worker gang loses ``dead_rank`` permanently (``decimate``), the
-    supervisor shrinks, the batch ledger is audited. Returns the
-    ``elastic`` failure_stats block: resizes, final world size,
-    exactly-once verdict — present even when the jax backend probe is
-    down, because nothing here touches jax."""
-    out_dir = tempfile.mkdtemp(prefix="sparkdl-elastic-policy-")
-    ledger_dir = os.path.join(out_dir, "ledger")
-    worker = _write(out_dir, "worker.py", _POLICY_WORKER)
-    plan = FaultPlan([Fault("step_start", "decimate",
-                            at_step=num_steps // 2, rank=dead_rank)])
-    res = supervise(worker, np=np_, args=[out_dir, str(num_steps)],
-                    env={"SPARKDL_BATCH_LEDGER": ledger_dir},
-                    plan=plan, elastic=True, max_restarts=2,
-                    timeout_s=60.0, backoff_s=0.05, poll_s=0.1)
-    exactly_once, replay_consistent, worlds = _audit_ledger(
-        ledger_dir, num_steps, num_steps)
-    return {"resizes": res.resizes, "final_np": res.final_np,
-            "start_np": np_, "restarts": res.restarts,
-            "exactly_once": bool(exactly_once and replay_consistent),
-            "ledger_worlds": worlds}
 
 
 if __name__ == "__main__":

@@ -39,8 +39,7 @@ Measurements per run:
 per-token prefill cost is what makes bucket padding and prefix reuse
 show up in wall time the way they do on hardware) and walks the static
 schedule with the same stub timings — the scheduler win stays
-measurable inside a ``backend_unavailable`` bench record (the
-never-host-blind rule from the host-ingest leg). The stub leg uses a
+measurable with no device backend. The stub leg uses a
 smaller chunk (8) than the CPU llama leg (32): chunking granularity is
 a per-call-overhead tradeoff, and the stub models an async device where
 per-call overhead ≈ 0 while the CPU pays ~10 ms dispatch per jitted
@@ -1191,8 +1190,8 @@ def run_tp_comparison(n_requests: int = 24,
     env = dict(os.environ)
     env["XLA_FLAGS"] = host_device_flags(env.get("XLA_FLAGS", ""), 8)
     env["JAX_PLATFORMS"] = "cpu"
-    # Evidence hygiene (shared with tp_serving_record.py and the
-    # dryrun leg): ambient serving knobs must not reshape the leg —
+    # Evidence hygiene (shared with the dryrun leg): ambient serving
+    # knobs must not reshape the leg —
     # see scrub_serving_env's docstring for why KV_POOL_MB in
     # particular would invert the 1/tp observable.
     from sparkdl_tpu.serving.engine import scrub_serving_env
@@ -1227,9 +1226,8 @@ def run_survivability_comparison(n_requests: int = 24,
     TTFT p99 for both runs, the failover recovery latency (fault to
     first resumed token, off the engine's own ledger), and whether the
     faulted run's greedy streams were token-identical to the clean
-    run's — the exactly-once resume observable ``bench_trend`` gates
-    (``serve_recovery_s`` lower-is-better, and
-    ``serve_failover_token_identical`` must stay 1.0)."""
+    run's — the exactly-once resume observable (``recovery_s``
+    lower-is-better, and ``token_identical`` must stay 1.0)."""
     from sparkdl_tpu.runner import chaos, telemetry
     from sparkdl_tpu.runner.chaos import Fault, FaultPlan
     from sparkdl_tpu.runner.telemetry import histogram_quantile
@@ -1309,8 +1307,7 @@ def run_survivability_comparison(n_requests: int = 24,
         "clean": clean, "faulted": faulted,
         "failovers": faulted["failovers"],
         "recovery_s": faulted["recovery_s"],
-        # float on purpose: bench_trend auto-gates numeric scalars and
-        # skips bools — 1.0 means every stream matched the clean run
+        # 1.0 means every stream matched the clean run
         "token_identical": 1.0 if identical else 0.0,
         "tokens_s_ratio": round(
             faulted["tokens_s"] / clean["tokens_s"], 4)
@@ -1330,9 +1327,9 @@ def run_fleet_comparison(n_requests: int = 24, n_replicas: int = 3,
     co-location win is the whole point of shadow-residency routing.
 
     **Recovery** — an inline fleet run with one unclean replica kill
-    mid-stream: ``fleet_recovery_s`` is kill-to-first-re-admitted-token
-    (bench_trend auto-gates it lower-is-better) and
-    ``fleet_token_identical`` (float; must stay 1.0) is the
+    mid-stream: ``recovery_s`` is kill-to-first-re-admitted-token
+    (lower is better) and
+    ``token_identical`` (float; must stay 1.0) is the
     zero-dup/zero-loss delivery-cursor + greedy-identity gate against a
     clean single-engine run."""
     from sparkdl_tpu.runner import telemetry
@@ -1444,8 +1441,6 @@ def run_fleet_comparison(n_requests: int = 24, n_replicas: int = 3,
                              / rr["reused_tokens"], 4)
         if rr["reused_tokens"] else None,
         "readmissions": fleet.stats["readmissions"],
-        # the two bench_trend-gated scalars (float on purpose — the
-        # trend gate skips bools; _s suffix = auto lower-is-better)
         "recovery_s": recovery_s,
         "token_identical": 1.0 if identical else 0.0,
     }
@@ -1466,7 +1461,7 @@ def run_stub_scheduler_comparison(n_requests: int = 96,
 
 
 def run(mode: str = "llama", rows: int | None = None) -> dict:
-    """Bench entry point (``bench.py --worker serve`` / ``serve_stub``).
+    """Bench entry point (``mode`` "llama" or "stub").
     Env knobs: BENCH_SERVE_REQUESTS / _SLOTS / _MAX_LEN /
     _CONCURRENCY (comma list) / _CHUNK / _STUB_STEP_S /
     _STUB_PREFILL_TOK_S."""

@@ -17,7 +17,7 @@ End-to-end proof on CPU with ``LlamaConfig.tiny``:
    within the documented tolerance gate (mean longest-common-prefix
    fraction >= 0.8 — int8 rounding may legitimately flip a late token
    on the random tiny model, full divergence may not), and the
-   speculative accept-rate delta is reported for bench_trend gating.
+   speculative accept-rate delta is reported.
 
 The closed-loop client harness is ``serve_bench.run_engine_leg`` — ONE
 driver shared with the bench, so smoke and bench cannot disagree on
@@ -51,8 +51,7 @@ def _serve_bench():
 
 
 def quant_block(n_requests: int = 24) -> dict:
-    """ISSUE 18 quant evidence leg (``bench.py`` failure_stats rides
-    this, like ``elastic_smoke.policy_block``): a paged + speculative
+    """ISSUE 18 quant evidence leg: a paged + speculative
     tiny-llama engine at ``kv_dtype=int8`` + ``weight_dtype=int8`` vs
     the same engine at f32, on CPU.
 

@@ -5,7 +5,7 @@
 Three legs over one deterministic 13-batch dataset:
 
 1. **Supervised run** — ``supervise()`` launches a single-rank training
-   worker (ListDataset, ``feed_lookahead=2``, checkpoint every 2 steps)
+   worker (ListDataset, checkpoint every 2 steps)
    with a chaos plan injecting (a) one SIGKILL at step 5 (fires once,
    persisted via the plan state_dir) and (b) a deterministic **poison
    batch**: batch index 8 NaN-poisoned at the ``data_fetch`` site on
@@ -14,11 +14,10 @@ Three legs over one deterministic 13-batch dataset:
    8 → one probe restart → same signature again → batch 8 quarantined
    onto the skip-list → final attempt finishes. The batch-id ledger
    (``SPARKDL_BATCH_LEDGER``) must show every step consuming the same
-   batch in every attempt that executed it (deterministic replay — the
-   lookahead batches were replayed, not dropped) and batches 0..12 minus
-   {8} each consumed by exactly one step. ``SuperviseResult.degradations``
-   must name both the restart-resume (``train_resume``) and the
-   ``train_batch_quarantined`` events.
+   batch in every attempt that executed it (deterministic replay) and
+   batches 0..12 minus {8} each consumed by exactly one step.
+   ``SuperviseResult.degradations`` must name both the restart-resume
+   (``train_resume``) and the ``train_batch_quarantined`` events.
 2. **Clean run** — same worker, no chaos, skip-list pre-seeded to {8}:
    its final loss must equal the supervised run's exactly (same batch
    lineage ⇒ same floats — the strongest exactly-once proof).
@@ -72,8 +71,7 @@ batches = [{{"image": np.random.RandomState(i).randn(8, 4)
 res = runner.run(lambda ctx: ctx.fit(
     loss_fn=softmax_cross_entropy_loss(), params=params, tx=optax.sgd(0.1),
     apply_fn=lambda p, x: x @ p["w"], data=ListDataset(batches),
-    num_steps=num_steps, checkpoint_every=2, log_every=1,
-    feed_lookahead=2))
+    num_steps=num_steps, checkpoint_every=2, log_every=1))
 with open(os.path.join(out_dir, "result.jsonl"), "a") as f:
     f.write(json.dumps({{
         "final_step": int(res["state"].step),
@@ -122,8 +120,7 @@ def main() -> int:
     # -- exactly-once ledger audit ----------------------------------------
     # Across ALL attempts (the ledger is append-mode, chronological):
     # every step that executed consumed the SAME batch in every attempt —
-    # deterministic replay; the lookahead batches drawn before the
-    # SIGKILL were replayed, not dropped — with exactly one legal remap:
+    # deterministic replay — with exactly one legal remap:
     # a step may move off a batch that was quarantined in between (the
     # entry's skip_list records the context). The final step→batch
     # mapping must cover every batch exactly once, minus the quarantined

@@ -1,12 +1,9 @@
 """Host-ingest layer: the host side of the scoring feed (ISSUE 7).
 
-The streamed scorer is host-bound (last on-chip record, 2026-07-31, since
-removed with its installation — see ROADMAP A3: the device trains
-ResNet-50 at 2541 img/s/chip while the scorer delivers ~81 f32 / ~287 u8
-img/s), and every host stage of that gap lives below the device boundary:
-decode, pack, pad, stage. This module owns those stages so they can be
-exercised — and benchmarked (``scripts/ingest_bench.py``) — without
-touching a device backend. NB: this module's OWN imports are
+Every host stage of the streamed scorer's feed lives below the device
+boundary: decode, pack, pad, stage (the scorer has no cell on the chip
+yet — ROADMAP B2). This module owns those stages so they can be exercised
+without touching a device backend. NB: this module's OWN imports are
 numpy/pyarrow only, but reaching it through the package
 (``sparkdl_tpu.core.ingest``) still runs the package ``__init__``,
 which imports jax — cheap in a fork (default) child that inherits the
@@ -146,16 +143,16 @@ def max_wire_shapes_default() -> int:
 
 
 # ---------------------------------------------------------------------------
-# The submit-ahead window (shared: runtime's feed paths AND the bench)
+# The submit-ahead window (shared by runtime's feed paths)
 # ---------------------------------------------------------------------------
 
 def windowed_apply(fn: Callable, items: Iterable, depth: int, workers: int,
                    thread_prefix: str = "", executor=None,
                    stall_s: float = 0.0, stall_stage: str = "decode"):
     """THE submit-ahead window (one copy: the HBM put feed, the decode
-    pool, run_stream's put stage, and ``scripts/ingest_bench.py`` all
-    ride it): apply ``fn`` to each item keeping up to ``depth`` results
-    in flight ahead of the consumer, yielding strictly in input order.
+    pool and run_stream's put stage all ride it): apply ``fn`` to each
+    item keeping up to ``depth`` results in flight ahead of the consumer,
+    yielding strictly in input order.
 
     ``workers <= 0`` applies inline — with ``depth > 0`` results are still
     produced ahead into the window (right for async-returning fns like

@@ -194,9 +194,8 @@ def transfer_workers_default() -> int:
     return int(os.environ.get("SPARKDL_TRANSFER_WORKERS", "0"))
 
 
-# THE submit-ahead window — one copy, in the jax-free ingest module so
-# the host-only bench (scripts/ingest_bench.py) measures the exact
-# pipeline the runtime runs; every feed path here rides it.
+# THE submit-ahead window — one copy, in the jax-free ingest module;
+# every feed path here rides it.
 _windowed_apply = ingest.windowed_apply
 
 
@@ -854,7 +853,7 @@ GLOBAL_COMPILE_CACHE = CompileCache()
 # and nothing here names a directory. If it is unset, the cache lives at
 # <checkout>/.jax_cache — a fixed path, because a cache directory that
 # moves between runs never hits. Armed at import, so every entry point
-# (chip_smoke.py, bench workers, launcher ranks, scoring jobs) has it: a
+# (chip_smoke.py, the benchmark, launcher ranks, scoring jobs) has it: a
 # second process compiling the same program (a supervised gang restart, a
 # repeat scoring job) loads the executable from disk instead of
 # recompiling.

@@ -457,8 +457,7 @@ def flash_attention(q, k, v, causal: bool = False, *, kv_mask=None,
     chains; bench flash leg) measured 512-blocks fastest at EVERY swept
     length (s512 0.042 ms vs 0.107 at 128; s2048 0.43 vs 1.34), so the
     default prefers the largest block unless the padding it forces on a
-    ragged length outweighs its per-work advantage.  The bench's flash
-    leg still sweeps via ``BENCH_FLASH_BLOCKS``.
+    ragged length outweighs its per-work advantage.
     """
     import os
     s_len = q.shape[2]

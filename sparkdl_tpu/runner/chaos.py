@@ -176,7 +176,7 @@ def announce_injection(what: str = "a deliberate retryable failure"):
     """Print the standard fault-injection announcement to stderr —
     call immediately before raising an injected failure in a dryrun /
     record leg, so the captured tail can never read the restart as a
-    real regression (the MULTICHIP_r05 lesson)."""
+    real regression."""
     import sys
     print(f"{CHAOS_INJECTED_MARKER} raising {what} (fault-injection "
           f"leg — the restart below is EXPECTED)", file=sys.stderr)

@@ -2,7 +2,7 @@
 
 PR 4 made the *scoring* data plane fault-tolerant; this module does the
 same for training. The gap it closes: ``fit()`` used to stream an opaque
-iterator, so a mid-loop failure dropped the batches the feed lookahead had
+iterator, so a mid-loop failure dropped the batches the feed had
 already drawn, a restart replayed the stream from wherever the caller's
 iterator happened to sit, and a deterministic poison batch death-looped
 the supervisor through its whole restart budget.
@@ -20,8 +20,8 @@ tiny JSON-able cursor:
   restore to in order to replay everything *after* this batch. ``fit()``
   persists the cursor of the last batch consumed by a **completed** step
   into the checkpoint manifest (``CheckpointManager.save(...,
-  data_cursor=)``), so in-flight lookahead batches are replayed on
-  restart, never dropped.
+  data_cursor=)``), so a batch drawn for a step that never completed is
+  replayed on restart, never dropped.
 
 Iteration is deterministic by contract: the same epoch must yield the
 same batches in the same order on every pass (lists and Arrow frames are

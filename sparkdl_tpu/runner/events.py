@@ -714,11 +714,12 @@ def merge_timeline(event_dir: str, heartbeat_dir: str | None = None,
             continue
         merged.extend(recs)
         # last_step from COMPUTE evidence (step_compute spans, chaos
-        # fires), not feed events: with feed_lookahead the prefetcher's
-        # data_fetch spans run steps ahead of the training loop, and a
-        # postmortem naming a step the rank never computed would misdirect
-        # the resume/diagnosis. Fall back to any step attr for hand-rolled
-        # traces that never emit step_compute.
+        # fires), not feed events: a data_fetch span carries the step its
+        # batch is drawn FOR, which the loop may never reach (the draw or
+        # the put can be what fails), and a postmortem naming a step the
+        # rank never computed would misdirect the resume/diagnosis. Fall
+        # back to any step attr for hand-rolled traces that never emit
+        # step_compute.
         compute_steps = [r["step"] for r in recs
                          if r.get("name") in ("step_compute", "chaos")
                          and isinstance(r.get("step"), (int, float))]

@@ -6,8 +6,8 @@ failures (backend unavailable, preempted chip, interconnect flake — worth a
 checkpoint-and-restart) from *program* failures (user code bugs, shape
 errors, NaNs — retrying burns the restart budget and re-raises anyway).
 
-``classify_exception`` is the policy point: ``run_with_restarts`` and
-``bench.py`` both route through it. ``diagnose_context`` wires the installed
+``classify_exception`` is the policy point: ``run_with_restarts`` and the
+gang supervisor both route through it. ``diagnose_context`` wires the installed
 ``cloud-tpu-diagnostics`` package (SURVEY.md §5.3 names it) so a faulting
 run leaves a stack-trace record on disk for postmortem.
 """
@@ -264,7 +264,7 @@ _FATAL_TRACEBACK_NAMES = ("ValueError", "TypeError", "KeyError",
 
 def classify_text(text: str) -> str:
     """``classify_exception`` for captured *text* (a dead worker's stderr):
-    the gang supervisor and bench driver classify children they cannot
+    the gang supervisor classifies children it cannot
     unpickle an exception object from.
 
     Fatal evidence first (status patterns, then Python traceback names) —

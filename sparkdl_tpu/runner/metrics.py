@@ -38,7 +38,7 @@ class RunStats:
     ``last_failure_kind`` next to the throughput numbers.
 
     ``run_with_restarts`` records restarts/failures; ``chaos.fire`` records
-    injections; ``bench.py`` merges a worker's snapshot into its record.
+    injections.
     Cumulative per process — tests isolate with ``reset()``.
     """
     restarts: int = 0
@@ -261,8 +261,8 @@ class StepTimeStats:
 
 
 # Process-wide accumulator (the run_stats pattern): every meter also records
-# here, so bench.py workers can report step-time percentiles for whatever
-# trained in-process without threading meter objects through.
+# here, so step-time percentiles for whatever trained in-process can be
+# read without threading meter objects through.
 global_step_stats = StepTimeStats()
 
 

@@ -262,9 +262,8 @@ def maybe_arm_from_env(env: dict | None = None) -> Sentinel | None:
 
 
 def anomaly_counts() -> dict[str, int]:
-    """metric -> anomaly transitions so far; {} when off or quiet. The
-    bench harness folds this into ``failure_stats`` so a drifting run is
-    visible in the record even when it completes."""
+    """metric -> anomaly transitions so far; {} when off or quiet: a
+    drifting run is visible to its caller even when it completes."""
     s = _SENTINEL
     return s.anomaly_counts() if s is not None else {}
 

@@ -21,7 +21,6 @@ DCN carries the cross-host legs of the collectives, ICI the intra-slice legs.
 
 from __future__ import annotations
 
-import collections
 import logging
 import os
 import time
@@ -212,7 +211,6 @@ class RunnerContext:
             log_every: int = 10, explicit_collectives: bool = False,
             resume: bool = True, profile_dir: str | None = None,
             remat: bool = False, accum_steps: int = 1,
-            feed_lookahead: int | None = None,
             flops_per_step: float | None = None) -> dict:
         """Run a full training loop; returns {state, meter, history}.
 
@@ -240,18 +238,6 @@ class RunnerContext:
         consume a step slot: the loop draws a replacement batch, so it
         always runs ``num_steps`` steps when the data suffices (before
         round 5 a skipped batch silently burned its step).
-
-        ``feed_lookahead`` > 0 shards batches that many steps AHEAD from a
-        worker thread (default from ``SPARKDL_FEED_LOOKAHEAD``, 0 =
-        inline): where ``device_put`` holds the calling thread for the
-        wire time, the next batch's host→HBM transfer then overlaps the
-        current step instead of serializing with it. Costs ``lookahead``
-        extra device batches of HBM. With a checkpointable dataset the
-        lookahead is resume-transparent: a mid-loop failure replays the prefetched
-        but unconsumed batches from the cursor on restart instead of
-        dropping them. Only a caller feeding a bare, reused iterator
-        still sees the old semantics (prefetched batches die with the
-        run) and should keep the inline feed for exact error-path resume.
 
         The loop is flight-recorded (``runner.events``): per-step
         ``data_fetch``/``shard_put``/``step_compute`` spans (``step_compute``
@@ -341,10 +327,9 @@ class RunnerContext:
         eval_step = self.make_eval_step(eval_fn) if eval_fn else None
         history: list[dict] = []
 
-        # Both paths feed (cursor_after | None, batch) pairs: the cursor
-        # rides WITH its batch through crop/lookahead staging, so whatever
-        # step ultimately consumes the batch knows exactly where the data
-        # plane stood after it — lookahead can run ahead freely.
+        # The feed is (cursor_after | None, batch) pairs: the cursor rides
+        # WITH its batch through crop and staging, so the step that
+        # consumes the batch knows where the data plane stood after it.
         if dataset is not None:
             data_it = dataset.indexed()
         else:
@@ -384,24 +369,30 @@ class RunnerContext:
                         lambda x: x[:keep], batch)
             return batch
 
-        lookahead = (int(os.environ.get("SPARKDL_FEED_LOOKAHEAD", "0"))
-                     if feed_lookahead is None else feed_lookahead)
-        pool = None
-        if lookahead > 0:
-            # shard_batch runs in worker threads `lookahead` steps ahead:
-            # host→HBM transfer of batch k+1 overlaps step k on backends
-            # whose device_put blocks for the wire time
-            from concurrent.futures import ThreadPoolExecutor
-            pool = ThreadPoolExecutor(max_workers=lookahead,
-                                      thread_name_prefix="sparkdl-shard")
-
         def _staged(limit: int):
             """(local_rows, sharded_batch, cursor_after) stream: crop
-            applied, at most ``limit`` batches drawn from ``data_it`` —
-            the lookahead may never consume input the step loop won't run
-            (a reused bare iterator must sit exactly where the inline
-            feed leaves it; a dataset replays from the cursor anyway)."""
-            def _one(cur, batch):
+            applied, at most ``limit`` batches drawn from ``data_it`` and
+            nothing pulled past that cap (checked BEFORE each next()): a
+            reused bare iterator sits exactly ``limit`` produced batches
+            on when the loop ends."""
+            produced = 0
+            while produced < limit:
+                try:
+                    # The span closes on StopIteration too, marking
+                    # end_of_data in the trace before the except
+                    # swallows it (PEP 479: it must not escape here).
+                    with events.span("data_fetch",
+                                     step=start_step + produced):
+                        cur, batch = next(data_it)
+                except StopIteration:
+                    return
+                batch = _crop(batch)
+                if batch is None:
+                    continue
+                batch = chaos.fire("batch_fetch",
+                                   step=start_step + produced,
+                                   batch=batch)
+                produced += 1
                 leaves = jax.tree_util.tree_leaves(batch)
                 n = len(leaves[0])
                 # rows/bytes ride the span so the stage accountant's
@@ -409,42 +400,7 @@ class RunnerContext:
                 nbytes = sum(getattr(x, "nbytes", 0) for x in leaves)
                 with events.span("shard_put", rows=n, bytes=nbytes):
                     sharded = self.shard_batch(batch)
-                return (n, sharded, cur)
-
-            def _cropped():
-                """Draw-on-demand: nothing is pulled from data_it past
-                the cap (checked BEFORE each next())."""
-                produced = 0
-                while produced < limit:
-                    try:
-                        # The span closes on StopIteration too, marking
-                        # end_of_data in the trace before the except
-                        # swallows it (PEP 479: it must not escape here).
-                        with events.span("data_fetch",
-                                         step=start_step + produced):
-                            cur, batch = next(data_it)
-                    except StopIteration:
-                        return
-                    batch = _crop(batch)
-                    if batch is None:
-                        continue
-                    batch = chaos.fire("batch_fetch",
-                                       step=start_step + produced,
-                                       batch=batch)
-                    produced += 1
-                    yield cur, batch
-
-            if pool is None:
-                for cur, batch in _cropped():
-                    yield _one(cur, batch)
-                return
-            pending: collections.deque = collections.deque()
-            for cur, batch in _cropped():
-                pending.append(pool.submit(_one, cur, batch))
-                while len(pending) > lookahead:
-                    yield pending.popleft().result()
-            while pending:
-                yield pending.popleft().result()
+                yield n, sharded, cur
 
         staged_it = _staged(num_steps - start_step)
         if profile_dir:
@@ -619,8 +575,6 @@ class RunnerContext:
             e._sparkdl_postmortemed = True
             raise
         finally:
-            if pool is not None:
-                pool.shutdown(wait=False, cancel_futures=True)
             if profile_dir:
                 # When the loop is already unwinding, a profiler-stop
                 # failure must not replace the real training error (the
@@ -675,9 +629,7 @@ class RunnerContext:
 
 def _env_flag(name: str) -> bool:
     """Boolean env knob: '1'/'true'/'yes' → on, everything else (incl. a
-    user's SPARKDL_MFU_ESTIMATE=0) → off. Same truth table as bench.py's
-    ``_env_flag`` — kept as two small copies because bench's driver stays
-    importable without pulling jax through this package."""
+    user's SPARKDL_MFU_ESTIMATE=0) → off."""
     return os.environ.get(name, "").strip().lower() in ("1", "true", "yes")
 
 
@@ -688,8 +640,8 @@ def _estimate_step_flops(step_fn, state, sharded) -> float | None:
     compile, doubling startup on big models and the window the gang
     watchdog must tolerate before the first heartbeat). None when the
     step isn't a jit function or the backend doesn't expose the estimate
-    pre-compile; callers wanting compiled-HLO numbers pass
-    ``fit(flops_per_step=...)`` from bench's AOT path instead."""
+    pre-compile; callers who know the step's FLOPs pass
+    ``fit(flops_per_step=...)`` instead."""
     try:
         lowered = step_fn.lower(state, sharded)
         cost = lowered.cost_analysis()

@@ -244,7 +244,7 @@ def scrub_serving_env(env: dict | None = None) -> dict:
     ``SPARKDL_TP_DEVICE_OFFSET``) from ``env`` — default the process
     environment — returning the removed entries so a caller can
     restore them. The ONE implementation of evidence hygiene for the
-    tp bench leg, the MULTICHIP record script and the dryrun leg: an
+    tp bench leg and the dryrun leg: an
     ambient ``SPARKDL_SERVE_KV_POOL_MB`` (a per-DEVICE budget) would
     size every tp degree's pool to ~equal device bytes and silently
     invert their 1/tp observable, and STALL_FREE/SPEC/PREFIX overrides

@@ -1,6 +1,6 @@
 """Exactly-once training data plane (ISSUE 5): cursor round-trips,
 skip-lists, adapters, manifest-persisted cursors, and fit() threading —
-the lookahead-replay and legacy-manifest degradation cases pinned fast.
+the preempt-and-resume and legacy-manifest degradation cases pinned fast.
 
 All CPU-only; the supervised end-to-end (SIGKILL + poison batch +
 quarantine) lives in scripts/train_resume_smoke.py (slow, test_chaos.py);
@@ -280,31 +280,6 @@ class TestFitCursorThreading:
         assert [(e["step"], e["batch_index"]) for e in led] == \
             [(i, i) for i in range(8)]
 
-    def test_lookahead_batches_replayed_not_dropped(self, tmp_path,
-                                                    monkeypatch):
-        """THE documented-caveat fix: a mid-loop failure with
-        feed_lookahead > 0 used to silently drop the prefetched batches;
-        with a dataset they replay from the cursor on resume."""
-        monkeypatch.setenv(data_lib.LEDGER_ENV, str(tmp_path / "led"))
-        batches = _batches(8)
-        chaos.install(FaultPlan(
-            [Fault("step_start", "preempt", at_step=3)]))
-        try:
-            with pytest.raises(InjectedPreemption):
-                _fit(tmp_path / "ck", ListDataset(batches), 8,
-                     feed_lookahead=2)
-        finally:
-            chaos.uninstall()
-        # steps 0..2 completed; lookahead had drawn batches ~3..5 which
-        # died with the attempt. Resume must replay them.
-        _fit(tmp_path / "ck", ListDataset(batches), 8, feed_lookahead=2)
-        led = read_ledger(str(tmp_path / "led"))
-        by_step = {}
-        for e in led:
-            assert by_step.setdefault(e["step"], e["batch_index"]) \
-                == e["batch_index"], "replay diverged"
-        assert sorted(by_step.items()) == [(i, i) for i in range(8)]
-
     @pytest.mark.parametrize("at_step", [2, 3, 4, 5])
     @pytest.mark.parametrize("log_every", [1, 3])
     def test_every_checkpointed_step_is_ledgered_and_logged(
@@ -357,8 +332,7 @@ class TestFitCursorThreading:
             [Fault("data_fetch", "fatal", at_step=3, once=False)]))
         try:
             with pytest.raises(chaos.InjectedFatal):
-                _fit(tmp_path / "ck", ListDataset(_batches(8)), 8,
-                     feed_lookahead=2)
+                _fit(tmp_path / "ck", ListDataset(_batches(8)), 8)
         finally:
             chaos.uninstall()
             monkeypatch.delenv(events.RECORDER_DIR_ENV)

@@ -473,9 +473,10 @@ class TestMergeTimeline:
         assert list(tmp_path.iterdir()) == []
 
     def test_last_step_ignores_prefetch_feed_events(self, tmp_path):
-        """feed_lookahead: data_fetch spans run steps AHEAD of compute —
-        the timeline must report the last step the rank actually computed,
-        not the feed position."""
+        """A feed event can carry a step AHEAD of compute (a feeder that
+        prefetches; a draw that failed before its step ran) — the timeline
+        must report the last step the rank actually computed, not the
+        feed position."""
         d = str(tmp_path)
         self._write(d, 0, [
             {"t": 1.0, "name": "step_compute", "ph": "E", "rank": 0,

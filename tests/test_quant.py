@@ -273,25 +273,50 @@ class TestWeightQuant:
         walk(qp)
         assert seen == set(L.WEIGHT_QUANT_TARGETS)
 
-    def test_int8_forward_close_to_f32_and_float_params_exact(self):
-        """The quantized model tracks the f32 model within absmax-
-        per-channel int8 error; the SAME quantized-model clone fed
-        UNCONVERTED float params takes the plain dense path and matches
-        the f32 model bitwise (graceful unconverted checkpoint)."""
-        cfg, model, variables = self._model()
-        ids = np.random.RandomState(3).randint(
+    def _ids(self, cfg):
+        return np.random.RandomState(3).randint(
             0, cfg.vocab_size, (2, 6)).astype(np.int32)
-        ref = model.apply(variables, ids)
+
+    def test_int8_forward_tracks_f32(self):
+        """The quantized model's logits stay within absmax-per-channel
+        int8 error of the f32 model's: ``|out - ref|_2 / |ref|_2`` under
+        a limit set between the sound reading and the planted faults'.
+        Readings on this tiny model over ids seeds 3..6: int8
+        0.0144-0.0161; scales 5% off 0.060-0.072; one scale a tensor in
+        place of one a channel 0.092-0.102; scales left out 1.16-1.27.
+        No argmax identity: on random weights the top two logits of a
+        row can sit closer than int8 rounding moves them."""
+        cfg, model, variables = self._model()
+        ids = self._ids(cfg)
+        ref = np.asarray(model.apply(variables, ids), np.float64)
         qmodel = model.clone(weight_quant="int8")
-        qp = {"params": L.quantize_params(variables["params"], "int8")}
-        out = qmodel.apply(qp, ids)
-        assert np.allclose(np.asarray(out), np.asarray(ref),
-                           atol=0.15, rtol=0.1)
-        # greedy next-token argmax survives quantization on the tiny
-        same = (np.asarray(out[:, -1]).argmax(-1)
-                == np.asarray(ref[:, -1]).argmax(-1))
-        assert same.all()
-        exact = qmodel.apply(variables, ids)  # float params, quant model
+        qp = L.quantize_params(variables["params"], "int8")
+
+        def gap(params):
+            out = np.asarray(qmodel.apply({"params": params}, ids),
+                             np.float64)
+            return np.linalg.norm(out - ref) / np.linalg.norm(ref)
+
+        def with_scales(tree, f):
+            if not isinstance(tree, dict):
+                return tree
+            return {k: f(v) if k == "kernel_scale" else with_scales(v, f)
+                    for k, v in tree.items()}
+
+        limit = 0.03
+        assert gap(qp) < limit
+        # the limit is a limit: the mildest planted fault reads over it
+        assert gap(with_scales(qp, lambda s: s * 1.05)) > limit
+        assert gap(with_scales(qp, jnp.ones_like)) > limit
+
+    def test_quant_model_with_float_params_is_bitwise_f32(self):
+        """The SAME quantized-model clone fed UNCONVERTED float params
+        takes the plain dense path and matches the f32 model bitwise
+        (graceful unconverted checkpoint)."""
+        cfg, model, variables = self._model()
+        ids = self._ids(cfg)
+        ref = model.apply(variables, ids)
+        exact = model.clone(weight_quant="int8").apply(variables, ids)
         np.testing.assert_array_equal(np.asarray(exact),
                                       np.asarray(ref))
 

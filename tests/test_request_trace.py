@@ -516,6 +516,16 @@ def _run_serving_workload(event_dir, monkeypatch):
     monkeypatch.delenv("SPARKDL_EVENT_DIR")
 
 
+def _load_check_metric_docs():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "check_metric_docs",
+        os.path.join(_REPO, "scripts", "check_metric_docs.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class TestReportClis:
     def test_request_report_cli(self, tmp_path, monkeypatch, capsys):
         _run_serving_workload(tmp_path, monkeypatch)
@@ -568,12 +578,7 @@ class TestReportClis:
         assert rec["report"] is not None
 
     def test_check_metric_docs_lint(self, tmp_path):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "check_metric_docs",
-            os.path.join(_REPO, "scripts", "check_metric_docs.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = _load_check_metric_docs()
         # the repo itself must be clean
         assert mod.missing_metrics() == []
         # synthetic drift is caught
@@ -588,11 +593,39 @@ class TestReportClis:
         assert missing == ["another_new_gauge",
                            "totally_new_metric_total"]
 
+    def test_readme_metric_tables_name_only_registered_metrics(self):
+        """The lint in reverse, on the repo: no row of the README's
+        metric tables outlives the metric it documents."""
+        mod = _load_check_metric_docs()
+        stale = mod.stale_metrics()
+        assert stale == [], \
+            f"README.md tables name metrics no code registers: {stale}"
+
+    def test_metric_lint_catches_synthetic_stale_row(self, tmp_path):
+        """Only table rows count (prose may name a metric that went), a
+        row may hold several names and several types, and each name is
+        checked on its own."""
+        mod = _load_check_metric_docs()
+        pkg = tmp_path / "sparkdl_tpu"
+        pkg.mkdir()
+        (pkg / "x.py").write_text(
+            'reg.counter("kept_total").inc()\n'
+            'reg.histogram("kept_s").observe(1.0)\n')
+        readme = tmp_path / "README.md"
+        readme.write_text(
+            "prose about `gone_in_prose_total`\n\n"
+            "| Metric | Type | Meaning |\n|---|---|---|\n"
+            "| `kept_total` / `gone_total` | counter | x |\n"
+            "| `kept_s` / `gone_s` | counter / histogram | y |\n"
+            "| `not_a_metric` | string | z |\n")
+        assert mod.stale_metrics(root=str(tmp_path),
+                                 readme=str(readme)) == \
+            ["gone_s", "gone_total"]
+
     def test_serve_bench_leg_records_slo_and_slowest_trace(self):
         """Satellite: run_engine_leg's record carries the SLO
         compliance numbers, the slowest-trace phase breakdown, and the
-        attribution residual — the fields _serve_headline forwards into
-        the bench record."""
+        attribution residual."""
         import importlib.util
         spec = importlib.util.spec_from_file_location(
             "serve_bench",
@@ -613,21 +646,11 @@ class TestReportClis:
         assert st["dominant_phase"] in (
             "queue", "prefill", "prefill_wait", "block_stall", "draft",
             "decode", "unattributed")
-        # ... and the headline forwards them
-        sys.path.insert(0, _REPO)
-        import bench
-        head = bench._serve_headline({"engine": {"4": leg}})
-        assert head["serve_slo_ttft_compliance"] == \
-            leg["slo"]["ttft_compliance"]
-        assert head["serve_slowest_trace"] == st
-        assert head["serve_trace_max_unattributed_frac"] == \
-            leg["trace_attribution"]["max_unattributed_frac"]
 
     def test_serve_bench_survivability_leg_and_gating(self):
         """ISSUE 19 satellite: the survivability leg reports one
         injected failover's recovery latency + the exactly-once
-        token-identity float, _serve_headline forwards both, and bench_trend's
-        name-shape rules gate them in the right direction."""
+        token-identity float."""
         import importlib.util
         spec = importlib.util.spec_from_file_location(
             "serve_bench",
@@ -642,38 +665,11 @@ class TestReportClis:
         assert surv["recovery_s"] is not None and surv["recovery_s"] > 0
         assert surv["clean"]["completed"] == 8
         assert surv["faulted"]["completed"] == 8
-        sys.path.insert(0, _REPO)
-        import bench
-        head = bench._serve_headline({"survivability": surv})
-        assert head["serve_recovery_s"] == surv["recovery_s"]
-        assert head["serve_failover_token_identical"] == 1.0
-        bt_spec = importlib.util.spec_from_file_location(
-            "bench_trend",
-            os.path.join(_REPO, "scripts", "bench_trend.py"))
-        bt = importlib.util.module_from_spec(bt_spec)
-        bt_spec.loader.exec_module(bt)
-        assert bt._LOWER_IS_BETTER.search("serve_recovery_s")
-        assert not bt._LOWER_IS_BETTER.search(
-            "serve_failover_token_identical")
-        # a slower recovery OR a broken identity must trip the gate
-        recs = [{"n": i, "parsed": {"metric": "m", "value": 1.0,
-                                    "extra": e}}
-                for i, e in ((1, {"serve_recovery_s": 0.05,
-                                  "serve_failover_token_identical": 1.0}),
-                             (2, {"serve_recovery_s": 0.12,
-                                  "serve_failover_token_identical": 0.0}))]
-        rep = bt.trend(recs)
-        assert {"serve_recovery_s", "serve_failover_token_identical"} \
-            <= set(rep["regressions"])
 
     def test_serve_bench_fleet_leg_and_gating(self):
         """ISSUE 20 satellite: the fleet leg reports the radix-vs-
         round-robin routing comparison plus one unclean replica kill's
-        recovery latency and the cross-replica exactly-once float;
-        _serve_headline forwards them (riding healthy AND
-        backend_unavailable records) and bench_trend's name-shape rules
-        gate fleet_recovery_s lower-is-better and fleet_token_identical
-        higher-is-better."""
+        recovery latency and the cross-replica exactly-once float."""
         import importlib.util
         spec = importlib.util.spec_from_file_location(
             "serve_bench",
@@ -688,29 +684,6 @@ class TestReportClis:
         for leg in (flt["radix"], flt["round_robin"]):
             assert leg["completed"] == flt["requests"]
             assert leg["reused_tokens"] >= 0
-        sys.path.insert(0, _REPO)
-        import bench
-        head = bench._serve_headline({"fleet": flt})
-        assert head["fleet_recovery_s"] == flt["recovery_s"]
-        assert head["fleet_token_identical"] == 1.0
-        assert head["fleet_prefix_reuse_ratio"] == flt["reuse_ratio"]
-        bt_spec = importlib.util.spec_from_file_location(
-            "bench_trend",
-            os.path.join(_REPO, "scripts", "bench_trend.py"))
-        bt = importlib.util.module_from_spec(bt_spec)
-        bt_spec.loader.exec_module(bt)
-        assert bt._LOWER_IS_BETTER.search("fleet_recovery_s")
-        assert not bt._LOWER_IS_BETTER.search("fleet_token_identical")
-        # slower fleet recovery OR a broken identity trips the gate
-        recs = [{"n": i, "parsed": {"metric": "m", "value": 1.0,
-                                    "extra": e}}
-                for i, e in ((1, {"fleet_recovery_s": 0.05,
-                                  "fleet_token_identical": 1.0}),
-                             (2, {"fleet_recovery_s": 0.12,
-                                  "fleet_token_identical": 0.0}))]
-        rep = bt.trend(recs)
-        assert {"fleet_recovery_s", "fleet_token_identical"} \
-            <= set(rep["regressions"])
 
     def test_gang_aggregation_merges_trace_blocks(self, tmp_path):
         """aggregate_snapshots re-ranks the per-rank slowest lists into

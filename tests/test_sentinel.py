@@ -193,9 +193,8 @@ class TestOffIsFree:
 
 class TestBenchLedger:
     def test_anomaly_counts_shape_rides_failure_stats(self):
-        """bench.py embeds anomaly_counts() under
-        failure_stats.sentinel_anomalies — the shape must stay a flat
-        {metric: int} json-serializable dict."""
+        """anomaly_counts() is embedded in json records — the shape must
+        stay a flat {metric: int} json-serializable dict."""
         sentinel.arm(ratio=2.0, window=8, min_n=8)
         for _ in range(16):
             sentinel.observe("ttft", 0.01)

@@ -1332,6 +1332,115 @@ class TestEngineOnCpu:
         eng_bl.run_until_idle()
         assert hb.result(1) == outs[0]
 
+
+# -- scripts/serve_bench.py's jax-free engine legs as regression pins --------
+
+def _load_serve_bench():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "serve_bench", os.path.join(os.path.dirname(__file__), "..",
+                                    "scripts", "serve_bench.py"))
+    sb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sb)
+    return sb
+
+
+def _retry_once(run, ok):
+    """Wall-clock stub comparisons ride time.sleep() on a shared CI
+    host: one retry absorbs a loaded-host scheduling hiccup without
+    weakening the floors (both attempts must run the SAME deterministic
+    workload — flakiness here is timer noise, never workload noise)."""
+    rec = run()
+    if ok(rec):
+        return rec
+    return run()
+
+
+def test_stub_scheduler_stall_free_beats_blocking():
+    """ISSUE 10 regression pin without hardware: on the long-prompt mix
+    with deterministic synthetic device costs (jax-free StubBackend),
+    the stall-free scheduler (chunked prefill + shared-prefix reuse)
+    must beat the PR 8 blocking engine on aggregate tokens/s (floor
+    1.2x — bench-record target 1.3x), cut prefill-induced decode-stall
+    wall time (floor 2.5x — record target 5x), and improve TTFT p99
+    (floor 1.2x — record target 2x)."""
+    sb = _load_serve_bench()
+    rec = _retry_once(
+        lambda: sb.run_stub_scheduler_comparison(n_requests=96),
+        lambda r: (r["speedup_vs_blocking"] >= 1.2
+                   and r["decode_stall_ratio"] >= 2.5
+                   and r["ttft_p99_ratio"] >= 1.2))
+    assert rec["speedup_vs_blocking"] >= 1.2, rec
+    assert rec["decode_stall_ratio"] >= 2.5, rec
+    assert rec["ttft_p99_ratio"] >= 1.2, rec
+    # the win comes from the prefix cache + chunking, and the record
+    # proves it: warm traffic hits the cache
+    assert rec["prefix_cache"]["hit_rate"] >= 0.5, rec["prefix_cache"]
+
+
+def test_paged_engine_beats_per_slot_on_high_churn():
+    """ISSUE 11 regression pin without hardware: at FIXED pool bytes on
+    the short-output high-churn mix, the paged 32-slot engine must beat
+    the PR 9 per-slot 8-slot engine on tokens/s (floor 1.3x), run the
+    pool hot (peak utilization >= 0.8 — throughput is bounded by pool
+    bytes, not max_len x slots), and hold the shared preamble as ONE
+    physical block set (blocks_shared_frac > 0)."""
+    sb = _load_serve_bench()
+    rec = _retry_once(
+        lambda: sb.run_paged_churn_comparison(n_requests=192),
+        lambda r: (r.get("paged_speedup", 0) >= 1.3
+                   and (r.get("kv_pool_utilization") or 0) >= 0.8))
+    assert rec["paged_speedup"] >= 1.3, rec
+    assert rec["kv_pool_utilization"] >= 0.8, rec
+    assert rec["blocks_shared_frac"] > 0, rec
+    assert rec["paged"]["completed"] == rec["paged"]["requests"], rec
+    # the admission-wait stats ride the record (healthy pool: ~0; a
+    # too-small pool shows up here instead of as a crash)
+    assert "admission_block_waits" in rec and "preemptions" in rec
+
+
+def test_stub_spec_leg_beats_k0_engine():
+    """ISSUE 12 regression pin without hardware: on the repetitive-text
+    mix (small-vocab stub streams are periodic, so the request's own
+    output is self-predictive — the default n-gram provider's home
+    turf), the k=4 speculative engine must beat the k=0 engine >= 1.5x
+    single-stream tokens/s (bench-record target 2x on the CPU-llama
+    leg), with a sane draft-acceptance floor and token-identical
+    output."""
+    sb = _load_serve_bench()
+    rec = _retry_once(
+        lambda: sb.run_spec_comparison_stub(
+            n_requests=16, ks=(0, 4), concurrencies=(1,),
+            step_s=0.0015, n_new=32),
+        lambda r: r.get("spec_speedup", 0) >= 1.5)
+    assert rec["spec_speedup"] >= 1.5, rec
+    assert rec["spec_accept_rate"] >= 0.3, rec  # acceptance sanity floor
+    assert rec["spec_token_identical"] is True, rec
+
+
+def test_multi_chunk_budget_admits_multiple_slots_per_iteration():
+    """The ISSUE 11 budget pin: where the one-chunk PR 9 budget fills 1
+    slot per iteration, SPARKDL_SERVE_PREFILL_BUDGET = 2 chunks fills
+    2 — jax-free, deterministic (no sleeps)."""
+    from sparkdl_tpu.serving import GenerationEngine, StubBackend
+
+    def refills_completed_after_one_iteration(budget):
+        eng = GenerationEngine(
+            StubBackend(4, 64, vocab_size=100, block_size=4,
+                        pool_blocks=80),
+            prefill_chunk=4, prefill_budget=budget)
+        for b in (1, 20, 40):  # one-chunk prompts: 1 chunk = 1 refill
+            eng.submit(list(range(b, b + 4)), max_new_tokens=1)
+        eng.step()
+        done = eng.snapshot()["prefills"]
+        eng.run_until_idle()
+        return done
+
+    assert refills_completed_after_one_iteration(None) == 1  # PR 9 cap
+    assert refills_completed_after_one_iteration(8) == 2     # 2 slots
+    assert refills_completed_after_one_iteration(12) == 3    # 3 slots
+
+
 @pytest.mark.slow
 def test_serve_smoke_end_to_end():
     """Concurrent submitters, no starvation, aggregate > single-stream,

@@ -652,15 +652,20 @@ class TestMeterIntegration:
         logger.close()
 
 
+def _check_env_docs():
+    sys.path.insert(0, os.path.join(_REPO, "scripts"))
+    try:
+        import check_env_docs
+    finally:
+        sys.path.pop(0)
+    return check_env_docs
+
+
 class TestEnvDocsLint:
     def test_repo_has_no_drift(self):
         """The lint itself, as a tier-1 gate: every SPARKDL_* var in the
         package is documented in README.md."""
-        sys.path.insert(0, os.path.join(_REPO, "scripts"))
-        try:
-            import check_env_docs
-        finally:
-            sys.path.pop(0)
+        check_env_docs = _check_env_docs()
         missing = check_env_docs.missing_vars()
         assert missing == [], \
             f"undocumented SPARKDL_* env vars: {missing}"
@@ -671,21 +676,71 @@ class TestEnvDocsLint:
     def test_lint_catches_synthetic_drift(self, tmp_path):
         """The mechanism, not just the current state: an undocumented var
         in a synthetic tree is reported."""
-        sys.path.insert(0, os.path.join(_REPO, "scripts"))
-        try:
-            import check_env_docs
-        finally:
-            sys.path.pop(0)
+        check_env_docs = _check_env_docs()
         pkg = tmp_path / "sparkdl_tpu"
         pkg.mkdir()
         (pkg / "mod.py").write_text(
             'import os\nX = os.environ.get("SPARKDL_TOTALLY_NEW_KNOB")\n')
         (tmp_path / "scripts").mkdir()
-        (tmp_path / "bench.py").write_text("")
         (tmp_path / "README.md").write_text("docs say nothing")
         missing = check_env_docs.missing_vars(
             root=str(tmp_path), readme=str(tmp_path / "README.md"))
         assert missing == ["SPARKDL_TOTALLY_NEW_KNOB"]
+
+    def test_repo_documents_no_var_that_nothing_reads(self):
+        """The other direction: a knob that was deleted leaves no row
+        behind in the README."""
+        stale = _check_env_docs().stale_vars()
+        assert stale == [], \
+            f"README.md documents SPARKDL_* names no code reads: {stale}"
+
+    def test_lint_catches_synthetic_stale_var(self, tmp_path):
+        """A documented var with no reader is reported; a name ending in
+        ``_`` is a prefix and passes only while some var still starts
+        with it; a test file that sets a var is not a reader, the
+        suite's conftest is."""
+        check_env_docs = _check_env_docs()
+        pkg = tmp_path / "sparkdl_tpu"
+        pkg.mkdir()
+        (pkg / "mod.py").write_text(
+            'import os\nX = os.environ.get("SPARKDL_SLO_TTFT_S")\n')
+        tests = tmp_path / "tests"
+        tests.mkdir()
+        (tests / "conftest.py").write_text(
+            'import os\nP = os.environ.get("SPARKDL_HARNESS_KNOB")\n')
+        (tests / "test_x.py").write_text(
+            'def test(monkeypatch):\n'
+            '    monkeypatch.setenv("SPARKDL_DEAD_KNOB", "1")\n')
+        readme = tmp_path / "README.md"
+        readme.write_text(
+            "`SPARKDL_SLO_TTFT_S`, every `SPARKDL_SLO_*`, "
+            "`SPARKDL_HARNESS_KNOB`; gone: `SPARKDL_DEAD_KNOB` and "
+            "every `SPARKDL_OLDFAMILY_*`")
+        assert check_env_docs.stale_vars(
+            root=str(tmp_path), readme=str(readme)) == \
+            ["SPARKDL_DEAD_KNOB", "SPARKDL_OLDFAMILY_"]
+
+    def test_readme_names_only_files_in_the_tree(self, tmp_path):
+        """Every `scripts/x.py`, `tests/x.py`, bare `x.py` and root
+        `X.json` the README names exists — in the repo, and the
+        mechanism on a synthetic tree (a bare script name resolves
+        under scripts/; a lower-case json is a run's output)."""
+        check_env_docs = _check_env_docs()
+        gone = check_env_docs.missing_paths()
+        assert gone == [], f"README.md names files that are gone: {gone}"
+        (tmp_path / "scripts").mkdir()
+        (tmp_path / "scripts" / "report.py").write_text("")
+        (tmp_path / "RECORD.json").write_text("{}")
+        readme = tmp_path / "README.md"
+        readme.write_text(
+            "run `scripts/report.py` (or `report.py`), read `RECORD.json` "
+            "and the run's `gang_timeline.json`; once there were "
+            "`scripts/old_gate.py`, `tests/test_old.py`, `old.py` and "
+            "`OLD_r01.json`")
+        assert check_env_docs.missing_paths(
+            root=str(tmp_path), readme=str(readme)) == \
+            ["OLD_r01.json", "old.py", "scripts/old_gate.py",
+             "tests/test_old.py"]
 
 
 class TestScorerGauges:

@@ -2,9 +2,8 @@
 propagation through the flight recorder, supervisor-minted trace ids
 shipped to ranks via env, the merged Chrome-trace export
 (``runner/traceview.py`` + ``scripts/trace_export.py``), the
-``gang_resized`` never-failure-evidence rule under elastic resizes, the
-engine's request-span parentage, and the BENCH trajectory gate
-(``scripts/bench_trend.py``).
+``gang_resized`` never-failure-evidence rule under elastic resizes, and
+the engine's request-span parentage.
 
 Fast and jax-free where possible: synthetic streams feed traceview and
 merge_timeline; the one subprocess test launches hand-rolled stdlib
@@ -484,76 +483,3 @@ class TestEngineParentage:
         assert not any(r["name"] == "serve_request" for r in recs)
         for r in recs:
             assert "span_id" not in r and "parent_id" not in r
-
-
-class TestBenchTrend:
-    def _rec(self, n, value, metric="tput", extra=None, error=None,
-             parsed=True):
-        p = None
-        if parsed:
-            p = {"metric": metric, "value": value, "extra": extra or {}}
-            if error:
-                p["error"] = error
-        return {"n": n, "rc": 0, "parsed": p}
-
-    def test_improvement_and_flat_pass(self):
-        mod = _load_script("bench_trend")
-        rep = mod.trend([self._rec(1, 100.0), self._rec(2, 110.0),
-                         self._rec(3, 109.0)], threshold=0.15)
-        assert rep["ok"]
-        (m,) = [x for x in rep["metrics"] if x["metric"] == "tput"]
-        assert m["best_prior"] == 110.0
-        assert m["regressed"] is False
-
-    def test_regression_past_threshold_fails(self):
-        mod = _load_script("bench_trend")
-        rep = mod.trend([self._rec(1, 100.0), self._rec(2, 70.0)],
-                        threshold=0.15)
-        assert not rep["ok"]
-        assert rep["regressions"] == ["tput"]
-        # ...but within threshold passes
-        rep2 = mod.trend([self._rec(1, 100.0), self._rec(2, 90.0)],
-                         threshold=0.15)
-        assert rep2["ok"]
-
-    def test_lower_is_better_metrics_invert(self):
-        mod = _load_script("bench_trend")
-        recs = [self._rec(1, 1.0, extra={"step_time_s": 0.010}),
-                self._rec(2, 1.0, extra={"step_time_s": 0.030})]
-        rep = mod.trend(recs, threshold=0.15)
-        (m,) = [x for x in rep["metrics"]
-                if x["metric"] == "step_time_s"]
-        assert m["direction"] == "lower"
-        assert m["regressed"] is True
-
-    def test_unmeasured_rounds_are_annotated_not_regressions(self):
-        """A backend_unavailable round scoring 0.0 must not read as a
-        100% regression — it is excluded and named in `skipped`."""
-        mod = _load_script("bench_trend")
-        recs = [self._rec(1, 100.0),
-                self._rec(2, 0.0,
-                          error={"kind": "backend_unavailable"}),
-                {"n": 3, "rc": 124, "parsed": None},
-                self._rec(4, 98.0)]
-        rep = mod.trend(recs, threshold=0.15)
-        assert rep["ok"]
-        assert [s["n"] for s in rep["skipped"]] == [2, 3]
-        assert [s["reason"] for s in rep["skipped"]] == [
-            "backend_unavailable", "no parse"]
-        (m,) = [x for x in rep["metrics"] if x["metric"] == "tput"]
-        assert m["points"] == 2  # only the measured rounds
-
-    def test_cli_exit_codes(self, tmp_path):
-        mod = _load_script("bench_trend")
-        for rec in [self._rec(1, 100.0), self._rec(2, 50.0)]:
-            with open(tmp_path / f"BENCH_r{rec['n']:02d}.json",
-                      "w") as f:
-                json.dump(rec, f)
-        assert mod.main(["--dir", str(tmp_path)]) == 1  # regression
-        assert mod.main(["--dir", str(tmp_path),
-                         "--threshold", "0.9"]) == 0
-        solo = tmp_path / "one"
-        solo.mkdir()
-        with open(solo / "BENCH_r01.json", "w") as f:
-            json.dump(self._rec(1, 100.0), f)
-        assert mod.main(["--dir", str(solo)]) == 2  # no trend yet
