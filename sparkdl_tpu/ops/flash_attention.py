@@ -10,15 +10,30 @@ and each hop's local compute can run through this kernel.
 Design (the standard streaming-softmax factorization, written for the MXU):
 - grid = (batch·heads, Q tiles, KV tiles); pallas pipelines each (BK, D)
   KV tile from HBM through the innermost grid dimension while the running
-  row max ``m``, normalizer ``l``, and unnormalized f32 accumulator persist
+  max ``m``, normalizer ``l``, and unnormalized f32 accumulator persist
   in VMEM scratch across KV steps.
 - S·S attention scores never materialize and no full K/V is ever VMEM
-  resident — VMEM holds one Q, K, V tile + one (BQ, BK) score tile, so
-  sequence length is bounded by HBM, not VMEM.
-- causal masking prunes whole KV tiles: dead tiles are skipped via pl.when.
-- ``kv_mask`` ([B, S] 0/1) streams as (1, BK) tiles and masks padded key
-  positions — the BERT attention-mask contract, so flash drops into padded
-  encoder batches, not just causal LMs.
+  resident — VMEM holds one Q, K, V tile + one score tile, so sequence
+  length is bounded by HBM, not VMEM.
+- precision, all three kernels: the MXU takes the tiles in the dtype they
+  arrive in, with float32 accumulation (float32 callers get float32
+  products, bf16 callers bf16 ones); ``p`` (and the backward's ``ds``) is
+  rounded to that dtype before its second product, as
+  ``parallel.ring_attention.dense_attention`` rounds ``p``; the scale is
+  applied after the product; the running max, the exponent, ``l``, the
+  accumulators, ``lse`` and ``delta = rowsum(do·o)`` are float32.
+- masks, all three kernels (``_when_live``, ``_kv_tile``/``_q_tile``):
+  causal dead tile pairs are skipped AND not fetched (their index is
+  clamped to the nearest live tile's); only a pair that straddles the
+  diagonal builds the causal compare; ``kv_mask`` ([B, S] 0/1) streams as
+  (1, BK) tiles and masks padded key positions — the BERT attention-mask
+  contract, so flash drops into padded encoder batches, not just causal
+  LMs. A query with no attendable key outputs zeros.
+- the forward holds its score tile TRANSPOSED, (BK, BQ): a query is a lane,
+  so the softmax's max and sum over the keys are elementwise vector work
+  down the sublanes, not reductions across the lanes; ``m``, ``l`` and the
+  logsumexp are (1, BQ) rows and the accumulator is (D, BQ), transposed
+  once per Q tile into the output.
 - the logsumexp output is blocked (1, BQ) per q-tile program — every store
   is a full-block write, no dynamic lane-dim slicing (round-1 advisor
   flagged the previous ``pl.ds`` store as a Mosaic alignment risk).
@@ -28,15 +43,9 @@ Design (the standard streaming-softmax factorization, written for the MXU):
   grid; dq accumulates). Each recomputes its score tile from the saved
   logsumexp (``p = exp(q·kᵀ·scale − lse)``), so activations stay O(S·D) —
   the flash-attention memory contract — and no score-sized array is ever
-  an HBM operand. The MXU takes the inputs' dtype with float32
-  accumulation; ``p`` and ``ds`` are rounded to it before their second
-  products, as ``parallel.ring_attention.dense_attention`` rounds ``p``;
-  lse, ``delta = rowsum(do·o)``, the exponent and the accumulators are
-  float32. Causal dead tile pairs are skipped AND not fetched (their index
-  is clamped to the nearest live tile's); a pair wholly below the diagonal
-  skips the causal compare. The dkv kernel holds its score tile transposed,
-  (BK, BQ), so the row statistics broadcast from their lane-oriented
-  blocks and no score-sized transpose is needed in either kernel.
+  an HBM operand. The dkv kernel holds its score tile transposed too, so
+  the row statistics broadcast from their lane-oriented blocks and no
+  score-sized transpose is needed in any kernel.
 
 ``interpret=True`` (or platform != tpu) runs the same kernel through the
 Pallas interpreter — how CPU tests validate kernel semantics; a TPU-gated
@@ -55,7 +64,72 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-_LANES = 128  # per-row stats live broadcast across one lane tile
+_LANES = 128  # the backward keeps per-row stats broadcast across a lane tile
+
+
+def _tile_is_live(qi, ki, block_q: int, block_k: int):
+    """Under ``causal``: whether Q tile ``qi`` has any row at or past KV
+    tile ``ki``'s first column — the one rule all three kernels'
+    ``pl.when`` and index maps follow (ints in, bool out; traced in, traced
+    out)."""
+    return ki * block_k <= qi * block_q + block_q - 1
+
+
+def _first_live_q(ki, block_q: int, block_k: int):
+    """Under ``causal`` the Q tiles dead for KV tile ``ki`` are the leading
+    ones: the first live one."""
+    return (ki * block_k) // block_q
+
+
+def _last_live_kv(qi, block_q: int, block_k: int):
+    """Under ``causal`` the KV tiles dead for Q tile ``qi`` are the trailing
+    ones: the last live one."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _kv_tile(qi, ki, block_q: int, block_k: int, causal: bool):
+    """The KV tile a (Q tile ``qi``, KV tile ``ki``) grid step fetches: a
+    dead pair does no work and fetches nothing — its index is clamped to
+    the nearest live tile's, whose block the pipeline already holds."""
+    return (jnp.minimum(ki, _last_live_kv(qi, block_q, block_k))
+            if causal else ki)
+
+
+def _q_tile(qi, ki, block_q: int, block_k: int, causal: bool):
+    """``_kv_tile``'s twin for the kernel that streams Q tiles past a KV
+    tile."""
+    return (jnp.maximum(qi, _first_live_q(ki, block_q, block_k))
+            if causal else qi)
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract both operands' last dim
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b: contract both operands' first dim
+
+
+def _as_column(row):
+    """(1, N) lane-oriented row -> (N, _LANES) with every lane of row n
+    holding ``row[0, n]``: per-position values arrive in ``lse``'s
+    lane-oriented layout and a score tile needs them down its sublanes.
+    A sublane broadcast and one aligned 2-D transpose. The backward
+    kernels do it once per accumulator into VMEM scratch; the forward,
+    whose keys change every grid step, once per tile pair for the (1, BK)
+    key-validity row (0.65 of its 13.3 ms at the training cell's shape)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _when_live(update, qi, ki, block_q: int, block_k: int, causal: bool):
+    """Run ``update(on_diagonal)`` for a live tile pair and nothing for a
+    dead one. Only a pair that straddles the diagonal (some column past
+    some row) pays for the causal compare; a pair wholly below it is as
+    unmasked as a non-causal one."""
+    if not causal:
+        update(False)
+        return
+    live = _tile_is_live(qi, ki, block_q, block_k)
+    straddles = ki * block_k + block_k - 1 > qi * block_q
+    pl.when(live & straddles)(functools.partial(update, True))
+    # no column past any row: wholly below the diagonal, so live
+    pl.when(jnp.logical_not(straddles))(functools.partial(update, False))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
@@ -63,12 +137,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
                 seq_len: int):
     """Grid = (B·H, Q tiles, KV tiles); KV tiles stream through VMEM via the
     innermost grid dimension (pallas pipelines the HBM loads), while the
-    (BQ, D) accumulator and per-row (m, l) stats persist in VMEM scratch
+    accumulator and the per-query (m, l) stats persist in VMEM scratch
     across KV steps. VMEM holds one Q, one K, one V tile + scratch — never
-    the full sequence."""
+    the full sequence.
+
+    The score tile is held TRANSPOSED, (BK, BQ), as the dkv kernel holds
+    its own: a query is a lane, so the softmax's max and sum over the keys
+    run down the sublanes — elementwise vector work — and not across the
+    lanes (512 x 512 row reductions across lanes were 10 of this kernel's
+    25 ms at the training cell's shape). ``m``, ``l`` and ``lse`` are
+    (1, BQ) rows, the accumulator is (D, BQ), and the one transpose is the
+    output's, once per Q tile. Both products take the tiles in the dtype
+    they arrive in (``p`` rounded to ``v``'s) with float32 accumulation;
+    the max, the exponent, ``alpha``, ``l``, the accumulator, ``lse`` and
+    the final division are float32."""
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     qi, ki = pl.program_id(1), pl.program_id(2)
-    n_kv = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -76,54 +160,50 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: tiles strictly above the diagonal contribute nothing.
-    live = (True if not causal
-            else ki * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(live)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # (BQ, D)
-        k = k_ref[0].astype(jnp.float32)                   # (BK, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        col_ids = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = col_ids < seq_len
-        mask = mask & (mask_ref[0, 0].astype(jnp.float32) > 0)
-        if causal:
-            row_ids = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (col_ids <= row_ids)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        # fully-masked-so-far rows: keep the accumulator at exact zero
-        p = jnp.where(m_new[:, None] <= NEG_INF, 0.0, p)
+    def _update(on_diagonal: bool):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        st = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        valid = (cols < seq_len) & (mask_ref[0, 0] > 0)     # (1, BK)
+        keep = _as_column(valid.astype(jnp.float32))[:, :1] > 0   # (BK, 1)
+        if on_diagonal:
+            shape = (block_k, block_q)
+            keep = keep & (
+                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        st = jnp.where(keep, st, NEG_INF)
+        m_prev = m_ref[:]                                   # (1, BQ)
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        # A masked entry is exactly NEG_INF: less any real maximum it is
+        # still -1e30 and its exponent exactly 0. A query with no key so
+        # far has m_new = NEG_INF; 0 stands in for it, so its p is 0 too
+        # and its accumulator stays at exact zero (no select on the tile).
+        pt = jnp.exp(st - jnp.where(m_new <= NEG_INF, 0.0, m_new))  # (BK, BQ)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(pt, axis=0, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            v, pt.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
 
-    @pl.when(ki == n_kv - 1)
+    _when_live(_update, qi, ki, block_q, block_k, causal)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
-        m = m_ref[:, 0]
-        l = l_ref[:, 0]
+        l = l_ref[:]
         safe_l = jnp.where(l > 0, l, 1.0)  # fully-masked rows (padding)
-        o_ref[0] = (acc_ref[:] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / safe_l).T.astype(o_ref.dtype)
         # lse rides in the PRE-BLOCKED 4-D layout (B·H, Sq tiles, 1, BQ):
         # its (1, 1, 1, BQ) block's trailing dims (1, BQ) EQUAL the array
         # dims, which satisfies Mosaic's block rule (sublane ∈ 8ℤ ∪
         # {array dim}, lane ∈ 128ℤ ∪ {array dim}) for ANY BQ, and the
         # in-kernel store stays a plain 2-D (1, BQ) lane-oriented write —
-        # no 1-D sublane vectors, no transpose. The real chip rejects the
+        # the layout the stats are kept in. The real chip rejects the
         # flat layouts ((1, BQ) block over (B·H, S): sublane 1 ∤ 8 ≠ B·H;
         # (…, 1, BQ) block over (B·H, 1, S): BQ < 128 ∤ 128) — a round-5
         # on-chip finding the interpreter cannot reproduce.
-        lse_ref[0, 0] = (m + jnp.log(safe_l))[None, :]
+        lse_ref[0, 0] = m_ref[:] + jnp.log(safe_l)
 
 
 def _tiles(s: int, block_q: int, block_k: int):
@@ -171,15 +251,20 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
     from jax.experimental.pallas import tpu as pltpu
 
     grid = (b * h, s_pad // bq, s_pad // bk)
+
+    def kv_tile(bh, i, j):
+        return bh, _kv_tile(i, j, bq, bk, causal)
+
+    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, i, j: (*kv_tile(bh, i, j), 0))
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal,
                           sm_scale=sm_scale, seq_len=s),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, 1, 1, bk), lambda bh, i, j: (bh, j, 0, 0)),
+            rows_kv, rows_kv,
+            pl.BlockSpec((1, 1, 1, bk),
+                         lambda bh, i, j: (*kv_tile(bh, i, j), 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -190,9 +275,9 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((b * h, s_pad // bq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),        # acc
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max m
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # normalizer l
+            pltpu.VMEM((d, bq), jnp.float32),    # acc, transposed
+            pltpu.VMEM((1, bq), jnp.float32),    # running max m
+            pltpu.VMEM((1, bq), jnp.float32),    # normalizer l
         ],
         # (bh, q-tile) carry no cross-step state — only the innermost kv
         # dimension threads the (acc, m, l) scratch — so Mosaic may
@@ -206,53 +291,6 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
         o3, lse2 = call(q3, k3, v3, m4)
     return (o3[:, :s].reshape(b, h, s, d),
             lse2.reshape(b * h, s_pad)[:, :s].reshape(b, h, s))
-
-
-def _tile_is_live(qi, ki, block_q: int, block_k: int):
-    """Under ``causal``: whether Q tile ``qi`` has any row at or past KV
-    tile ``ki``'s first column — the one rule both backward kernels'
-    ``pl.when`` and index maps follow (ints in, bool out; traced in, traced
-    out)."""
-    return ki * block_k <= qi * block_q + block_q - 1
-
-
-def _first_live_q(ki, block_q: int, block_k: int):
-    """Under ``causal`` the Q tiles dead for KV tile ``ki`` are the leading
-    ones: the first live one."""
-    return (ki * block_k) // block_q
-
-
-def _last_live_kv(qi, block_q: int, block_k: int):
-    """Under ``causal`` the KV tiles dead for Q tile ``qi`` are the trailing
-    ones: the last live one."""
-    return (qi * block_q + block_q - 1) // block_k
-
-
-_NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract both operands' last dim
-
-
-def _as_column(row):
-    """(1, N) lane-oriented row -> (N, _LANES) with every lane of row n
-    holding ``row[0, n]``: the per-row statistics arrive in ``lse``'s
-    lane-oriented layout and a score tile needs them down its sublanes.
-    A sublane broadcast and one aligned 2-D transpose, done once per
-    accumulator (not once per tile pair) into VMEM scratch."""
-    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
-
-
-def _when_live(update, qi, ki, block_q: int, block_k: int, causal: bool):
-    """Run ``update(on_diagonal)`` for a live tile pair and nothing for a
-    dead one. Only a pair that straddles the diagonal (some column past
-    some row) pays for the causal compare; a pair wholly below it is as
-    unmasked as a non-causal one."""
-    if not causal:
-        update(False)
-        return
-    live = _tile_is_live(qi, ki, block_q, block_k)
-    straddles = ki * block_k + block_k - 1 > qi * block_q
-    pl.when(live & straddles)(functools.partial(update, True))
-    # no column past any row: wholly below the diagonal, so live
-    pl.when(jnp.logical_not(straddles))(functools.partial(update, False))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -369,13 +407,11 @@ def _bwd(q, k, v, kv_mask, o, lse, do, causal: bool, block_q: int,
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
-    # A dead pair does no work and fetches nothing: its index is clamped to
-    # the nearest live tile's, whose block the pipeline already holds.
     def q_tile(bh, j, i):
-        return (bh, jnp.maximum(i, _first_live_q(j, bq, bk)) if causal else i)
+        return bh, _q_tile(i, j, bq, bk, causal)
 
     def kv_tile(bh, i, j):
-        return (bh, jnp.minimum(j, _last_live_kv(i, bq, bk)) if causal else j)
+        return bh, _kv_tile(i, j, bq, bk, causal)
 
     rows_q = pl.BlockSpec((1, bq, d), lambda bh, j, i: (*q_tile(bh, j, i), 0))
     stat_q = pl.BlockSpec((1, 1, 1, bq),

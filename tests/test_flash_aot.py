@@ -92,3 +92,18 @@ def test_other_callers_shapes_compile(one_chip, shape, dtype, causal, masked,
     compiled = _compiled_grad(one_chip, shape, dtype, causal, masked=masked,
                               block_q=blocks[0], block_k=blocks[1])
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_forward_alone_at_llamas_head(one_chip, dtype):
+    """The forward alone at D = 128 and 512 x 512 blocks, causal — llama's
+    head, the largest VMEM need of the forward's body (a (512, 512) float32
+    score tile and its exponent, a (128, 512) float32 accumulator and its
+    transpose into the output) — in bf16 and in the tests' float32."""
+    x = jax.ShapeDtypeStruct((1, 8, 4096, 128), dtype, sharding=one_chip)
+    text = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, True, block_q=512, block_k=512, interpret=False)).lower(
+            x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_attention_fwd" in text

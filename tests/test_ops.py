@@ -186,7 +186,7 @@ def test_backward_fully_masked_rows(causal):
 
 
 def test_causal_live_tile_rule():
-    """The one rule both backward kernels' ``pl.when`` and index maps
+    """The one rule all three kernels' ``pl.when`` and index maps
     follow: at the cell's shape (S = 8192, 512-blocks) 136 of the 256 tile
     pairs are live; the clamped index of a dead pair is the nearest live
     tile's and a live pair's is its own."""
@@ -247,14 +247,160 @@ def test_backward_skips_dead_causal_tiles(bq, bk, clamped, monkeypatch):
                                np.asarray(clean[0][:, :, :-blk]), atol=1e-6)
 
 
-def test_bf16_inputs():
-    q, k, v = [x.astype(jnp.bfloat16) for x in _rand_qkv()]
-    o = flash_attention(q, k, v, True)
+def _fwd_outputs(q, k, v, kv_mask, causal, blocks):
+    """(o, lse) of the forward kernel alone, at explicit or default blocks."""
+    b, _, s, _ = q.shape
+    if kv_mask is None:
+        kv_mask = jnp.ones((b, s), jnp.float32)
+    bq, bk = (blk or _fa._default_block(s) for blk in blocks)
+    return _fa._fwd(q, k, v, kv_mask, causal, bq, bk, _fa._resolve(None))
+
+
+def _lse_reference(q, k, kv_mask, causal):
+    """float32 logsumexp of the masked, scaled scores."""
+    s = q.shape[2]
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                    k.astype(jnp.float32)) / np.sqrt(q.shape[-1])
+    valid = jnp.ones((1, 1, s, s), bool)
+    if kv_mask is not None:
+        valid = valid & (kv_mask[:, None, None, :] > 0)
+    if causal:
+        valid = valid & jnp.tril(jnp.ones((s, s), bool))
+    return jax.nn.logsumexp(jnp.where(valid, sc, -jnp.inf), axis=-1)
+
+
+# Float32 inputs against a reference at the HIGHEST precision. Interpreted,
+# the kernel's float32 products are exact; compiled on the chip they run at
+# the default precision, one bf16 pass that rounds q, k, p and v (PR 32's
+# on-chip reading: one entry of 24,576 off by 0.0038 under ``causal``, where
+# the first rows' outputs are O(1)) — FWD_ATOL's 2e-3 there was read against
+# a dense reference rounded the same way.
+F32_ATOL = 8e-3 if is_tpu_backend() else FWD_ATOL
+
+
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 32), (None, None)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kvmask"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_forward_precision_follows_the_inputs(dtype, causal, masked, blocks):
+    """The forward kernel hands the MXU the inputs' dtype. float32 callers
+    get float32 products and stay at FWD_ATOL; bf16 callers get bf16
+    products with ``p`` rounded to bf16 before the second one: against
+    float32 dense attention of the same bf16 values the gap is bf16's
+    (2**-9 of an output of up to ~1, the output's own rounding included;
+    readings 0.0004-0.0014), and against the program's own dense path on
+    the bf16 values (which rounds ``p`` the same way, and on the CPU the
+    scores too: its own gap to float32 reads 0.0027) a little more. ``lse``
+    comes from the float32 accumulation of exact bf16 products, so it
+    meets a float32 ``logsumexp`` at float32's tolerance: the backward
+    pair recomputes ``p`` from it."""
+    s = 128
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(s=s))
+    kv_mask = _len_mask(s, [s, 51]) if masked else None
+    o, lse = _fwd_outputs(q, k, v, kv_mask, causal, blocks)
+    assert o.dtype == dtype and lse.dtype == jnp.float32
+    o = np.asarray(o, np.float32)
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(dense_attention(
+            *(x.astype(jnp.float32) for x in (q, k, v)), causal, kv_mask))
+        want_lse = np.asarray(_lse_reference(q, k, kv_mask, causal))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(o, ref, atol=F32_ATOL)
+    else:
+        np.testing.assert_allclose(o, ref, atol=4e-3)
+        assert np.linalg.norm(o - ref) / np.linalg.norm(ref) < 4e-3
+        same_rounding = np.asarray(dense_attention(q, k, v, causal, kv_mask),
+                                   np.float32)
+        np.testing.assert_allclose(o, same_rounding, atol=8e-3)
+    np.testing.assert_allclose(np.asarray(lse), want_lse,
+                               atol=FWD_ATOL if dtype == jnp.bfloat16
+                               else F32_ATOL)
+
+
+@pytest.mark.parametrize("wide", ["k", "v"])
+def test_forward_mixed_dtypes(wide):
+    """One float32 operand among bf16 ones: the first product promotes as
+    ``jnp`` does, ``p`` takes ``v``'s dtype (the dense path's convention),
+    and the output keeps ``q``'s."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in _rand_qkv())
+    if wide == "k":
+        k = k.astype(jnp.float32)
+    else:
+        v = v.astype(jnp.float32)
+    o = flash_attention(q, k, v, True, block_q=64, block_k=64)
     assert o.dtype == jnp.bfloat16
-    ref = dense_attention(q.astype(jnp.float32), k.astype(jnp.float32),
-                          v.astype(jnp.float32), True)
-    np.testing.assert_allclose(np.asarray(o, dtype=np.float32),
-                               np.asarray(ref), atol=3e-2)
+    with jax.default_matmul_precision("highest"):
+        ref = dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                              True)
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(ref),
+                               atol=4e-3)
+
+
+def _walk_eqns(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in its
+    equations' parameters, with the names of the pallas calls around it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        within = inside
+        if eqn.primitive.name == "pallas_call":
+            within = inside + (eqn.params["name"],)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub, within)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_forward_products_take_the_inputs_dtype(dtype, causal):
+    """Structural: every ``dot_general`` traced inside ``flash_attention_fwd``
+    has both operands in the inputs' dtype and accumulates in float32 — so a
+    later edit cannot put the float32 up-cast back unseen. Nothing else
+    would notice: on the v5e a default-precision float32 product inside a
+    kernel is one bf16 pass, so neither the result nor the time moves (PR
+    32's ladder); the up-cast doubles the tiles' VMEM and leaves the
+    kernel's speed to the compiler's default precision."""
+    q = jnp.ones((1, 2, 128, 64), dtype)
+    jaxpr = jax.make_jaxpr(lambda a, b, c: flash_attention(
+        a, b, c, causal, block_q=64, block_k=64))(q, q, q)
+    dots = [eqn for eqn, inside in _walk_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and inside[-1:] == ("flash_attention_fwd",)]
+    # two products an update; a causal kernel traces the update twice
+    # (on and below the diagonal)
+    assert len(dots) == (4 if causal else 2)
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("clamped", [True, False],
+                         ids=["clamped", "unclamped"])
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 64)])
+def test_forward_skips_dead_causal_tiles(bq, bk, clamped, monkeypatch):
+    """The forward's twin of ``test_backward_skips_dead_causal_tiles``: NaN
+    in the last KV tile's k, v and mask reaches only the Q tile that
+    legitimately reads it; ``o`` and ``lse`` of every other Q tile are
+    clean. ``unclamped`` takes the index maps' clamp away, so the dead
+    pairs' NaN blocks ARE fetched and ``pl.when`` alone keeps them out."""
+    if not clamped:
+        monkeypatch.setattr(_fa, "_last_live_kv", lambda qi, bq, bk: 1 << 20)
+    s, blk = 256, max(bq, bk)
+    q, k, v = _rand_qkv(s=s, d=16, seed=21)
+    ones = jnp.ones((2, s), jnp.float32)
+    clean_o, clean_lse = _fwd_outputs(q, k, v, ones, True, (bq, bk))
+    o, lse = _fwd_outputs(q, k.at[:, :, -blk:].set(jnp.nan),
+                          v.at[:, :, -blk:].set(jnp.nan),
+                          ones.at[:, -blk:].set(jnp.nan), True, (bq, bk))
+    np.testing.assert_allclose(np.asarray(o[:, :, :-blk]),
+                               np.asarray(clean_o[:, :, :-blk]), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(lse[:, :, :-blk]),
+                               np.asarray(clean_lse[:, :, :-blk]), atol=1e-6)
+    assert np.isnan(np.asarray(o[:, :, -blk:])).any()   # the NaN was there
 
 
 def test_jit_and_blocks_smaller_than_seq():
