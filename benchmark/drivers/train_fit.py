@@ -58,6 +58,7 @@ class Feeder:
         self.spans = []
         self.done = False
         self.marks = {}               # perf_counter at the parts of set-up
+        self.memory = {}              # the first chip's memory at each mark
 
     # -- listeners -----------------------------------------------------------
     def on_duration(self, event, duration, **_kw):
@@ -73,6 +74,15 @@ class Feeder:
             self.spans.append({"name": rec["name"], "t": rec["t"],
                                "dur_s": rec["dur_s"],
                                "bytes": rec.get("bytes")})
+
+    def mark(self, name: str) -> float:
+        """Note the first chip's memory at a part of set-up, then the time."""
+        import jax
+        stats = jax.local_devices()[0].memory_stats() or {}
+        self.memory[name] = {k: int(stats.get(k, 0)) for k in (
+            "bytes_in_use", "peak_bytes_in_use")}
+        self.marks[name] = time.perf_counter()
+        return self.marks[name]
 
     # -- the first steps: what ``correct`` compares -----------------------------
     def _snapshot(self):
@@ -110,13 +120,13 @@ class Feeder:
         if self.k <= 1:
             # asked for batch 0: fit has placed its state; for batch 1: the
             # first step is traced, compiled or loaded, and dispatched
-            self.marks["fit_asks_batch_%d" % self.k] = time.perf_counter()
+            self.mark("fit_asks_batch_%d" % self.k)
         if self.k <= CHECK_STEPS + 1:
             self._snapshot()
         if self.k == self.warmup:
             # fit has just fetched the loss of step k (k % log_every == 0):
             # the device is drained, the clock starts
-            self.t0 = time.perf_counter()
+            self.t0 = self.mark("window_opens")
         if self.t0 is not None:
             elapsed = time.perf_counter() - self.t0
             if elapsed >= self.seconds:
@@ -182,10 +192,10 @@ def run(job: dict) -> dict:
         # fit takes host parameters and places them itself
         weights = jax.tree_util.tree_map(
             np.asarray, make_weights(ref, cfg, job["seed"]))
-        t_weights = time.perf_counter()
-        pool = make_pool(traffic, cfg, job["seed"], ctx.size)
-        feeder = Feeder(job, pool, ref.trainable(weights), prog, cfg)
-        feeder.marks.update(weights=t_weights, pool=time.perf_counter())
+        feeder = Feeder(job, None, ref.trainable(weights), prog, cfg)
+        feeder.mark("weights")
+        pool = feeder.pool = make_pool(traffic, cfg, job["seed"], ctx.size)
+        feeder.mark("pool")
         jax.monitoring.register_event_duration_secs_listener(
             feeder.on_duration)
         if job["trace"]:
@@ -276,11 +286,15 @@ def run(job: dict) -> dict:
                             for i in range(CHECK_STEPS)],
                  "grad1": r["grad1"] or {}, "delta": r["delta"] or {}}
     ref_read = run_steps(ref, cfg, weights, pool[:CHECK_STEPS])
+    # the runtime's peak only grows: this reads the larger of the program's
+    # peak and the reference's (memory_peak_bytes was taken before it ran)
+    after_reference = int((used[0].memory_stats() or {}).get(
+        "peak_bytes_in_use", 0))
     numbers, where = compare.training_numbers(prog_read, ref_read)
     correct, compared = compare.judge(numbers, res["limits"]["limits"])
     result["correct"] = bool(correct)
     # where set-up went: seconds from the process's start to each mark
-    marks = dict(imports=t_imports, **feeder.marks, window_opens=feeder.t0)
+    marks = dict(imports=t_imports, **feeder.marks)
     result["setup_parts_s"] = {k: v - job["t_start"]
                                for k, v in sorted(marks.items(),
                                                   key=lambda kv: kv[1])}
@@ -297,7 +311,10 @@ def run(job: dict) -> dict:
         "not_compared": {k: v for k, v in numbers.items()
                          if k not in res["limits"]["limits"]},
         "peak_bytes_in_use": int(stats[0].get("peak_bytes_in_use", 0)),
-        "peak_bytes_reserved": int(stats[0].get("peak_bytes_reserved", 0))}
+        "peak_bytes_reserved": int(stats[0].get("peak_bytes_reserved", 0)),
+        "peak_bytes_in_use_after_reference": after_reference,
+        "bytes_limit": int(stats[0].get("bytes_limit", 0)),
+        "memory_at_marks": feeder.memory}
     result["compared"] = compared
     if job.get("keep_readings"):
         result["readings"] = {"program": prog_read, "reference": ref_read}

@@ -11,7 +11,10 @@ times the forward pass; nothing recomputed is counted.
 
 For the flash-attention forward kernel, from the same shapes: the operations
 of those two products, and the bytes it cannot avoid: q in, the output out,
-each key and value head once, the row statistics.
+each key and value head once, the row statistics. For the backward kernel
+pair: five products over the same pairs (the scores again, dv, dp, dq, dk),
+2.5 times the forward's operations, and q, k, v, o, do in and dq, dk, dv out
+once, with both row statistics.
 """
 
 from __future__ import annotations
@@ -79,3 +82,19 @@ def flash_attention_fwd_per_example(cfg: dict, traffic: dict) -> dict:
     stats = seq * cfg["num_attention_heads"] * 4
     return {"flops": attention_flops_per_sequence(cfg, seq),
             "bytes": float(layers * (q_and_o + k_and_v + stats))}
+
+
+def flash_attention_bwd_per_example(cfg: dict, traffic: dict) -> dict:
+    """``{"flops", "bytes"}`` one sequence needs of attention's backward
+    pass: five products over the causal pairs for the forward's two; q, o, do
+    in and dq out at the query heads' width, k, v in and dk, dv out at the
+    key/value heads', the two row statistics (lse, rowsum(do * o))."""
+    seq, d = _seq(traffic), cfg["hidden_size"]
+    hd = d // cfg["num_attention_heads"]
+    layers = sum(k != "conv" for k in _kinds(cfg))
+    act = 2  # bytes of a bfloat16
+    q_side = 4 * seq * d * act
+    kv_side = 4 * seq * cfg["num_key_value_heads"] * hd * act
+    stats = 2 * seq * cfg["num_attention_heads"] * 4
+    return {"flops": 2.5 * attention_flops_per_sequence(cfg, seq),
+            "bytes": float(layers * (q_side + kv_side + stats))}

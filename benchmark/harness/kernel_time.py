@@ -24,6 +24,34 @@ def window_seconds(ctx: dict, match) -> tuple | None:
     return busy / 1e9, (hi - lo) / 1e9, steps
 
 
+def named(prefix: str):
+    """A ``match`` for the operations whose own name starts with ``prefix``.
+    The operation's own name, not its HLO line: a consumer's line names the
+    kernel among its operands."""
+    return lambda name: trace_lib.short_name(name).startswith(prefix)
+
+
+def roofline_share(ctx: dict, metric: str, prefix: str, need: str):
+    """A kernel's share (%) of its roofline: the least time the chip could
+    take for what the MODEL needs of it in the steady window's steps (the
+    flops module's function ``need`` gives ``{"flops", "bytes"}`` an example;
+    the larger of operations over the bf16 peak and bytes over the memory's
+    rate), over the device time of the operations named ``prefix*``. None
+    where no such operation ran, or the run is of no cell on ``metric``'s
+    list."""
+    got = window_seconds(ctx, named(prefix))
+    if got is None or got[0] <= 0:
+        return None
+    cell = cell_of(metric, ctx)
+    if cell is None:
+        return None
+    n = getattr(cell["flops_module"], need)(cell["config"], cell["traffic"])
+    examples = ctx["global_batch"] / ctx["chips"] * got[2]
+    least = max(n["flops"] / ctx["peak"]["bf16_flops_per_s"],
+                n["bytes"] / ctx["peak"]["hbm_bytes_per_s"]) * examples
+    return 100.0 * least / got[0]
+
+
 def cell_of(metric: str, ctx: dict) -> dict | None:
     """The resolved cell this run is of: the one among the metric's own
     ``workloads`` whose operations per example and global batch are the

@@ -40,6 +40,20 @@ def leaf_norms(tree) -> dict:
             for k, v in flat(tree).items()}
 
 
+def jitted_calls(ref, cfg: dict, precision: str = "float32") -> tuple:
+    """``(gradient, update)``: the reference's two jitted calls, built here
+    and nowhere else. The update donates the parameters and the optimizer's
+    state, so its outputs take their buffers: at the update the device holds
+    parameters, gradient and the optimizer's trees once (16 bytes a parameter
+    under Adam), as a training step does. The gradient is not donated: no
+    output could take its buffer; its caller drops it after the update."""
+    grad = jax.jit(jax.value_and_grad(
+        lambda p, b: ref.loss_fn(cfg, p, b, precision)))
+    update = jax.jit(lambda p, g, o, i: ref.opt_update(cfg, p, g, o, i),
+                     donate_argnums=(0, 2))
+    return grad, update
+
+
 def run_steps(ref, cfg: dict, weights, batches: list, *,
               precision: str = "float32", rows=None,
               frozen: bool = False) -> dict:
@@ -48,13 +62,16 @@ def run_steps(ref, cfg: dict, weights, batches: list, *,
     ``rows``: a slice of each batch's rows to keep (the planted fault "half
     of the batch left out, the mean taken over the rest"); None keeps all.
     Returns the losses, the leaf norms of the first gradient and of the
-    parameters' change after the last step."""
+    parameters' change after the last step. ``weights`` are left as they
+    were: the parameters stepped are a device copy of their host values, and
+    the change is taken on the host."""
     with jax.default_matmul_precision("highest"):
-        params0 = ref.trainable(weights)
-        grad = jax.jit(jax.value_and_grad(
-            lambda p, b: ref.loss_fn(cfg, p, b, precision)))
-        update = jax.jit(lambda p, g, o, i: ref.opt_update(cfg, p, g, o, i))
-        params, opt = params0, ref.opt_init(cfg, params0)
+        params0 = flat(jax.device_get(ref.trainable(weights)))
+        grad, update = jitted_calls(ref, cfg, precision)
+        # copies: a donated buffer is the caller's no longer, and a state
+        # whose trees share their zeros cannot be donated twice
+        params = jax.tree_util.tree_map(jnp.array, ref.trainable(weights))
+        opt = jax.tree_util.tree_map(jnp.array, ref.opt_init(cfg, params))
         losses, g1 = [], None
         for i, b in enumerate(batches):
             b = {k: jnp.asarray(v[rows] if rows is not None else v)
@@ -67,5 +84,9 @@ def run_steps(ref, cfg: dict, weights, batches: list, *,
                     g1 = dict.fromkeys(g1, 0.0)
             if not frozen:
                 params, opt = update(params, g, opt, jnp.float32(i + 1))
-        delta = jax.tree_util.tree_map(lambda a, b_: a - b_, params, params0)
-        return {"losses": losses, "grad1": g1, "delta": leaf_norms(delta)}
+            del g    # or it would wait on the device through the next call
+        # the change is taken on the host, leaf by leaf: a second copy of the
+        # parameters on the device is memory a large cell does not have
+        delta = leaf_norms({k: np.asarray(v) - params0[k]
+                            for k, v in flat(params).items()})
+        return {"losses": losses, "grad1": g1, "delta": delta}

@@ -118,13 +118,16 @@ def main_module(plane: dict):
 
 
 def steady_window(plane: dict):
-    """``(lo_ns, hi_ns, steps)``: from the start of the first whole step in
-    the trace to the start of the last, a whole number of step periods, so the
-    edges of the trace weigh nothing. None where under three steps ran."""
+    """``(lo_ns, hi_ns, steps)``: from the start of the SECOND step program in
+    the trace to the start of the last, a whole number of step periods. The
+    trace starts wherever the host starts it, as a rule inside a step: its
+    first program is cut, with a false gap before it and part of its
+    operations missing, so it is left out, like the last. None where under
+    four programs ran."""
     _, evs = main_module(plane)
-    if len(evs) < 3:
+    if len(evs) < 4:
         return None
-    return evs[0][0], evs[-1][0], len(evs) - 1
+    return evs[1][0], evs[-1][0], len(evs) - 2
 
 
 def device_summary(trace: dict):

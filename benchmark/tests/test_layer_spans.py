@@ -17,9 +17,12 @@ def _read(metric, ctx):
 
 
 def _device(gap_ns):
-    """STEPS + 1 runs of the step's program, one operation each, and before
+    """What is left of the step the trace started in, a false gap, then
+    STEPS + 1 runs of the step's program, one operation each, and before
     step i an idle gap of ``gap_ns(i)``."""
-    mods, ops, t = [], [], 5_000
+    mods, ops = [("jit_step", 5_000, STEP_NS // 2)], \
+        [("fusion.1", 5_000, STEP_NS // 2)]
+    t = 5_000 + STEP_NS // 2 + 300_000
     for i in range(STEPS + 1):
         t += gap_ns(i)
         mods.append(("jit_step", t, STEP_NS))

@@ -31,7 +31,8 @@ def test_the_lfm2_cell_resolves_to_its_own_files():
     assert res["traffic"]["per_chip_batch"] == 2
     names = {m["name"] for m in res["per_layer"]}
     assert names >= {"train_step_mfu", "moe_held_share",
-                     "moe_load_max_over_mean", "flash_attention_fwd_roofline"}
+                     "moe_load_max_over_mean", "flash_attention_fwd_roofline",
+                     "flash_attention_bwd_roofline"}
     assert set(res["limits"]["limits"]) <= {
         "loss_1", "loss_2", "loss_3", "grad1_leaf", "delta_leaf", "grad1_all",
         "delta_all"} and res["limits"]["limits"]
@@ -187,3 +188,68 @@ def test_flash_roofline_counts_the_models_need_against_the_kernels_time():
     assert _read("flash_attention_fwd_roofline", _ctx(bare, res, flops)) \
         is None
     assert _read("flash_attention_fwd_roofline", {}) is None
+
+
+def test_flash_backward_need_against_a_hand_count():
+    res = loader.resolve_cell(LFM2)
+    flops = loader.load_module("flops", "lfm2-8b-a1b")
+    need = flops.flash_attention_bwd_per_example(res["config"], res["traffic"])
+    # one attention layer: five products of 2 x 64 operations a head and
+    # pair, 32 heads, 8192 x 8193 / 2 causal pairs
+    assert need["flops"] == 5 * 2 * 64 * 32 * 8192 * 8193 // 2
+    fwd = flops.flash_attention_fwd_per_example(res["config"], res["traffic"])
+    assert need["flops"] == pytest.approx(2.5 * fwd["flops"])
+    # q, o, do, dq at 32 heads and k, v, dk, dv at 8, bf16; lse and
+    # rowsum(do * o) in float32 a head and row
+    assert need["bytes"] == 4 * 8192 * 2048 * 2 + 4 * 8192 * 512 * 2 \
+        + 2 * 8192 * 32 * 4
+    # 6.98 ms a step of two sequences at the bf16 peak (PERF.md section 5)
+    assert 2 * need["flops"] / 197e12 == pytest.approx(6.98e-3, rel=2e-3)
+    assert need["flops"] / 197e12 > need["bytes"] / 819e9   # compute-bound
+
+
+def test_flash_backward_roofline_sums_the_pair_and_nothing_else():
+    res = loader.resolve_cell(LFM2)
+    flops = loader.load_module("flops", "lfm2-8b-a1b")
+    need = flops.flash_attention_bwd_per_example(res["config"], res["traffic"])
+    least_s = 2 * need["flops"] / 197e12       # two sequences a step
+    dkv = "%flash_attention_bwd_dkv.1 = (bf16[64,8192,64]) custom-call()"
+    dq = "%flash_attention_bwd_dq.1 = bf16[64,8192,64] custom-call()"
+    fwd = "%flash_attention_fwd.3 = (bf16[64,8192,64]) custom-call()"
+    consumer = "%fusion.9 = f32[] fusion(%flash_attention_bwd_dq.1)"
+    # the pair takes four times the least time between them: a quarter
+    dur = int(2 * least_s * 1e9)
+    tr = _trace([(fwd, 1_000_000), (dkv, dur), (consumer, 1_000_000),
+                 (dq, dur)], step_ns=80_000_000)
+    ctx = _ctx(tr, res, flops)
+    assert _read("flash_attention_bwd_roofline", ctx) == pytest.approx(
+        25.0, rel=1e-3)
+    # the forward's reader, through the same helper, sees its own kernel only
+    assert _read("flash_attention_fwd_roofline", ctx) > 100   # 1 ms: made up
+    # in a cell the metric does not list, and on a trace without the pair
+    assert _read("flash_attention_bwd_roofline",
+                 dict(ctx, global_batch=128)) is None
+    bare = _trace([(fwd, 1_000_000)])
+    assert _read("flash_attention_bwd_roofline", _ctx(bare, res, flops)) \
+        is None
+    assert _read("flash_attention_bwd_roofline", {}) is None
+
+
+def test_the_roofline_readers_start_at_the_second_program():
+    """A trace that starts inside a step holds the rest of that step's
+    program and only its later kernel calls: counted as a step, it read
+    need x N over 2N - 1 calls (PERF.md section 6, PR 30)."""
+    res = loader.resolve_cell(LFM2)
+    flops = loader.load_module("flops", "lfm2-8b-a1b")
+    need = flops.flash_attention_fwd_per_example(res["config"], res["traffic"])
+    dur = int(2 * 2 * need["flops"] / 197e12 * 1e9)
+    kernel = "%flash_attention_fwd.3 = (bf16[64,8192,64]) custom-call()"
+    tr = _trace([(kernel, dur), (kernel, dur)], step_ns=20_000_000)
+    whole = _read("flash_attention_fwd_roofline", _ctx(tr, res, flops))
+    plane = tr["/device:TPU:0"]
+    # cut the first program: its first kernel call ran before the trace
+    name, t, d = plane["XLA Modules"][0]
+    plane["XLA Modules"][0] = (name, t + dur, d - dur)
+    plane["XLA Ops"] = plane["XLA Ops"][1:]
+    assert _read("flash_attention_fwd_roofline", _ctx(tr, res, flops)) \
+        == pytest.approx(whole) == pytest.approx(25.0, rel=1e-3)

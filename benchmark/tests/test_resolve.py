@@ -29,16 +29,30 @@ def test_every_cell_resolves_to_files_that_exist():
         assert res["limits"]["limits"]
 
 
-def test_the_four_chip_cell_is_one_new_entry_and_no_new_file():
-    bench = copy.deepcopy(loader.load_benchmark())
-    bench["workloads"].append({
-        "name": "resnet50.train-b128-dp4", "config": "resnet50",
-        "traffic": "imagenet-f32-b128", "chips": 4,
-        "why": "global batch 512 over the 2x2 host: the gradient mean crosses chips"})
+def test_the_four_chip_cell_resolves_to_files_of_its_own_name():
+    bench = loader.load_benchmark()
     res = loader.resolve_cell("resnet50.train-b128-dp4", bench)
     assert res["cell"]["chips"] == 4
     assert res["files"]["reference"] == ("references", "resnet50")
-    assert {m["name"] for m in res["per_layer"]} >= {"train_step_mfu"}
+    # a pair of configuration and traffic appears once: the mix has the
+    # one-chip cell's parameters under a name of its own
+    one = loader.resolve_cell("resnet50.train-b128", bench)
+    assert res["cell"]["traffic"] != one["cell"]["traffic"]
+    same = ("driver", "per_chip_batch", "pool_batches", "warmup_steps",
+            "inputs")
+    assert {k: res["traffic"][k] for k in same} == {
+        k: one["traffic"][k] for k in same}
+    assert os.path.isfile(loader.bench_path(
+        "limits", "resnet50.train-b128-dp4.json"))
+    # the same numbers decide, at limits set from this cell's own readings
+    assert set(res["limits"]["limits"]) == set(one["limits"]["limits"])
+    assert res["limits"] != one["limits"]
+    names = {m["name"] for m in res["per_layer"]}
+    assert "grad_allreduce_share" in names and "train_step_mfu" in names
+    assert "grad_allreduce_share" not in {m["name"] for m in one["per_layer"]}
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 
 def test_run_py_holds_no_model_or_cell_name():

@@ -15,21 +15,36 @@ def test_union_and_gaps_of_hand_made_intervals():
     assert trace.gaps(iv, 0, 100) == [(20, 30), (40, 100)]
 
 
+T0 = 201_000
+
+
 def _synthetic():
-    # three steps of 100 us every 150 us: two ops of 40 us in each
-    mods, ops = [], []
+    # the trace starts inside a step: what is left of that program, a false
+    # gap, then three steps of 100 us every 150 us, two ops of 40 us in each
+    mods = [("jit_step", 1000, 60_000)]
+    ops = [("fusion.2", 1000 + 10_000, 40_000)]
     for i in range(3):
-        t = 1000 + 150_000 * i
+        t = T0 + 150_000 * i
         mods.append(("jit_step", t, 100_000))
         ops += [("fusion.1", t, 40_000), ("fusion.2", t + 50_000, 40_000)]
-    host = [("bench_feed", 1000 + 100_000, 45_000),
-            ("bench_feed", 1000 + 250_000, 45_000)]
+    host = [("bench_feed", T0 + 100_000, 45_000),
+            ("bench_feed", T0 + 250_000, 45_000)]
     return {"/device:TPU:0": {"XLA Modules": mods, "XLA Ops": ops},
             "/host:CPU": {"python": host}}
 
 
 def _read(metric, ctx):
     return loader.load_module("layer_metrics", metric).read(ctx)
+
+
+def test_the_steady_window_runs_from_the_second_program_to_the_last():
+    plane = _synthetic()["/device:TPU:0"]
+    # the cut first program, its operations and the gap after it are outside
+    assert trace.steady_window(plane) == (T0, T0 + 300_000, 2)
+    # three programs hold one whole step at the most: nothing to read
+    plane["XLA Modules"] = plane["XLA Modules"][:3]
+    assert trace.steady_window(plane) is None
+    assert trace.device_summary({"/device:TPU:0": plane}) is None
 
 
 def test_device_idle_share_on_synthetic_steps():

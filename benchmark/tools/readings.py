@@ -15,6 +15,9 @@ For each seed, one JSON line per reading, all against the float32 reference:
     unchanged   the reference with every step returning its state unchanged
     half_batch  the reference with half of each batch left out and the mean
                 taken over the rest, put in the program's place
+    no_exchange (a cell on several chips) the reference stepped on the first
+                chip's rows alone: what that chip holds when no gradient and
+                no batch statistic crosses the chips
 
 All in one process, which holds the chip: the benchmark's own runs never run
 this. A state left unchanged reads 1 by the measure and needs no run.
@@ -77,7 +80,8 @@ def main(argv=None):
                       "where": r["window"]["worst_leaves"],
                       "spread": spread_of(compare, pr, rr),
                       "losses": r["window"]["program_losses"]})
-            if not {"control", "half_batch", "bf16", "unchanged"} & set(what):
+            if not {"control", "half_batch", "bf16", "unchanged",
+                    "no_exchange"} & set(what):
                 continue
             weights = jax.tree_util.tree_map(
                 np.asarray, make_weights(ref, cfg, seed))
@@ -87,6 +91,8 @@ def main(argv=None):
             for name, kw in (("control", {"precision": "fp8"}),
                              ("bf16", {"precision": "bf16"}),
                              ("half_batch", {"rows": slice(0, rows // 2)}),
+                             ("no_exchange",
+                              {"rows": slice(0, rows // chips)}),
                              ("unchanged", {"frozen": True})):
                 if name not in what:
                     continue
