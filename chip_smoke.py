@@ -50,6 +50,14 @@ MOSAIC_CALL = "tpu_custom_call"
 # error is O(1) on these inputs.
 KERNEL_ATOL = 4e-2
 
+# The selective scan against the plain recurrence, as a share of the
+# reference's largest entry. Both run the recurrence in float32 over the same
+# bf16 ``u, B, C``; the kernel rounds ``y`` (and ``du``) to bf16 once, half an
+# ulp of its own size, 2^-9, and the float32 sums differ in order. 2^-7 leaves
+# both room (observed on the v5e, PR 34: 1.6e-3 on y, 2e-4 on a gradient); a
+# wrong column, chunk edge or carried state is O(1).
+SCAN_TOL = 2.0 ** -7
+
 
 class CompileClock:
     """Seconds XLA spent compiling, from jax's own monitoring events."""
@@ -254,7 +262,8 @@ def _cache_ref(q, k_all, v_all, qpos, pads):
 
 def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
                           grad_seqs=(2048, 8192), grad_heads=64,
-                          grad_head_dim=64, dense_heads=2) -> dict:
+                          grad_head_dim=64, dense_heads=2,
+                          grad_window=512) -> dict:
     """Causal prefill at S=seq, and the gradient through the backward
     kernel pair at the training cell's head shape (``grad_heads`` heads of
     ``grad_head_dim``, bf16) at each of ``grad_seqs``. No default engine
@@ -267,7 +276,10 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
     not mix, so those heads of the full call are held to it exactly. The
     record gives each gradient's largest gap as a share of the reference's
     largest entry (``max_err``, held to the kernels' tolerance) and the
-    ratio of the 2-norms."""
+    ratio of the 2-norms. At the longest of ``grad_seqs`` the gradient is
+    taken once more under a window of ``grad_window`` keys
+    (``flash_attention_grad_S*_w*``), against dense attention under the same
+    band."""
     import jax
     import jax.numpy as jnp
 
@@ -286,16 +298,22 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
             lambda a, b, c, w: (attn(a, b, c).astype(jnp.float32) * w).sum(),
             argnums=(0, 1, 2)))(*qkvw)
 
-    for s in grad_seqs:
+    cases = [(s, None) for s in grad_seqs]
+    if grad_window:
+        cases.append((max(grad_seqs), grad_window))
+    for s, window in cases:
         q, k, v, w = (jnp.asarray(rng.randn(1, grad_heads, s, grad_head_dim),
                                   jnp.bfloat16) for _ in range(4))
         got = grads(lambda a, b, c: flash_attention(
-            a, b, c, True, interpret=interpret), q, k, v, w)
+            a, b, c, True, interpret=interpret, window=window), q, k, v, w)
         with jax.default_matmul_precision("highest"):
-            want = grads(lambda a, b, c: dense_attention(a, b, c, True),
-                         *(jnp.asarray(x[:, :dense_heads], jnp.float32)
-                           for x in (q, k, v, w)))
+            want = grads(lambda a, b, c: dense_attention(
+                a, b, c, True, None, window),
+                *(jnp.asarray(x[:, :dense_heads], jnp.float32)
+                  for x in (q, k, v, w)))
         rec = {"S": s, "heads": grad_heads, "max_err": 0.0}
+        if window:
+            rec["window"] = window
         for name, g, r in zip(("dq", "dk", "dv"), got, want):
             assert g.dtype == jnp.bfloat16 and np.isfinite(
                 np.asarray(g, np.float32)).all(), name
@@ -305,8 +323,83 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
                 np.linalg.norm(g) / np.linalg.norm(r))
             rec["max_err"] = max(rec["max_err"],
                                  float(np.abs(g - r).max() / np.abs(r).max()))
-        out[f"flash_attention_grad_S{s}"] = rec
+        out[f"flash_attention_grad_S{s}" + (f"_w{window}" if window
+                                            else "")] = rec
     return out
+
+
+def check_selective_scan(rng, *, interpret, seq=8192, channels=5120,
+                         states=16, dense_channels=256) -> dict:
+    """The selective-scan kernel pair at the Mamba cell's shape (one
+    sequence, ``u, B, C`` in bf16, ``dt`` and ``A`` float32, the recurrence
+    in float32): forward and every gradient at all ``channels``, against the
+    plain position-by-position recurrence in float32 on the first
+    ``dense_channels`` (channels do not mix; ``dB`` and ``dC`` sum over them,
+    so those two are taken from a second call over the first channels
+    alone). ``max_err`` is the largest gap, forward or gradient, as a share
+    of the reference's largest entry, held to ``SCAN_TOL``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.ops import selective_scan as scan_ops
+
+    def plain(u, dt, A, B, C, D):
+        u, B, C = (t.astype(jnp.float32) for t in (u, B, C))
+
+        def step(h, x):
+            u_t, dt_t, b_t, c_t = x
+            h = jnp.exp(dt_t[..., None] * A) * h \
+                + (dt_t * u_t)[..., None] * b_t[:, None, :]
+            return h, jnp.sum(h * c_t[:, None, :], -1)
+
+        xs = tuple(jnp.swapaxes(t, 0, 1) for t in (u, dt, B, C))
+        _, y = jax.lax.scan(
+            step, jnp.zeros((u.shape[0], u.shape[2], A.shape[1])), xs)
+        return jnp.swapaxes(y, 0, 1) + D * u
+
+    u, w = (jnp.asarray(rng.randn(1, seq, channels), jnp.bfloat16)
+            for _ in range(2))
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(1, seq, channels) - 4.0,
+                                     jnp.float32))
+    A = -jnp.exp(jnp.broadcast_to(
+        jnp.log(jnp.arange(1, states + 1, dtype=jnp.float32)),
+        (channels, states)))
+    B, C = (jnp.asarray(rng.randn(1, seq, states), jnp.bfloat16)
+            for _ in range(2))
+    D = jnp.ones((channels,), jnp.float32)
+    args, nc = (u, dt, A, B, C, D), dense_channels
+
+    def first(t):
+        """The first ``dense_channels`` channels of an operand."""
+        if t.ndim == 3 and t.shape[-1] == channels:
+            return t[..., :nc]
+        return t[:nc] if t.shape[0] == channels else t
+
+    def grads(fn, args, w):
+        return jax.jit(jax.grad(
+            lambda *a: (fn(*a).astype(jnp.float32)
+                        * w.astype(jnp.float32)).sum(),
+            argnums=tuple(range(6))))(*args)
+
+    def scan(*a):
+        return scan_ops.selective_scan(*a, interpret=interpret)[0]
+
+    few = tuple(first(t) for t in args)
+    y = jax.jit(scan)(*args)
+    assert y.dtype == jnp.bfloat16 and y.shape == u.shape
+    want = jax.jit(plain)(*few)
+    rec = {"S": seq, "channels": channels,
+           "max_err": _max_err(first(y), want) / float(jnp.abs(want).max())}
+    got, got_few = grads(scan, args, w), grads(scan, few, first(w))
+    ref = grads(plain, few, first(w))
+    for i, name in enumerate(("du", "ddt", "dA", "dB", "dC", "dD")):
+        g = got_few[i] if name in ("dB", "dC") else first(got[i])
+        r = np.asarray(ref[i], np.float32)
+        err = _max_err(g, r) / float(np.abs(r).max())
+        rec[f"{name}_err"] = err
+        rec["max_err"] = max(rec["max_err"], err)
+    assert rec["max_err"] <= SCAN_TOL, rec
+    return {"selective_scan": rec}
 
 
 def check_flash_decode(rng, *, interpret, slots, heads, kv_heads, head_dim,
@@ -369,11 +462,12 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                   heads: int = 16, kv_heads: int = 8, head_dim: int = 128,
                   max_len: int = 2048, block_size: int = 16,
                   verify_window: int = 5, kv_dtypes=(None, "int8"),
-                  atol: float = KERNEL_ATOL, **flash_grad) -> dict:
+                  atol: float = KERNEL_ATOL, scan=None, **flash_grad) -> dict:
     """Each Pallas kernel against the dense reference at the shapes the
     server phase serves (and, for the flash kernel's backward pair, the
     training cell's: ``flash_grad`` overrides ``check_flash_attention``'s
-    ``grad_*`` sizes). ``interpret`` is passed to every call explicitly:
+    ``grad_*`` sizes, ``scan`` ``check_selective_scan``'s). ``interpret`` is
+    passed to every call explicitly:
     False compiles through Mosaic, True is the CPU test's interpreter."""
     rng = np.random.RandomState(0)
     shape = dict(interpret=interpret, slots=slots, heads=heads,
@@ -382,6 +476,7 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
         **check_flash_attention(rng, interpret=interpret, seq=seq,
                                 heads=heads, head_dim=head_dim,
                                 **flash_grad),
+        **check_selective_scan(rng, interpret=interpret, **(scan or {})),
         **check_flash_decode(rng, **shape)}
     for kv in kv_dtypes:
         checks.update(check_paged_flash_decode(

@@ -3,6 +3,7 @@ from .bert import (BertConfig, BertEncoder, BertForSequenceClassification,
 from .lfm2 import Lfm2Config, Lfm2ForCausalLM
 from .llama import LlamaConfig, LlamaModel, lora_mask, lora_optimizer
 from .lm_loss import causal_lm_loss_fn
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
 from .pretrained import (CheckpointMismatch, cast_float_leaves,
                          import_hf_bert, import_hf_llama,
                          import_keras_inception, import_keras_resnet,
@@ -21,7 +22,8 @@ __all__ = [
     "BertConfig", "BertEncoder", "BertForSequenceClassification",
     "glue_loss_fn", "bert_finetune_loss",
     "LlamaConfig", "LlamaModel", "causal_lm_loss_fn", "lora_mask",
-    "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM",
+    "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM", "Phi4FlashConfig",
+    "Phi4FlashForCausalLM",
     "load_pretrained", "import_hf_llama", "import_hf_bert",
     "import_keras_resnet", "import_keras_vgg", "import_keras_inception",
     "import_keras_xception",

@@ -67,12 +67,16 @@ NEG_INF = -1e30
 _LANES = 128  # the backward keeps per-row stats broadcast across a lane tile
 
 
-def _tile_is_live(qi, ki, block_q: int, block_k: int):
+def _tile_is_live(qi, ki, block_q: int, block_k: int, window=None):
     """Under ``causal``: whether Q tile ``qi`` has any row at or past KV
     tile ``ki``'s first column — the one rule all three kernels'
     ``pl.when`` and index maps follow (ints in, bool out; traced in, traced
-    out)."""
-    return ki * block_k <= qi * block_q + block_q - 1
+    out). Under a ``window`` (row ``t`` sees keys ``t - window + 1 .. t``)
+    also whether the tile's last column is inside the first row's band."""
+    live = ki * block_k <= qi * block_q + block_q - 1
+    if window is not None:
+        live = live & (ki * block_k + block_k - 1 > qi * block_q - window)
+    return live
 
 
 def _first_live_q(ki, block_q: int, block_k: int):
@@ -87,19 +91,74 @@ def _last_live_kv(qi, block_q: int, block_k: int):
     return (qi * block_q + block_q - 1) // block_k
 
 
-def _kv_tile(qi, ki, block_q: int, block_k: int, causal: bool):
-    """The KV tile a (Q tile ``qi``, KV tile ``ki``) grid step fetches: a
-    dead pair does no work and fetches nothing — its index is clamped to
-    the nearest live tile's, whose block the pipeline already holds."""
+def _first_live_kv(qi, block_q: int, block_k: int, window: int):
+    """Under a ``window`` the leading KV tiles are dead for Q tile ``qi``
+    too: the tile of the first key its first row sees."""
+    first_key = qi * block_q - (window - 1)
+    return (first_key + abs(first_key)) // 2 // block_k    # max(., 0)
+
+
+def _last_live_q(ki, block_q: int, block_k: int, window: int):
+    """Under a ``window`` the trailing Q tiles are dead for KV tile ``ki``:
+    the tile of the last row that sees its last key (it may lie past the
+    sequence's last tile)."""
+    return (ki * block_k + block_k - 1 + window - 1) // block_q
+
+
+def _band_steps_kv(n_q: int, block_q: int, block_k: int, window: int) -> int:
+    """The most KV tiles any Q tile's band touches: under a window the
+    forward's and the dq kernel's innermost grid axis spans these and no more
+    (a dead grid step still costs its ~0.35 us). 2 at 512-blocks and a
+    window of 512, whatever the sequence. (The edges are written out here:
+    the grid's size is not the index maps' clamp.)"""
+    return max((qi * block_q + block_q - 1) // block_k
+               - max(qi * block_q - (window - 1), 0) // block_k + 1
+               for qi in range(n_q))
+
+
+def _band_steps_q(n_q: int, n_kv: int, block_q: int, block_k: int,
+                  window: int) -> int:
+    """``_band_steps_kv``'s twin for the dkv kernel's Q axis."""
+    return max(min((ki * block_k + block_k - 1 + window - 1) // block_q,
+                   n_q - 1) - (ki * block_k) // block_q + 1
+               for ki in range(n_kv))
+
+
+def _kv_index(qi, step, block_q: int, block_k: int, window):
+    """The KV tile grid step ``step`` of Q tile ``qi`` stands for: the step
+    itself, or under a window the step counted from the band's lower edge."""
+    if window is None:
+        return step
+    return _first_live_kv(qi, block_q, block_k, window) + step
+
+
+def _q_index(ki, step, block_q: int, block_k: int, window):
+    """``_kv_index``'s twin for the kernel that streams Q tiles past a KV
+    tile: under a window the steps count from the diagonal."""
+    if window is None:
+        return step
+    return _first_live_q(ki, block_q, block_k) + step
+
+
+def _kv_tile(qi, step, block_q: int, block_k: int, causal: bool,
+             window=None):
+    """The KV tile a (Q tile ``qi``, grid step ``step``) fetches: a dead
+    pair does no work and fetches nothing — its index is clamped to the
+    nearest live tile's, whose block the pipeline already holds."""
+    ki = _kv_index(qi, step, block_q, block_k, window)
     return (jnp.minimum(ki, _last_live_kv(qi, block_q, block_k))
             if causal else ki)
 
 
-def _q_tile(qi, ki, block_q: int, block_k: int, causal: bool):
+def _q_tile(step, ki, block_q: int, block_k: int, causal: bool,
+            window=None, n_q=None):
     """``_kv_tile``'s twin for the kernel that streams Q tiles past a KV
     tile."""
-    return (jnp.maximum(qi, _first_live_q(ki, block_q, block_k))
-            if causal else qi)
+    if window is None:
+        return (jnp.maximum(step, _first_live_q(ki, block_q, block_k))
+                if causal else step)
+    last = jnp.minimum(_last_live_q(ki, block_q, block_k, window), n_q - 1)
+    return jnp.minimum(_q_index(ki, step, block_q, block_k, window), last)
 
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract both operands' last dim
@@ -117,24 +176,54 @@ def _as_column(row):
     return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
-def _when_live(update, qi, ki, block_q: int, block_k: int, causal: bool):
-    """Run ``update(on_diagonal)`` for a live tile pair and nothing for a
-    dead one. Only a pair that straddles the diagonal (some column past
-    some row) pays for the causal compare; a pair wholly below it is as
-    unmasked as a non-causal one."""
+def _when_live(update, qi, ki, block_q: int, block_k: int, causal: bool,
+               window=None, n_q=None):
+    """Run ``update(on_diagonal, on_edge)`` for a live tile pair and nothing
+    for a dead one. Only a pair that straddles the diagonal (some column past
+    some row) pays for the causal compare, and only one that straddles the
+    band's lower edge (some column at or before some row's ``t - window``)
+    for the window's; a pair wholly between them is as unmasked as a
+    non-causal one. ``n_q``: the Q tiles there are, where the grid's Q steps
+    may count past them (the dkv kernel under a window)."""
     if not causal:
         update(False)
         return
-    live = _tile_is_live(qi, ki, block_q, block_k)
+    live = _tile_is_live(qi, ki, block_q, block_k, window)
     straddles = ki * block_k + block_k - 1 > qi * block_q
-    pl.when(live & straddles)(functools.partial(update, True))
-    # no column past any row: wholly below the diagonal, so live
-    pl.when(jnp.logical_not(straddles))(functools.partial(update, False))
+    if window is None:
+        pl.when(live & straddles)(functools.partial(update, True))
+        # no column past any row: wholly below the diagonal, so live
+        pl.when(jnp.logical_not(straddles))(functools.partial(update, False))
+        return
+    if n_q is not None:
+        live = live & (qi < n_q)
+    on_edge = qi * block_q + block_q - 1 - ki * block_k >= window
+    for diag in (False, True):
+        for edge in (False, True):
+            pl.when(live & (straddles == diag) & (on_edge == edge))(
+                functools.partial(update, diag, edge))
+
+
+def _band_mask(keep, qi, ki, block_q: int, block_k: int, shape, q_axis: int,
+               on_diagonal: bool, on_edge: bool, window):
+    """``keep`` and the causal compare (``on_diagonal``) and the window's
+    (``on_edge``) over a score tile of ``shape`` whose queries run along
+    ``q_axis``."""
+    if not (on_diagonal or on_edge):
+        return keep
+    cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                   1 - q_axis)
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    if on_diagonal:
+        keep = keep & (cols <= rows)
+    if on_edge:
+        keep = keep & (cols > rows - window)
+    return keep
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, causal: bool, sm_scale: float,
-                seq_len: int):
+                seq_len: int, window=None):
     """Grid = (B·H, Q tiles, KV tiles); KV tiles stream through VMEM via the
     innermost grid dimension (pallas pipelines the HBM loads), while the
     accumulator and the per-query (m, l) stats persist in VMEM scratch
@@ -152,15 +241,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
     the max, the exponent, ``alpha``, ``l``, the accumulator, ``lse`` and
     the final division are float32."""
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
+    ki = _kv_index(qi, step, block_q, block_k, window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _update(on_diagonal: bool):
+    def _update(on_diagonal: bool, on_edge: bool = False):
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
@@ -168,11 +258,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
             jnp.int32, (1, block_k), 1)
         valid = (cols < seq_len) & (mask_ref[0, 0] > 0)     # (1, BK)
         keep = _as_column(valid.astype(jnp.float32))[:, :1] > 0   # (BK, 1)
-        if on_diagonal:
-            shape = (block_k, block_q)
-            keep = keep & (
-                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        keep = _band_mask(keep, qi, ki, block_q, block_k,
+                          (block_k, block_q), 1, on_diagonal, on_edge, window)
         st = jnp.where(keep, st, NEG_INF)
         m_prev = m_ref[:]                                   # (1, BQ)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
@@ -187,9 +274,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
             v, pt.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    _when_live(_update, qi, ki, block_q, block_k, causal)
+    _when_live(_update, qi, ki, block_q, block_k, causal, window)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l = l_ref[:]
         safe_l = jnp.where(l > 0, l, 1.0)  # fully-masked rows (padding)
@@ -241,41 +328,54 @@ def _per_head(kv_mask, h: int):
     return jnp.broadcast_to(kv_mask[:, None, :], (b, h, s)).reshape(b * h, s)
 
 
+def _rows3(t, s_pad: int):
+    """[B, H, S, D] -> [B·H, S padded, D]."""
+    b, h, s, d = t.shape
+    return _pad_rows(t.reshape(b * h, s, d), s_pad)
+
+
 def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
-         interpret: bool):
+         interpret: bool, window=None):
     b, h, s, d = q.shape
+    dv = v.shape[-1]             # the values may be wider than the keys
     bq, bk, s_pad = _tiles(s, block_q, block_k)
     sm_scale = 1.0 / math.sqrt(d)
-    q3, k3, v3 = (_pad_rows(t.reshape(b * h, s, d), s_pad) for t in (q, k, v))
+    q3, k3, v3 = (_rows3(t, s_pad) for t in (q, k, v))
     m4 = _blocked_rows(_per_head(kv_mask, h), s_pad, bk)
     from jax.experimental.pallas import tpu as pltpu
 
-    grid = (b * h, s_pad // bq, s_pad // bk)
+    n_kv = s_pad // bk
+    if window is not None:       # the KV axis spans the band and no more
+        n_kv = _band_steps_kv(s_pad // bq, bq, bk, window)
+    grid = (b * h, s_pad // bq, n_kv)
 
     def kv_tile(bh, i, j):
-        return bh, _kv_tile(i, j, bq, bk, causal)
+        return bh, _kv_tile(i, j, bq, bk, causal, window)
 
-    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, i, j: (*kv_tile(bh, i, j), 0))
+    def rows_kv(width):
+        return pl.BlockSpec((1, bk, width),
+                            lambda bh, i, j: (*kv_tile(bh, i, j), 0))
+
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal,
-                          sm_scale=sm_scale, seq_len=s),
+        functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
+                          seq_len=s, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            rows_kv, rows_kv,
+            rows_kv(d), rows_kv(dv),
             pl.BlockSpec((1, 1, 1, bk),
                          lambda bh, i, j: (*kv_tile(bh, i, j), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda bh, i, j: (bh, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, s_pad // bq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),    # acc, transposed
+            pltpu.VMEM((dv, bq), jnp.float32),   # acc, transposed
             pltpu.VMEM((1, bq), jnp.float32),    # running max m
             pltpu.VMEM((1, bq), jnp.float32),    # normalizer l
         ],
@@ -289,13 +389,14 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
     )
     with jax.named_scope("flash_attention_fwd"):
         o3, lse2 = call(q3, k3, v3, m4)
-    return (o3[:, :s].reshape(b, h, s, d),
+    return (o3[:, :s].reshape(b, h, s, dv),
             lse2.reshape(b * h, s_pad)[:, :s].reshape(b, h, s))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     mask_ref, dk_ref, dv_ref, dk_acc, dv_acc, valid_ref, *,
-                    causal: bool, sm_scale: float, seq_len: int):
+                    causal: bool, sm_scale: float, seq_len: int,
+                    window=None, n_q=None):
     """Grid = (B·H, KV tiles, Q tiles), Q innermost: one K/V tile stays in
     VMEM while the Q/dO tiles stream past it and float32 ``dk``/``dv``
     accumulate in scratch. The score tile is held TRANSPOSED, (BK, BQ):
@@ -303,9 +404,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     their (1, BQ) blocks, and all four products are plain ``a @ b`` /
     ``a @ b.T`` — no score-sized transpose."""
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    ki, step = pl.program_id(1), pl.program_id(2)
+    qi = _q_index(ki, step, block_q, block_k, window)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -314,16 +416,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         valid = (cols < seq_len) & (mask_ref[0, 0] > 0)
         valid_ref[:] = _as_column(valid.astype(jnp.float32))
 
-    def _update(on_diagonal: bool):
+    def _update(on_diagonal: bool, on_edge: bool = False):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
         keep = valid_ref[:, :1] > 0                         # (BK, 1)
-        if on_diagonal:
-            shape = (block_k, block_q)
-            keep = keep & (
-                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        keep = _band_mask(keep, qi, ki, block_q, block_k,
+                          (block_k, block_q), 1, on_diagonal, on_edge, window)
         # exp first, select after: a masked entry may overflow (a fully
         # masked row's lse is NEG_INF) and the select drops it
         pt = jnp.where(keep, jnp.exp(st - lse_ref[0, 0]), 0.0)   # (BK, BQ)
@@ -335,9 +434,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] += jnp.dot(dst.astype(q.dtype), q,
                              preferred_element_type=jnp.float32)
 
-    _when_live(_update, qi, ki, block_q, block_k, causal)
+    _when_live(_update, qi, ki, block_q, block_k, causal, window, n_q)
 
-    @pl.when(qi == pl.num_programs(2) - 1)   # the last Q tile is always live
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -345,33 +444,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
                    dq_ref, dq_acc, lse_col, delta_col, *, causal: bool,
-                   sm_scale: float, seq_len: int):
+                   sm_scale: float, seq_len: int, window=None):
     """Grid = (B·H, Q tiles, KV tiles), KV innermost, as the forward: one
     Q/dO tile stays while K/V tiles stream and float32 ``dq`` accumulates.
     The score tile is (BQ, BK) here, so ``kv_mask`` broadcasts from its
     (1, BK) block and ``lse``/``delta`` are turned into columns once per
     Q tile."""
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
+    ki = _kv_index(qi, step, block_q, block_k, window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
         lse_col[:] = _as_column(lse_ref[0, 0])
         delta_col[:] = _as_column(delta_ref[0, 0])
 
-    def _update(on_diagonal: bool):
+    def _update(on_diagonal: bool, on_edge: bool = False):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = jax.lax.dot_general(
             q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
         cols = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         keep = (cols < seq_len) & (mask_ref[0, 0] > 0)      # (1, BK)
-        if on_diagonal:
-            shape = (block_q, block_k)
-            keep = keep & (
-                ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                <= qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        keep = _band_mask(keep, qi, ki, block_q, block_k,
+                          (block_q, block_k), 0, on_diagonal, on_edge, window)
         p = jnp.where(keep, jnp.exp(s - lse_col[:, :1]), 0.0)    # (BQ, BK)
         dp = jax.lax.dot_general(
             do, v, _NT, preferred_element_type=jnp.float32)
@@ -379,69 +476,86 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
                              preferred_element_type=jnp.float32)
 
-    _when_live(_update, qi, ki, block_q, block_k, causal)
+    _when_live(_update, qi, ki, block_q, block_k, causal, window)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd(q, k, v, kv_mask, o, lse, do, causal: bool, block_q: int,
-         block_k: int, interpret: bool):
+         block_k: int, interpret: bool, window=None):
     """dq, dk, dv by the two kernels above, from the forward's residuals.
     Tiles are recomputed from ``lse``; nothing score-sized touches HBM."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
+    dv = v.shape[-1]
     bq, bk, s_pad = _tiles(s, block_q, block_k)
     sm_scale = 1.0 / math.sqrt(d)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    q3, k3, v3, do3 = (_pad_rows(t.reshape(b * h, s, d), s_pad)
-                       for t in (q, k, v, do))
+    q3, k3, v3, do3 = (_rows3(t, s_pad) for t in (q, k, v, do))
     # padded query rows: lse 0 keeps p finite, do = delta = 0 keep it unused
     lse4 = _blocked_rows(lse.reshape(b * h, s), s_pad, bq)
     delta4 = _blocked_rows(delta.reshape(b * h, s), s_pad, bq)
     m4 = _blocked_rows(_per_head(kv_mask, h), s_pad, bk)
     n_q, n_kv = s_pad // bq, s_pad // bk
-    kernel_args = dict(causal=causal, sm_scale=sm_scale, seq_len=s)
+    steps_q, steps_kv = n_q, n_kv
+    if window is not None:       # each inner axis spans the band and no more
+        steps_q = _band_steps_q(n_q, n_kv, bq, bk, window)
+        steps_kv = _band_steps_kv(n_q, bq, bk, window)
+    kernel_args = dict(causal=causal, sm_scale=sm_scale, seq_len=s,
+                       window=window)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     def q_tile(bh, j, i):
-        return bh, _q_tile(i, j, bq, bk, causal)
+        return bh, _q_tile(i, j, bq, bk, causal, window, n_q)
 
     def kv_tile(bh, i, j):
-        return bh, _kv_tile(i, j, bq, bk, causal)
+        return bh, _kv_tile(i, j, bq, bk, causal, window)
 
-    rows_q = pl.BlockSpec((1, bq, d), lambda bh, j, i: (*q_tile(bh, j, i), 0))
+    def rows_q(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda bh, j, i: (*q_tile(bh, j, i), 0))
+
+    def rows_kv(width):
+        return pl.BlockSpec((1, bk, width), lambda bh, j, i: (bh, j, 0))
+
     stat_q = pl.BlockSpec((1, 1, 1, bq),
                           lambda bh, j, i: (*q_tile(bh, j, i), 0, 0))
-    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
     dk3, dv3 = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kernel_args),
-        grid=(b * h, n_kv, n_q),
-        in_specs=[rows_q, rows_kv, rows_kv, rows_q, stat_q, stat_q,
+        functools.partial(_bwd_dkv_kernel, n_q=n_q, **kernel_args),
+        grid=(b * h, n_kv, steps_q),
+        in_specs=[rows_q(d), rows_kv(d), rows_kv(dv), rows_q(dv), stat_q,
+                  stat_q,
                   pl.BlockSpec((1, 1, 1, bk), lambda bh, j, i: (bh, j, 0, 0))],
-        out_specs=[rows_kv, rows_kv],
+        out_specs=[rows_kv(d), rows_kv(dv)],
         out_shape=[jax.ShapeDtypeStruct((b * h, s_pad, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, s_pad, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h, s_pad, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),        # dk
-                        pltpu.VMEM((bk, d), jnp.float32),        # dv
+                        pltpu.VMEM((bk, dv), jnp.float32),       # dv
                         pltpu.VMEM((bk, _LANES), jnp.float32)],  # key validity
         compiler_params=semantics, interpret=interpret,
         name="flash_attention_bwd_dkv",
     )(q3, k3, v3, do3, lse4, delta4, m4)
 
-    rows_q = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+    def rows_q(width):
+        return pl.BlockSpec((1, bq, width), lambda bh, i, j: (bh, i, 0))
+
+    def rows_kv(width):
+        return pl.BlockSpec((1, bk, width),
+                            lambda bh, i, j: (*kv_tile(bh, i, j), 0))
+
     stat_q = pl.BlockSpec((1, 1, 1, bq), lambda bh, i, j: (bh, i, 0, 0))
-    rows_kv = pl.BlockSpec((1, bk, d), lambda bh, i, j: (*kv_tile(bh, i, j), 0))
     dq3 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_args),
-        grid=(b * h, n_q, n_kv),
-        in_specs=[rows_q, rows_kv, rows_kv, rows_q, stat_q, stat_q,
+        grid=(b * h, n_q, steps_kv),
+        in_specs=[rows_q(d), rows_kv(d), rows_kv(dv), rows_q(dv), stat_q,
+                  stat_q,
                   pl.BlockSpec((1, 1, 1, bk),
                                lambda bh, i, j: (*kv_tile(bh, i, j), 0, 0))],
-        out_specs=rows_q,
+        out_specs=rows_q(d),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),        # dq
                         pltpu.VMEM((bq, _LANES), jnp.float32),   # lse, columns
@@ -449,26 +563,29 @@ def _bwd(q, k, v, kv_mask, o, lse, do, causal: bool, block_q: int,
         compiler_params=semantics, interpret=interpret,
         name="flash_attention_bwd_dq",
     )(q3, k3, v3, do3, lse4, delta4, m4)
-    return tuple(t[:, :s].reshape(b, h, s, d) for t in (dq3, dk3, dv3))
+    return tuple(t[:, :s].reshape(b, h, s, -1) for t in (dq3, dk3, dv3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_core(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
-                interpret: bool):
-    o, _ = _fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret)
+                interpret: bool, window=None):
+    o, _ = _fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+                window)
     return o
 
 
-def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+               window):
+    o, lse = _fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
+                  window)
     return o, (q, k, v, kv_mask, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, do):
     q, k, v, kv_mask, o, lse = res
     with jax.named_scope("flash_attention_bwd"):
         dq, dk, dv = _bwd(q, k, v, kv_mask, o, lse, do, causal, block_q,
-                          block_k, interpret)
+                          block_k, interpret, window)
     return dq, dk, dv, jnp.zeros_like(kv_mask)
 
 
@@ -477,8 +594,16 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal: bool = False, *, kv_mask=None,
                     block_q: int | None = None, block_k: int | None = None,
-                    interpret: bool | None = None):
-    """Pallas flash attention. q/k/v: ``[B, H, S, D]`` → ``[B, H, S, D]``.
+                    interpret: bool | None = None,
+                    window: int | None = None):
+    """Pallas flash attention. q/k: ``[B, H, S, D]``, v: ``[B, H, S, Dv]``
+    (``Dv`` may differ from ``D``: differential attention's values are twice
+    as wide as its keys) → ``[B, H, S, Dv]``.
+
+    ``window`` (with ``causal``): position ``t`` attends keys
+    ``t - window + 1 .. t``. A tile pair outside that band is neither
+    computed nor fetched, and the kernels' innermost grid axis spans the
+    band's tiles only.
 
     ``kv_mask``: optional ``[B, S]`` 0/1 array — key positions with 0 are
     excluded from every query's softmax (the BERT attention-mask contract).
@@ -496,6 +621,8 @@ def flash_attention(q, k, v, causal: bool = False, *, kv_mask=None,
     ragged length outweighs its per-work advantage.
     """
     import os
+    if window is not None and not (causal and window >= 1):
+        raise ValueError("a window needs causal=True and at least one key")
     s_len = q.shape[2]
     if block_q is None:
         env_q = os.environ.get("SPARKDL_FLASH_BLOCK_Q")
@@ -509,7 +636,7 @@ def flash_attention(q, k, v, causal: bool = False, *, kv_mask=None,
     else:
         kv_mask = kv_mask.astype(jnp.float32)
     return _flash_core(q, k, v, kv_mask, causal, block_q, block_k,
-                       _resolve(interpret))
+                       _resolve(interpret), window)
 
 
 # Relative per-unit-work kernel speed by block size, measured on TPU v5
@@ -542,13 +669,14 @@ def _resolve(interpret: bool | None) -> bool:
     return interpret
 
 
-def dense_attention_masked(q, k, v, causal: bool = False, kv_mask=None):
+def dense_attention_masked(q, k, v, causal: bool = False, kv_mask=None,
+                           window: int | None = None):
     """The short-sequence arm of :func:`adaptive_attention`: delegates to
     ``parallel.ring_attention.dense_attention`` (ONE source of truth for
     the reference numerics, including the flash kernel's fully-masked-
     row-outputs-zeros contract)."""
     from ..parallel.ring_attention import dense_attention
-    return dense_attention(q, k, v, causal, kv_mask)
+    return dense_attention(q, k, v, causal, kv_mask, window)
 
 
 def _flash_min_seq() -> int:
@@ -557,7 +685,8 @@ def _flash_min_seq() -> int:
 
 
 def adaptive_attention(q, k, v, causal: bool = False, *, kv_mask=None,
-                       interpret: bool | None = None):
+                       interpret: bool | None = None,
+                       window: int | None = None):
     """Length-adaptive attention: the Pallas flash kernel at and above
     ``SPARKDL_FLASH_MIN_SEQ`` (default 2048), XLA dense attention below.
 
@@ -571,8 +700,8 @@ def adaptive_attention(q, k, v, causal: bool = False, *, kv_mask=None,
     sequence length traces exactly one arm."""
     if q.shape[2] >= _flash_min_seq():
         return flash_attention(q, k, v, causal, kv_mask=kv_mask,
-                               interpret=interpret)
-    return dense_attention_masked(q, k, v, causal, kv_mask)
+                               interpret=interpret, window=window)
+    return dense_attention_masked(q, k, v, causal, kv_mask, window)
 
 
 def auto_attn_fn():

@@ -33,8 +33,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 NEG_INF = -1e30  # large-but-finite: -inf breaks the streaming-softmax max
 
 
-def dense_attention(q, k, v, causal: bool = False, kv_mask=None):
-    """Reference single-device attention. [B, H, S, D] layout.
+def dense_attention(q, k, v, causal: bool = False, kv_mask=None,
+                    window: int | None = None):
+    """Reference single-device attention. [B, H, S, D] layout (``v`` may
+    have a width of its own). ``window`` (with ``causal``): position ``t``
+    attends keys ``t - window + 1 .. t``.
 
     ``kv_mask`` ([B, S] 0/1) follows the flash kernel's contract exactly,
     including the edge the streaming kernel gets for free: a row whose
@@ -45,13 +48,19 @@ def dense_attention(q, k, v, causal: bool = False, kv_mask=None):
     if causal:
         S = q.shape[2]
         mask = jnp.tril(jnp.ones((S, S), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((S, S), bool), -window)
         s = jnp.where(mask, s, NEG_INF)
     if kv_mask is not None:
         valid = kv_mask.astype(bool)
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
-    if kv_mask is not None:
+    if kv_mask is not None and window is not None:
+        # a row whose whole band is masked: zeros, as the kernel gives
+        seen = (mask & valid[:, None, :]).any(-1)
+        o = o * seen.astype(o.dtype)[:, None, :, None]
+    elif kv_mask is not None:
         o = o * valid.any(-1).astype(o.dtype)[:, None, None, None]
     return o
 

@@ -49,11 +49,40 @@ def test_kernel_phase_tiny_interpreted():
     rec = chip_smoke.phase_kernels(
         interpret=True, seq=256, slots=3, heads=4, kv_heads=2,
         head_dim=32, max_len=128, block_size=8, verify_window=3,
-        grad_seqs=(256,), grad_heads=3, grad_head_dim=32)
+        grad_seqs=(256,), grad_heads=3, grad_head_dim=32, grad_window=40,
+        scan=dict(seq=40, channels=200, states=4, dense_channels=128))
     assert rec["interpret"] is True
     assert abs(rec["flash_attention_grad_S256"]["dk_norm_ratio"] - 1) < 1e-2
     assert {"flash_attention", "flash_decode", "paged_flash_decode_bf16_S1",
-            "paged_flash_decode_int8_S3"} <= set(rec)
+            "paged_flash_decode_int8_S3", "selective_scan",
+            "flash_attention_grad_S256_w40"} <= set(rec)
+    assert rec["flash_attention_grad_S256_w40"]["window"] == 40
+    assert abs(rec["flash_attention_grad_S256_w40"]["dq_norm_ratio"] - 1) \
+        < 1e-2
+    assert rec["selective_scan"]["max_err"] <= chip_smoke.SCAN_TOL
+    assert {"du_err", "ddt_err", "dA_err", "dB_err", "dC_err",
+            "dD_err"} <= set(rec["selective_scan"])
+
+
+def test_the_scan_check_sees_a_scan_that_restarts_its_state(monkeypatch):
+    """A kernel that restarted its state halfway is O(1) off, and the check
+    says so."""
+    import jax.numpy as jnp
+    import numpy as np
+    from sparkdl_tpu.ops import selective_scan as ss
+    real = ss.selective_scan
+
+    def restarted(u, dt, A, B, C, D, **kw):
+        half = u.shape[1] // 2
+        parts = [real(u[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], D, **kw)
+                 for sl in (slice(0, half), slice(half, None))]
+        return jnp.concatenate([y for y, _ in parts], axis=1), parts[1][1]
+
+    monkeypatch.setattr(ss, "selective_scan", restarted)
+    with pytest.raises(AssertionError):
+        chip_smoke.check_selective_scan(
+            np.random.RandomState(0), interpret=True, seq=32, channels=128,
+            states=4, dense_channels=128)
 
 
 def test_server_phase_tiny():

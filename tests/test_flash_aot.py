@@ -107,3 +107,139 @@ def test_forward_alone_at_llamas_head(one_chip, dtype):
             x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "flash_attention_fwd" in text
+
+
+# -- the window, the wider values, and the selective scan (PR 34) -----------------
+
+# sha256 of each kernel's Mosaic module at the LFM2 cell's shape, printed
+# without debug locations, as the tree before the window lowered it (PR 33's
+# commit, this container's jax). A kernel edit that is meant to change the
+# causal program re-pins them: ``_kernel_modules`` of the new lowering.
+LFM2_KERNELS = {
+    "flash_attention_fwd":
+        "c6bf1a3344388bda6753be54a7bd107af6271c8bd4d6a5348cea9ef619779b17",
+    "flash_attention_bwd_dkv":
+        "657b7239b09e6258e1ec0dfbcd09e3e8b9deac2b779589e58c2c48a634ed0b9e",
+    "flash_attention_bwd_dq":
+        "782c5368fe393bc015310c59eb2c6068af4fe52bd4446cb2f2568c3574865305",
+}
+
+
+def _kernel_modules(lowered_text: str) -> dict:
+    """``{kernel name: its Mosaic module as text, debug locations left
+    out}`` of every ``tpu_custom_call`` of a lowered program."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jaxlib.mlir import ir
+    out = {}
+    for line in lowered_text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        cfg = re.search(r'backend_config = "(.*?)"(?=[,}])', line).group(1)
+        body = json.loads(cfg.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            text = module.operation.get_asm(enable_debug_info=False)
+        out[re.search(r"module @(\w+)", text).group(1)] = text
+    return out
+
+
+def test_without_a_window_the_kernels_lower_to_the_programs_they_were(
+        one_chip):
+    """2 x 32 heads of 64 at S = 8192, bf16, causal, no window — the LFM2
+    cell's call: each of the three kernels' Mosaic modules is, operation for
+    operation, the one the tree before the window built."""
+    import hashlib
+    x = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, interpret=False).astype(
+            jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).as_text()
+    modules = _kernel_modules(text)
+    assert set(modules) == set(LFM2_KERNELS)
+    for name, module in modules.items():
+        assert hashlib.sha256(module.encode()).hexdigest() == \
+            LFM2_KERNELS[name], name
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window-512", "full"])
+def test_differential_attentions_gradient_at_the_cells_shape(one_chip,
+                                                             window):
+    """40 heads of 64 with values 128 wide at S = 8192, bf16 — the new cell's
+    attention layers, windowed (layer 15) and not (17, 19): forward and the
+    backward pair compile, and under the window the innermost grid axis of
+    all three spans the band's 2 tiles, not 16."""
+    b, h, s = 1, 40, 8192
+    qk = jax.ShapeDtypeStruct((b, h, s, 64), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, s, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, window=window,
+                                interpret=False).astype(jnp.float32)
+                ** 2).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v)
+    grids = [re.search(r"iteration_bounds = array<i64: ([0-9, ]+)>",
+                       module).group(1)
+             for module in _kernel_modules(lowered.as_text()).values()]
+    assert grids == ["40, 16, %d" % (2 if window else 16)] * 3, grids
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < b * h * s * 512, largest
+
+
+def test_the_cells_scan_gradient_keeps_no_state_per_position(one_chip):
+    """One sequence of 8192 positions, 5120 channels, 16 states, ``u, B, C``
+    in bf16 and ``dt`` in float32 — the new cell's Mamba layer: the forward
+    and the backward kernel compile at the default chunk and channel block,
+    by name, and no buffer of the compiled gradient holds
+    ``S * 5120 * 16`` elements or more (the per-position state, 2.7 GB in
+    float32, stays in VMEM)."""
+    from sparkdl_tpu.ops.selective_scan import selective_scan
+    s, c, n = 8192, 5120, 16
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((1, s, c), jnp.bfloat16), sd((1, s, c), jnp.float32),
+            sd((c, n), jnp.float32), sd((1, s, n), jnp.bfloat16),
+            sd((1, s, n), jnp.bfloat16), sd((c,), jnp.float32))
+
+    def loss(*a):
+        return (selective_scan(*a, interpret=False)[0].astype(jnp.float32)
+                ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < s * c * n, largest
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_scan_compiles_at_a_ragged_length_in_float32(one_chip):
+    """The tests' dtype and a length that is no multiple of the chunk."""
+    from sparkdl_tpu.ops.selective_scan import selective_scan
+    x = jax.ShapeDtypeStruct((2, 300, 256), jnp.float32, sharding=one_chip)
+    cols = jax.ShapeDtypeStruct((2, 300, 16), jnp.float32, sharding=one_chip)
+    a = jax.ShapeDtypeStruct((256, 16), jnp.float32, sharding=one_chip)
+    d = jax.ShapeDtypeStruct((256,), jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.grad(lambda *t: selective_scan(
+        *t, interpret=False)[0].sum(), argnums=tuple(range(6)))).lower(
+            x, x, a, cols, cols, d).compile().as_text()
+    assert text.count("tpu_custom_call") == 2

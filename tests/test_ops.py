@@ -563,3 +563,199 @@ def test_compiled_flash_on_tpu():
         assert grad["max_err"] <= chip_smoke.KERNEL_ATOL, grad
         for name in ("dq", "dk", "dv"):
             assert abs(grad[f"{name}_norm_ratio"] - 1) < 5e-3, grad
+
+
+# -- a window (position t attends keys t - window + 1 .. t) ---------------------
+
+@pytest.mark.parametrize("dv", [16, 32], ids=["v-as-wide", "v-twice-as-wide"])
+@pytest.mark.parametrize("s,window,blocks", [
+    (128, 32, (32, 32)),      # block multiples, window = block
+    (128, 40, (32, 64)),      # unequal blocks, the band's edge inside a tile
+    (128, 40, (64, 32)),
+    (100, 24, (32, 32)),      # ragged S
+    (130, 33, (64, 32)),      # ragged S, unequal blocks
+    (96, 1, (32, 32)),        # the position itself and nothing else
+    (96, 500, (32, 32)),      # wider than the sequence: plain causal
+    (70, 16, (None, None)),   # the default 128-aligned blocks
+], ids=lambda v: str(v).replace(" ", ""))
+def test_window_matches_dense_masked_attention(s, window, blocks, dv):
+    """Forward and all three gradients under a window, against dense
+    attention under the same mask; values as wide as the keys and twice as
+    wide (differential attention's)."""
+    q, k, _ = _rand_qkv(s=s, d=16, seed=31)
+    rng = np.random.RandomState(32)
+    v = jnp.asarray(rng.randn(2, 3, s, dv).astype(np.float32) * 0.3)
+    w = jnp.asarray(rng.randn(2, 3, s, dv).astype(np.float32))
+    bq, bk = blocks
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, window=window, block_q=bq,
+                               block_k=bk)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, True, None, window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)), atol=FWD_ATOL)
+    got = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: (dense(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=BWD_ATOL, err_msg=name)
+
+
+def test_window_with_a_kv_mask_and_fully_masked_rows():
+    q, k, v = _rand_qkv(s=96, d=16, seed=33)
+    mask = _len_mask(96, [96, 50])
+    got = flash_attention(q, k, v, True, kv_mask=mask, window=20,
+                          block_q=32, block_k=32)
+    want = dense_attention(q, k, v, True, mask, 20)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=FWD_ATOL)
+    # row 80 of the second sequence sees keys 61..80, all padding: zeros
+    assert not np.asarray(got[1, :, 80]).any()
+
+
+def test_a_window_needs_causal():
+    q, k, v = _rand_qkv(s=64, d=16)
+    with pytest.raises(ValueError, match="window needs causal"):
+        flash_attention(q, k, v, False, window=16)
+    with pytest.raises(ValueError, match="window needs causal"):
+        flash_attention(q, k, v, True, window=0)
+
+
+def test_window_live_tile_rule_counted_by_hand():
+    """At the new cell's shape (S = 8192, 512-blocks, a window of 512) a Q
+    tile's band is its own KV tile and the one before: 16 + 15 = 31 live
+    pairs of 256 (136 under the causal mask alone), and the innermost grid
+    axis spans 2 steps, not 16. The diagonal pair pays the causal compare
+    only, the one before it the window's only."""
+    n, b, w = 8192 // 512, 512, 512
+    live = [(i, j) for i in range(n) for j in range(n)
+            if _fa._tile_is_live(i, j, b, b, w)]
+    assert len(live) == 31
+    assert live == sorted([(i, i) for i in range(n)]
+                          + [(i, i - 1) for i in range(1, n)])
+    assert _fa._band_steps_kv(n, b, b, w) == 2
+    assert _fa._band_steps_q(n, n, b, b, w) == 2
+    for i in range(n):
+        first, last = (_fa._first_live_kv(i, b, b, w),
+                       _fa._last_live_kv(i, b, b))
+        assert (first, last) == (max(i - 1, 0), i)
+        assert int(_fa._kv_tile(i, 0, b, b, True, w)) == first
+        assert int(_fa._kv_tile(i, 1, b, b, True, w)) == last  # clamped at 0
+    for j in range(n):
+        assert _fa._first_live_q(j, b, b) == j
+        assert _fa._last_live_q(j, b, b, w) == j + 1   # past the end at n-1
+        assert int(_fa._q_tile(1, j, b, b, True, w, n)) == min(j + 1, n - 1)
+    # a window of 513 still starts in the tile before (key 512 i - 512);
+    # one key more and the band touches a third tile
+    assert _fa._band_steps_kv(n, b, b, w + 1) == 2
+    assert _fa._band_steps_kv(n, b, b, w + 2) == 3
+    # unequal blocks, by the definition: some (row, column) of the pair with
+    # column <= row < column + window
+    for bq, bk, win, s in [(64, 32, 40, 256), (32, 64, 40, 256),
+                           (32, 32, 1, 128)]:
+        for i in range(s // bq):
+            for j in range(s // bk):
+                by_hand = any(c <= r < c + win
+                              for r in range(i * bq, i * bq + bq)
+                              for c in range(j * bk, j * bk + bk))
+                assert bool(_fa._tile_is_live(i, j, bq, bk, win)) == by_hand
+            inside = [j for j in range(s // bk)
+                      if _fa._tile_is_live(i, j, bq, bk, win)]
+            assert inside == list(range(
+                _fa._first_live_kv(i, bq, bk, win),
+                _fa._last_live_kv(i, bq, bk) + 1))
+            assert len(inside) <= _fa._band_steps_kv(s // bq, bq, bk, win)
+
+
+@pytest.mark.parametrize("clamped", [True, False],
+                         ids=["clamped", "unclamped"])
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 64)])
+def test_window_skips_tiles_outside_the_band(bq, bk, clamped, monkeypatch):
+    """A tile pair outside the band does no work, in all three kernels: NaN
+    planted in the first tile's k/v rows reaches only the queries whose band
+    holds them (the tiles before the band's lower edge are not in the grid at
+    all), and NaN in the last tile's q/dO rows only the keys those rows see.
+    ``unclamped`` takes the index maps' clamps away (to the last tile there
+    is, so that a dead step's own NaN block IS fetched) and ``pl.when`` alone
+    has to keep it out."""
+    s, blk, window = 256, max(bq, bk), 40
+    if not clamped:
+        monkeypatch.setattr(_fa, "_last_live_kv",
+                            lambda qi, bq_, bk_: s // bk_ - 1)
+        monkeypatch.setattr(_fa, "_last_live_q",
+                            lambda ki, bq_, bk_, w_: 1 << 20)
+    q, k, v = _rand_qkv(s=s, d=16, seed=41)
+    w = jnp.asarray(np.random.RandomState(3).randn(*q.shape), jnp.float32)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, True, window=window, block_q=bq,
+                               block_k=bk)
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda a, b, c: (attend(a, b, c) * w).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    clean_o, clean = attend(q, k, v), grads(q, k, v, w)
+    nan = jnp.full((2, 3, blk, 16), jnp.nan)
+    # keys 0 .. blk-1 are seen by rows under blk + window - 1 and no other:
+    # the forward and the dq kernel, from the first Q tile wholly past them
+    row0 = -(-(blk + window - 1) // blk) * blk
+    k_nan, v_nan = k.at[:, :, :blk].set(nan), v.at[:, :, :blk].set(nan)
+    o = attend(q, k_nan, v_nan)
+    np.testing.assert_allclose(np.asarray(o[:, :, row0:]),
+                               np.asarray(clean_o[:, :, row0:]), atol=1e-6)
+    assert np.isnan(np.asarray(o[:, :, :blk])).any()    # the NaN was there
+    dq = grads(q, k_nan, v_nan, w)[0]
+    np.testing.assert_allclose(np.asarray(dq[:, :, row0:]),
+                               np.asarray(clean[0][:, :, row0:]), atol=1e-6)
+    # the last KV tile is past the diagonal for every Q tile before the last
+    o = attend(q, k.at[:, :, -blk:].set(nan), v.at[:, :, -blk:].set(nan))
+    np.testing.assert_allclose(np.asarray(o[:, :, :-blk]),
+                               np.asarray(clean_o[:, :, :-blk]), atol=1e-6)
+    # the dkv kernel: rows s-blk .. s-1 see keys from s - blk - window + 1
+    key1 = (s - blk - window + 1) // blk * blk
+    got = grads(q.at[:, :, -blk:].set(nan), k, v, w.at[:, :, -blk:].set(nan))
+    for name, a, b in zip(("dk", "dv"), got[1:], clean[1:]):
+        np.testing.assert_allclose(np.asarray(a[:, :, :key1]),
+                                   np.asarray(b[:, :, :key1]), atol=1e-6,
+                                   err_msg=name)
+        assert np.isnan(np.asarray(a[:, :, -blk:])).any(), name
+    # and the first Q tile is before the diagonal of every KV tile past it
+    got = grads(q.at[:, :, :blk].set(nan), k, v, w.at[:, :, :blk].set(nan))
+    for name, a, b in zip(("dk", "dv"), got[1:], clean[1:]):
+        np.testing.assert_allclose(np.asarray(a[:, :, blk:]),
+                                   np.asarray(b[:, :, blk:]), atol=1e-6,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32)], ids=str)
+def test_without_a_window_nothing_changed(blocks):
+    """``window=None`` is the causal kernel it always was (its lowered
+    program is pinned in ``tests/test_flash_aot.py``): the same results as a
+    window that covers the whole prefix, bit for bit the same from call to
+    call, and no trace of the window's arithmetic in its jaxpr."""
+    q, k, v = _rand_qkv(s=128, d=16, seed=51)
+    bq, bk = blocks
+    plain = flash_attention(q, k, v, True, block_q=bq, block_k=bk)
+    wide = flash_attention(q, k, v, True, window=128, block_q=bq, block_k=bk)
+    np.testing.assert_allclose(np.asarray(plain), np.asarray(wide),
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(plain), np.asarray(dense_attention(q, k, v, True)),
+        atol=FWD_ATOL)
+
+    def grid_of(window):
+        jaxpr = jax.make_jaxpr(lambda *a: flash_attention(
+            *a, True, window=window, block_q=bq, block_k=bk))(q, k, v)
+        return [eqn.params["grid_mapping"].grid
+                for eqn, _ in _walk_eqns(jaxpr.jaxpr)
+                if eqn.primitive.name == "pallas_call"]
+
+    assert grid_of(None) == [(6, 128 // bq, 128 // bk)]
+    assert grid_of(16) == [(6, 128 // bq, _fa._band_steps_kv(
+        128 // bq, bq, bk, 16))]
