@@ -140,6 +140,34 @@ def _permute_rows_bwd(saved, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+@jax.custom_vjp
+def _gather_tokens(h, token, inv_order, held_kn):
+    """``h[token]``: the row of ``h [N, D]`` that each of the ``k * N`` sorted
+    slots holds, out of ``h`` itself (no ``[N * k, D]`` copy of it is made).
+    ``token`` holds each of the N tokens k times, so the gradient is a sum
+    over a token's k slots: the cotangent is gathered by ``inv_order`` into
+    pick-major order (a gather, never a scatter-add), viewed ``[k, N, D]``,
+    the picks that are not held (``held_kn [k, N]`` false: dead slots, which
+    a grouped product's transpose leaves unwritten) selected away, and summed
+    over k in float32."""
+    return h[token]
+
+
+def _gather_tokens_fwd(h, token, inv_order, held_kn):
+    return h[token], (inv_order, held_kn)
+
+
+def _gather_tokens_bwd(saved, g):
+    inv_order, held_kn = saved
+    k, n = held_kn.shape
+    g_kn = jnp.where(held_kn[..., None], g[inv_order].reshape(k, n, -1), 0)
+    return (jnp.sum(g_kn.astype(jnp.float32), axis=0).astype(g.dtype),
+            None, None, None)
+
+
+_gather_tokens.defvjp(_gather_tokens_fwd, _gather_tokens_bwd)
+
+
 def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
     """The held experts' part of a routed SwiGLU layer, and its counters.
 
@@ -149,17 +177,32 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
     a token that are held of w_e * E_e(h)``, ``[N, D]`` in ``h``'s type:
     what the absent experts would add is left out.
 
-    No token is dropped. The ``N x k`` assignments are sorted by expert
-    (those of absent experts last), their rows gathered, and each
-    projection is ONE grouped product over the held experts' groups
+    No token is dropped. The ``N x k`` assignments are numbered PICK-MAJOR:
+    pick ``j`` of token ``t`` is assignment ``j * N + t``, so every buffer
+    over the assignments is ``[k, N, ...]``, k contiguous ``[N, ...]`` slabs,
+    and the sum over a token's picks adds slabs. They are sorted by expert
+    (those of absent experts last; inside an expert by pick, then token),
+    each sorted slot's row is gathered once out of ``h``, and each projection
+    is ONE grouped product over the held experts' groups
     (``jax.lax.ragged_dot``). Shapes are static: the row buffer holds all
-    ``N x k`` assignments, the worst case, and the rows past the held
-    groups belong to no group.
+    ``N x k`` assignments, the worst case, and the slots past the held
+    groups (the dead slots) belong to no group.
+
+    A grouped product neither reads nor WRITES the dead slots, going forward
+    or going backward: on the chip they hold whatever was there. What enters
+    the experts is therefore not masked, and what comes out is masked where
+    it is next read, behind the gather that takes it back to pick-major
+    order: the slot an assignment sorted into is dead exactly when the
+    assignment is not held, so there the mask is ``is_held`` viewed
+    ``[k, N]`` and fuses into the reduction that follows -- the weighted sum
+    over the picks going forward, and going backward the sum of ``h``'s
+    gradient over the picks (:func:`_gather_tokens`). Each mask is a SELECT,
+    never a product by zero: an unwritten row may hold ``inf`` or ``nan``.
     """
     n, k = idx.shape
     held = w1.shape[0]
     with jax.named_scope("moe_dispatch"):
-        flat = idx.reshape(n * k) - first_held
+        flat = idx.T.reshape(k * n) - first_held        # pick j, token t: j*n+t
         is_held = (flat >= 0) & (flat < held)
         local = jnp.where(is_held, flat, held)          # absent sort last
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
@@ -167,12 +210,8 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
             jnp.int32)
         n_held = jnp.sum(group_sizes)
-        live = (jnp.arange(n * k, dtype=jnp.int32) < n_held)[:, None]
-        # a grouped product leaves the rows past its groups unwritten, going
-        # forward and going backward: what enters and what leaves the experts
-        # is masked, so neither the result nor h's gradient reads them
-        rows = jnp.where(live, _permute_rows(
-            jnp.repeat(h, k, axis=0), order, inv_order), 0)
+        held_kn = is_held.reshape(k, n)
+        rows = _gather_tokens(h, order % n, inv_order, held_kn)
     with jax.named_scope("moe_experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                                 preferred_element_type=h.dtype)
@@ -180,10 +219,11 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         b = dot(rows, w3.astype(h.dtype))
         y = dot(jax.nn.silu(a) * b, w2.astype(h.dtype))
     with jax.named_scope("moe_combine"):
-        y = jnp.where(live, y, 0)
-        y = _permute_rows(y, inv_order, order).reshape(n, k, -1)
-        wk = jnp.where(is_held.reshape(n, k), w, 0.0)
-        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), wk)
+        y = _permute_rows(y, inv_order, order).reshape(k, n, -1)
+        out = jnp.einsum(
+            "knd,kn->nd",
+            jnp.where(held_kn[..., None], y, 0).astype(jnp.float32),
+            jnp.where(held_kn, w.T, 0.0))
     sizes = group_sizes.astype(jnp.float32)
     assigned_here = jnp.sum(is_held)
     counters = {

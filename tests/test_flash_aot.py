@@ -243,3 +243,62 @@ def test_the_scan_compiles_at_a_ragged_length_in_float32(one_chip):
         *t, interpret=False)[0].sum(), argnums=tuple(range(6)))).lower(
             x, x, a, cols, cols, d).compile().as_text()
     assert text.count("tpu_custom_call") == 2
+
+
+# -- the routed layer's row movement (PR 35) --------------------------------------
+
+def _entry_results(text: str) -> list:
+    """``(opcode, dtype, dims)`` of every array that an instruction of the
+    ENTRY computation yields (a fused computation's inner shapes are never
+    materialised, so they are left out)."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    out = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(",
+                     line)
+        if m:
+            out += [(m.group(2), dtype, tuple(map(int, dims.split(","))))
+                    for dtype, dims in re.findall(r"(\w+)\[([0-9,]+)\]",
+                                                  m.group(1))]
+    return out
+
+
+def test_the_routed_layer_moves_each_held_row_once(one_chip):
+    """``held_experts_ffn`` and its gradient under ``jax.checkpoint`` at the
+    LFM2 cell's shape (16,384 tokens, top 4, 2048 wide, experts of 1792, 8
+    held of 32, bf16): the assignment rows are ``[65536, 2048]`` or its
+    pick-major view ``[4, 16384, 2048]``, 268 MB each. Outside the grouped
+    products, the compiled program writes such a buffer 8 times (the six
+    gathers, the sum of the two products' ``d rows``, the combine's
+    gradient; 15 times before PR 35), none of them token-major
+    (``[16384, 4, 2048]``, which the compiler tiles ``T(4,128)``: half-empty
+    registers), none in float32 and none a broadcast of ``h``. Structure,
+    not time: nothing runs."""
+    from sparkdl_tpu.parallel.moe import held_experts_ffn
+    n, k, d, f, held = 16384, 4, 2048, 1792, 8
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((n, d), jnp.bfloat16), sd((n, k), jnp.float32),
+            sd((held, d, f), jnp.float32), sd((held, d, f), jnp.float32),
+            sd((held, f, d), jnp.float32), sd((n, k), jnp.int32))
+
+    @jax.checkpoint
+    def layer(h, w, w1, w3, w2, idx):
+        return held_experts_ffn(h, idx, w, w1, w3, w2)[0]
+
+    def loss(*a):
+        return (layer(*a).astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    rows = [(op, dtype, dims) for op, dtype, dims in _entry_results(text)
+            if math.prod(dims) == n * k * d]
+    assert rows and {dims for _, _, dims in rows} <= {
+        (n * k, d), (k, n, d)}, rows
+    assert {dtype for _, dtype, _ in rows} == {"bf16"}, rows
+    written = [op for op, _, _ in rows
+               if op not in ("custom-call", "bitcast", "reshape")]
+    assert "broadcast" not in written, written
+    assert len(written) <= 8, written

@@ -208,16 +208,69 @@ def expert_weights(key, e=32, d=32, f=16, held=32):
             0.2 * jax.random.normal(ks[4], (held, f, d)))
 
 
-def test_expert_layer_with_all_experts_held_against_the_per_token_loop():
-    wr, bias, w1, w3, w2 = expert_weights(jax.random.PRNGKey(6))
+def dense_experts(h, idx, w, w1, w3, w2, first=0):
+    """The held experts' part with every held expert run on every token and
+    the weights of the picks that name it: plain jnp, differentiable in
+    ``h`` and ``w``."""
+    out = 0.0
+    for j in range(w1.shape[0]):
+        y = (jax.nn.silu(h @ w1[j]) * (h @ w3[j])) @ w2[j]
+        out = out + jnp.sum(jnp.where(idx == first + j, w, 0.0), -1,
+                            keepdims=True) * y
+    return out
+
+
+def steered(wr, logits):
+    """A token whose router logits are ``logits`` (least squares)."""
+    return jnp.asarray(np.linalg.lstsq(
+        np.asarray(wr, np.float64).T, np.asarray(logits, np.float64),
+        rcond=None)[0], jnp.float32)
+
+
+@pytest.mark.parametrize("first,held", [(0, 32), (8, 8)],
+                         ids=["all_experts_held", "uneven_groups_of_8_held"])
+def test_expert_layer_against_the_per_token_loop(first, held):
+    """Output, ``dh`` and the counters. With experts 8-15 held of 32 the
+    groups are uneven, token 0 picks four held experts (8-11) and token 1
+    only absent ones (0, 1, 20, 31): its rows of the result and of ``dh`` are
+    zero."""
+    wr, bias, w1, w3, w2 = expert_weights(jax.random.PRNGKey(6), held=held)
+    bias = 0.1 * bias
     h = jax.random.normal(jax.random.PRNGKey(7), (24, 32))
-    idx, w = sigmoid_topk_route(h, wr, bias, 4)
-    out, counters = held_experts_ffn(h, idx, w, w1, w3, w2)
-    want, picks = per_token_loop(h, wr, bias, w1, w3, w2, 4)
+    if held < 32:
+        h = h.at[0].set(steered(wr, np.where(
+            np.isin(np.arange(32), [8, 9, 10, 11]), 6.0, -6.0)))
+        h = h.at[1].set(steered(wr, np.where(
+            np.isin(np.arange(32), [0, 1, 20, 31]), 6.0, -6.0)))
+    cot = jax.random.normal(jax.random.PRNGKey(18), h.shape)
+
+    def layer(x):
+        idx, w = sigmoid_topk_route(x, wr, bias, 4)
+        out, counters = held_experts_ffn(x, idx, w, w1, w3, w2, first)
+        return (out * cot).sum(), (out, counters, idx)
+
+    (_, (out, counters, idx)), dh = jax.value_and_grad(
+        layer, has_aux=True)(h)
+    want, picks = per_token_loop(h, wr, bias, w1, w3, w2, 4, first)
     assert [sorted(r) for r in np.asarray(idx).tolist()] == picks
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=1e-6)
-    assert float(counters["moe_assignments_held"]) == 24 * 4
+    want_dh = jax.grad(lambda x: (dense_experts(
+        x, idx, sigmoid_topk_route(x, wr, bias, 4)[1], w1, w3, w2, first)
+        * cot).sum())(h)
+    np.testing.assert_allclose(dh, want_dh, rtol=2e-4, atol=2e-6)
+    loads = np.bincount(np.concatenate(picks), minlength=32)[
+        first:first + held]
+    assert float(counters["moe_assignments"]) == 24 * 4
+    assert float(counters["moe_assignments_held"]) == loads.sum()
+    assert float(counters["moe_held_load_max"]) == loads.max()
+    np.testing.assert_allclose(counters["moe_held_load_mean"], loads.mean(),
+                               rtol=1e-6)
     assert float(counters["moe_dropped"]) == 0.0
+    if held < 32:
+        assert picks[0] == [8, 9, 10, 11] and picks[1] == [0, 1, 20, 31]
+        assert len(set(loads.tolist())) > 2          # uneven groups
+        assert not np.asarray(out[1]).any() and np.asarray(out[0]).any()
+        assert not np.asarray(dh[1]).any() and np.asarray(dh[0]).any()
 
 
 def test_no_token_is_dropped_when_every_token_picks_one_expert():
@@ -235,12 +288,15 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
     assert float(counters["moe_held_load_mean"]) == 20.0
 
 
-def test_rows_past_the_held_groups_are_never_read(monkeypatch):
-    """On the chip a grouped product leaves the rows past its groups
-    unwritten, in its result and in its gradient alike (PR 29: the first
-    gradient read 16-20% high). Here such a product is planted: the
-    layer's output and its gradient with respect to its input must not
-    change."""
+@pytest.mark.parametrize("left", [1e4, float("nan"), float("inf")],
+                         ids=["1e4", "nan", "inf"])
+def test_rows_past_the_held_groups_are_never_read(monkeypatch, left):
+    """On the chip a grouped product neither reads nor writes the rows past
+    its groups, in its result and in its gradient alike (PR 29: the first
+    gradient read 16-20% high). Here such a product is planted, which leaves
+    ``left`` in those rows: the layer's output and its gradients with respect
+    to its input, the experts' three weights and the router's must not
+    change. ``nan`` and ``inf`` pass only where every mask is a select."""
     wr, bias, w1, w3, w2 = expert_weights(jax.random.PRNGKey(15), held=8)
     h = jax.random.normal(jax.random.PRNGKey(16), (24, 32))
     cot = jax.random.normal(jax.random.PRNGKey(17), h.shape)
@@ -248,7 +304,7 @@ def test_rows_past_the_held_groups_are_never_read(monkeypatch):
 
     def soil(x, group_sizes):
         dead = jnp.arange(x.shape[0]) >= jnp.sum(group_sizes)
-        return jnp.where(dead[:, None], 1e4, x)
+        return jnp.where(dead[:, None], left, x)
 
     @jax.custom_vjp
     def dirty(lhs, rhs, group_sizes):
@@ -264,17 +320,24 @@ def test_rows_past_the_held_groups_are_never_read(monkeypatch):
 
     dirty.defvjp(fwd, bwd)
 
-    def layer(x):
-        idx, w = sigmoid_topk_route(x, wr, bias, 4)
-        return (held_experts_ffn(x, idx, w, w1, w3, w2)[0] * cot).sum()
+    def layer(x, router, experts):
+        idx, w = sigmoid_topk_route(x, router, bias, 4)
+        return (held_experts_ffn(x, idx, w, *experts)[0] * cot).sum()
 
-    want, want_g = jax.value_and_grad(layer)(h)
+    grad = jax.value_and_grad(layer, argnums=(0, 1, 2))
+    want, want_g = grad(h, wr, (w1, w3, w2))
     monkeypatch.setattr(
         jax.lax, "ragged_dot",
         lambda lhs, rhs, group_sizes, **kw: dirty(lhs, rhs, group_sizes))
-    got, got_g = jax.value_and_grad(layer)(h)
+    got, got_g = grad(h, wr, (w1, w3, w2))
     np.testing.assert_allclose(got, want, rtol=1e-6)
-    np.testing.assert_allclose(got_g, want_g, rtol=1e-6, atol=1e-7)
+    for name, mine, theirs in zip(
+            ("h", "router", "w1", "w3", "w2"),
+            jax.tree_util.tree_leaves(got_g),
+            jax.tree_util.tree_leaves(want_g)):
+        assert np.asarray(theirs).any(), name
+        np.testing.assert_allclose(mine, theirs, rtol=1e-6, atol=1e-7,
+                                   err_msg=name)
 
 
 def test_expert_bias_moves_the_selection_and_not_the_weights():
