@@ -58,6 +58,18 @@ KERNEL_ATOL = 4e-2
 # wrong column, chunk edge or carried state is O(1).
 SCAN_TOL = 2.0 ** -7
 
+# The chunked state-space-dual scan against the same plain recurrence, as a
+# share of the reference's largest entry. Unlike the selective scan, its sums
+# over positions are MXU products: the decay-weighted [Q, Q] tile, the state
+# and the cotangents each round to bf16 (2^-9 of their size) before a product
+# whose float32 accumulator adds up to 256 of them, and ``y``, ``dx`` round
+# once more; independent roundings grow with the root of their number.
+# Observed on the v5e at the cell's shape (PR 36): 5.8e-3 and, on another
+# draw, 6.6e-3 at the worst (``dB``, ``dx``; ``dC`` 3.4e-3 to 4.5e-3, ``dA``
+# up to 2.3e-3, ``ddt`` 8e-4). 2^-6 leaves that 2.4 times of room; a wrong
+# mask, chunk edge or carried state is O(1).
+SSD_TOL = 2.0 ** -6
+
 
 class CompileClock:
     """Seconds XLA spent compiling, from jax's own monitoring events."""
@@ -328,6 +340,37 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
     return out
 
 
+def _scan_gaps(scan, plain, args, w, first, names, summed) -> dict:
+    """Forward and every gradient of ``scan`` at all of ``args`` against
+    ``plain`` on what ``first`` keeps of them, each gap as a share of the
+    reference's largest entry: ``{"max_err", "<name>_err", ...}``. The
+    gradients named in ``summed`` add up over what ``first`` cuts away, so
+    they are taken from a second call over the kept part alone."""
+    import jax
+    import jax.numpy as jnp
+
+    def grads(fn, args, w):
+        return jax.jit(jax.grad(
+            lambda *a: (fn(*a).astype(jnp.float32)
+                        * w.astype(jnp.float32)).sum(),
+            argnums=tuple(range(len(args)))))(*args)
+
+    few = tuple(first(t) for t in args)
+    y = jax.jit(scan)(*args)
+    assert y.dtype == jnp.bfloat16 and y.shape == args[0].shape
+    want = jax.jit(plain)(*few)
+    rec = {"max_err": _max_err(first(y), want) / float(jnp.abs(want).max())}
+    got, got_few = grads(scan, args, w), grads(scan, few, first(w))
+    ref = grads(plain, few, first(w))
+    for i, name in enumerate(names):
+        g = got_few[i] if name in summed else first(got[i])
+        r = np.asarray(ref[i], np.float32)
+        err = _max_err(g, r) / float(np.abs(r).max())
+        rec[f"{name}_err"] = err
+        rec["max_err"] = max(rec["max_err"], err)
+    return rec
+
+
 def check_selective_scan(rng, *, interpret, seq=8192, channels=5120,
                          states=16, dense_channels=256) -> dict:
     """The selective-scan kernel pair at the Mamba cell's shape (one
@@ -375,31 +418,70 @@ def check_selective_scan(rng, *, interpret, seq=8192, channels=5120,
             return t[..., :nc]
         return t[:nc] if t.shape[0] == channels else t
 
-    def grads(fn, args, w):
-        return jax.jit(jax.grad(
-            lambda *a: (fn(*a).astype(jnp.float32)
-                        * w.astype(jnp.float32)).sum(),
-            argnums=tuple(range(6))))(*args)
-
     def scan(*a):
         return scan_ops.selective_scan(*a, interpret=interpret)[0]
 
-    few = tuple(first(t) for t in args)
-    y = jax.jit(scan)(*args)
-    assert y.dtype == jnp.bfloat16 and y.shape == u.shape
-    want = jax.jit(plain)(*few)
-    rec = {"S": seq, "channels": channels,
-           "max_err": _max_err(first(y), want) / float(jnp.abs(want).max())}
-    got, got_few = grads(scan, args, w), grads(scan, few, first(w))
-    ref = grads(plain, few, first(w))
-    for i, name in enumerate(("du", "ddt", "dA", "dB", "dC", "dD")):
-        g = got_few[i] if name in ("dB", "dC") else first(got[i])
-        r = np.asarray(ref[i], np.float32)
-        err = _max_err(g, r) / float(np.abs(r).max())
-        rec[f"{name}_err"] = err
-        rec["max_err"] = max(rec["max_err"], err)
+    rec = {"S": seq, "channels": channels, **_scan_gaps(
+        scan, plain, args, w, first,
+        ("du", "ddt", "dA", "dB", "dC", "dD"), ("dB", "dC"))}
     assert rec["max_err"] <= SCAN_TOL, rec
     return {"selective_scan": rec}
+
+
+def check_ssd_scan(rng, *, interpret, seq=8192, heads=64, head_dim=64,
+                   states=128, chunk=256, dense_heads=4) -> dict:
+    """The chunked-scan kernel pair at the Mamba-2 cell's shape (one
+    sequence, ``x, B, C`` in bf16, ``dt`` and ``A`` float32): forward and
+    every gradient at all ``heads``, against the plain position-by-position
+    recurrence in float32 on the first ``dense_heads`` (heads do not mix;
+    ``dB`` and ``dC`` sum over them, so those two are taken from a second
+    call over the first heads alone). ``max_err`` is the largest gap, forward
+    or gradient, as a share of the reference's largest entry, held to
+    ``SSD_TOL``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.ops import ssd_scan as ssd_ops
+
+    def plain(x, dt, A, B, C, D):
+        x, B, C = (t.astype(jnp.float32) for t in (x, B, C))
+
+        def step(h, inp):
+            x_t, dt_t, b_t, c_t = inp
+            h = jnp.exp(dt_t * A)[..., None, None] * h \
+                + (dt_t[..., None] * x_t)[..., None] * b_t[:, None, None, :]
+            return h, jnp.sum(h * c_t[:, None, None, :], -1)
+
+        xs = tuple(jnp.swapaxes(t, 0, 1)
+                   for t in (x, dt, B[:, :, 0], C[:, :, 0]))
+        _, y = jax.lax.scan(step, jnp.zeros(
+            (x.shape[0], x.shape[2], x.shape[3], B.shape[-1])), xs)
+        return jnp.swapaxes(y, 0, 1) + D[:, None] * x
+
+    x, w = (jnp.asarray(rng.randn(1, seq, heads, head_dim), jnp.bfloat16)
+            for _ in range(2))
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(1, seq, heads) - 4.0,
+                                     jnp.float32))
+    A = -jnp.asarray(rng.uniform(1.0, 16.0, (heads,)), jnp.float32)
+    B, C = (jnp.asarray(rng.randn(1, seq, 1, states), jnp.bfloat16)
+            for _ in range(2))
+    D = jnp.ones((heads,), jnp.float32)
+    args, nh = (x, dt, A, B, C, D), dense_heads
+
+    def first(t):
+        """The first ``dense_heads`` heads of an operand."""
+        if t.ndim >= 3 and t.shape[2] == heads:
+            return t[:, :, :nh]
+        return t[:nh] if t.shape[0] == heads else t
+
+    def scan(*a):
+        return ssd_ops.ssd_scan(*a, chunk=chunk, interpret=interpret)[0]
+
+    rec = {"S": seq, "heads": heads, **_scan_gaps(
+        scan, plain, args, w, first,
+        ("dx", "ddt", "dA", "dB", "dC", "dD"), ("dB", "dC"))}
+    assert rec["max_err"] <= SSD_TOL, rec
+    return {"ssd_scan": rec}
 
 
 def check_flash_decode(rng, *, interpret, slots, heads, kv_heads, head_dim,
@@ -462,11 +544,13 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                   heads: int = 16, kv_heads: int = 8, head_dim: int = 128,
                   max_len: int = 2048, block_size: int = 16,
                   verify_window: int = 5, kv_dtypes=(None, "int8"),
-                  atol: float = KERNEL_ATOL, scan=None, **flash_grad) -> dict:
+                  atol: float = KERNEL_ATOL, scan=None, ssd=None,
+                  **flash_grad) -> dict:
     """Each Pallas kernel against the dense reference at the shapes the
     server phase serves (and, for the flash kernel's backward pair, the
     training cell's: ``flash_grad`` overrides ``check_flash_attention``'s
-    ``grad_*`` sizes, ``scan`` ``check_selective_scan``'s). ``interpret`` is
+    ``grad_*`` sizes, ``scan`` ``check_selective_scan``'s, ``ssd``
+    ``check_ssd_scan``'s). ``interpret`` is
     passed to every call explicitly:
     False compiles through Mosaic, True is the CPU test's interpreter."""
     rng = np.random.RandomState(0)
@@ -477,6 +561,7 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                                 heads=heads, head_dim=head_dim,
                                 **flash_grad),
         **check_selective_scan(rng, interpret=interpret, **(scan or {})),
+        **check_ssd_scan(rng, interpret=interpret, **(ssd or {})),
         **check_flash_decode(rng, **shape)}
     for kv in kv_dtypes:
         checks.update(check_paged_flash_decode(

@@ -1,5 +1,6 @@
 from .bert import (BertConfig, BertEncoder, BertForSequenceClassification,
                    bert_finetune_loss, glue_loss_fn)
+from .granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
 from .lfm2 import Lfm2Config, Lfm2ForCausalLM
 from .llama import LlamaConfig, LlamaModel, lora_mask, lora_optimizer
 from .lm_loss import causal_lm_loss_fn
@@ -23,7 +24,7 @@ __all__ = [
     "glue_loss_fn", "bert_finetune_loss",
     "LlamaConfig", "LlamaModel", "causal_lm_loss_fn", "lora_mask",
     "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM", "Phi4FlashConfig",
-    "Phi4FlashForCausalLM",
+    "Phi4FlashForCausalLM", "GraniteHybridConfig", "GraniteHybridForCausalLM",
     "load_pretrained", "import_hf_llama", "import_hf_bert",
     "import_keras_resnet", "import_keras_vgg", "import_keras_inception",
     "import_keras_xception",

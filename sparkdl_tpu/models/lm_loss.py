@@ -28,3 +28,15 @@ def causal_lm_loss_fn():
         return loss, {"perplexity": jnp.exp(loss), **counters}
 
     return loss_fn
+
+
+def folded_counters(mutated: dict, folds: dict) -> dict:
+    """One number a counter out of what the layers of a model sowed into its
+    ``counters`` collection (``model.apply(..., mutable=["counters"])[1]``):
+    ``folds[name]`` folds the layers' readings of ``name``, stacked."""
+    seen: dict = {}
+    for path, v in jax.tree_util.tree_flatten_with_path(
+            mutated.get("counters", {}))[0]:
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        seen.setdefault(name, []).append(v)
+    return {k: folds[k](jnp.stack(v)) for k, v in seen.items()}

@@ -40,6 +40,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
+                            dt_bias_init)
+from .lm_loss import folded_counters
+
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
 
 
@@ -111,18 +115,6 @@ class Phi4FlashConfig:
         return cls(**{k: cfg[k] for k in same})
 
 
-def _dense(features: int, dtype, name: str):
-    return nn.Dense(features, use_bias=False, dtype=dtype, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
-
-
-def _count(module: nn.Module, name: str, value):
-    """One layer's reading of a counter; :meth:`Phi4FlashForCausalLM.
-    apply_with_counters` folds the layers' readings."""
-    module.sow("counters", name,
-               jax.lax.stop_gradient(value.astype(jnp.float32)))
-
-
 class _DtProj(nn.Module):
     """``r W_dt`` with a float32 result: ``dt`` is float32 from here on."""
     features: int
@@ -137,13 +129,6 @@ class _DtProj(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-def _dt_bias_init(key, shape):
-    """``softplus^-1(dt0)``, ``dt0`` log-uniform in [1e-3, 1e-1]."""
-    dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
-                                     math.log(1e-3), math.log(1e-1)))
-    return dt0 + jnp.log(-jnp.expm1(-dt0))
-
-
 class Phi4FlashMamba(nn.Module):
     """``(out, y)``: the mixer's output and the scan's, before the gate."""
     cfg: Phi4FlashConfig
@@ -155,7 +140,7 @@ class Phi4FlashMamba(nn.Module):
         c = self.cfg
         di, n, taps, s = c.d_inner, c.mamba_d_state, c.mamba_d_conv, x.shape[1]
         with jax.named_scope("mamba_in_proj"):
-            u, z = jnp.split(_dense(2 * di, self.dtype, "in_proj")(x), 2,
+            u, z = jnp.split(dense(2 * di, self.dtype, "in_proj")(x), 2,
                              axis=-1)
         with jax.named_scope("mamba_conv"):
             bound = 1.0 / math.sqrt(taps)
@@ -169,19 +154,19 @@ class Phi4FlashMamba(nn.Module):
                                 for j in range(taps))
                             + bias.astype(self.dtype))
             r, b_t, c_t = jnp.split(
-                _dense(c.dt_rank + 2 * n, self.dtype, "x_proj")(u),
+                dense(c.dt_rank + 2 * n, self.dtype, "x_proj")(u),
                 [c.dt_rank, c.dt_rank + n], axis=-1)
             dt = jax.nn.softplus(
                 _DtProj(di, self.dtype, name="dt_proj")(r)
-                + self.param("dt_bias", _dt_bias_init, (di,)))
+                + self.param("dt_bias", dt_bias_init, (di,)))
         a_log = self.param("A_log", lambda k, shp: jnp.broadcast_to(jnp.log(
             jnp.arange(1, n + 1, dtype=jnp.float32)), shp), (di, n))
         skip = self.param("D", nn.initializers.ones, (di,))
         y, last = selective_scan(u, dt, -jnp.exp(a_log), b_t, c_t, skip)
-        _count(self, "ssm_state_absmax", jnp.max(jnp.abs(last)))
-        _count(self, "ssm_dt_mean", jnp.mean(dt))
+        count(self, "ssm_state_absmax", jnp.max(jnp.abs(last)))
+        count(self, "ssm_dt_mean", jnp.mean(dt))
         with jax.named_scope("mamba_out_proj"):
-            out = _dense(c.hidden_size, self.dtype, "out_proj")(
+            out = dense(c.hidden_size, self.dtype, "out_proj")(
                 y * jax.nn.silu(z))
         return out, y
 
@@ -213,11 +198,11 @@ class Phi4FlashAttention(nn.Module):
         scope = "cross_attention" if kind == CROSS else "diff_attention"
         with jax.named_scope(scope):
             if kind == CROSS:
-                q = heads(_dense(h * hd, self.dtype, "Wq")(x), h)
+                q = heads(dense(h * hd, self.dtype, "Wq")(x), h)
                 k, v = kv
             else:
                 q, k, v = jnp.split(
-                    _dense((h + 2 * hkv) * hd, self.dtype, "Wqkv")(x),
+                    dense((h + 2 * hkv) * hd, self.dtype, "Wqkv")(x),
                     [h * hd, (h + hkv) * hd], axis=-1)
                 q, k, v = heads(q, h), heads(k, hkv), heads(v, hkv)
             # query head 2j + e reads key head 2 (j // rep) + e, and both
@@ -238,7 +223,7 @@ class Phi4FlashAttention(nn.Module):
             lam = jnp.exp(jnp.sum(vec["lambda_q1"] * vec["lambda_k1"])) \
                 - jnp.exp(jnp.sum(vec["lambda_q2"] * vec["lambda_k2"])) \
                 + lam_init
-            _count(self, "diff_lambda_mean", lam)
+            count(self, "diff_lambda_mean", lam)
             o = o[:, :, 0] - lam * o[:, :, 1]
             scale = self.param("subln", lambda k_, shp: {
                 "scale": jnp.ones(shp, jnp.float32)}, (2 * hd,))["scale"]
@@ -246,7 +231,7 @@ class Phi4FlashAttention(nn.Module):
                                   + c.layer_norm_eps) * scale
             o = (o * (1.0 - lam_init)).astype(self.dtype)
             o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
-            return _dense(c.hidden_size, self.dtype, "out_proj")(o), (k, v)
+            return dense(c.hidden_size, self.dtype, "out_proj")(o), (k, v)
 
 
 class Phi4FlashGatedMemory(nn.Module):
@@ -256,9 +241,9 @@ class Phi4FlashGatedMemory(nn.Module):
     @nn.compact
     def __call__(self, x, m):
         with jax.named_scope("gated_memory"):
-            gate = jax.nn.silu(_dense(self.cfg.d_inner, self.dtype,
+            gate = jax.nn.silu(dense(self.cfg.d_inner, self.dtype,
                                       "in_proj")(x))
-            return _dense(self.cfg.hidden_size, self.dtype, "out_proj")(
+            return dense(self.cfg.hidden_size, self.dtype, "out_proj")(
                 m.astype(self.dtype) * gate)
 
 
@@ -268,10 +253,10 @@ class Phi4FlashMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        gate, up = jnp.split(_dense(2 * self.cfg.intermediate_size,
+        gate, up = jnp.split(dense(2 * self.cfg.intermediate_size,
                                     self.dtype, "gate_up_proj")(x), 2,
                              axis=-1)
-        return _dense(self.cfg.hidden_size, self.dtype, "down_proj")(
+        return dense(self.cfg.hidden_size, self.dtype, "down_proj")(
             jax.nn.silu(gate) * up)
 
 
@@ -349,20 +334,4 @@ class Phi4FlashForCausalLM(nn.Module):
         ``diff_lambda_mean`` the mean ``lambda`` over the attention layers
         (the second map's weight, which drifts with training)."""
         logits, mut = self.apply(variables, ids, mutable=["counters"])
-        seen: dict = {}
-        for path, v in jax.tree_util.tree_flatten_with_path(
-                mut.get("counters", {}))[0]:
-            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
-            seen.setdefault(name, []).append(v)
-        return logits, {k: _FOLDS[k](jnp.stack(v)) for k, v in seen.items()}
-
-
-def decay_mask(params):
-    """True for the leaves weight decay touches: the matrices and the
-    embedding (``optax.adamw(..., mask=decay_mask)``); none on norms,
-    ``A_log``, ``D``, biases, the convolution's taps, the ``lambda``
-    vectors."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: getattr(path[-1], "key", None) in ("kernel",
-                                                           "embedding"),
-        params)
+        return logits, folded_counters(mut, _FOLDS)

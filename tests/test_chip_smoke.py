@@ -50,7 +50,9 @@ def test_kernel_phase_tiny_interpreted():
         interpret=True, seq=256, slots=3, heads=4, kv_heads=2,
         head_dim=32, max_len=128, block_size=8, verify_window=3,
         grad_seqs=(256,), grad_heads=3, grad_head_dim=32, grad_window=40,
-        scan=dict(seq=40, channels=200, states=4, dense_channels=128))
+        scan=dict(seq=40, channels=200, states=4, dense_channels=128),
+        ssd=dict(seq=40, heads=6, head_dim=8, states=4, chunk=8,
+                 dense_heads=2))
     assert rec["interpret"] is True
     assert abs(rec["flash_attention_grad_S256"]["dk_norm_ratio"] - 1) < 1e-2
     assert {"flash_attention", "flash_decode", "paged_flash_decode_bf16_S1",
@@ -62,6 +64,9 @@ def test_kernel_phase_tiny_interpreted():
     assert rec["selective_scan"]["max_err"] <= chip_smoke.SCAN_TOL
     assert {"du_err", "ddt_err", "dA_err", "dB_err", "dC_err",
             "dD_err"} <= set(rec["selective_scan"])
+    assert rec["ssd_scan"]["max_err"] <= chip_smoke.SSD_TOL
+    assert {"dx_err", "ddt_err", "dA_err", "dB_err", "dC_err",
+            "dD_err"} <= set(rec["ssd_scan"])
 
 
 def test_the_scan_check_sees_a_scan_that_restarts_its_state(monkeypatch):
@@ -83,6 +88,28 @@ def test_the_scan_check_sees_a_scan_that_restarts_its_state(monkeypatch):
         chip_smoke.check_selective_scan(
             np.random.RandomState(0), interpret=True, seq=32, channels=128,
             states=4, dense_channels=128)
+
+
+def test_the_chunked_scan_check_sees_a_state_that_is_not_handed_on(
+        monkeypatch):
+    """A kernel that starts every other chunk from nothing is O(1) off, and
+    the check says so."""
+    import jax.numpy as jnp
+    import numpy as np
+    from sparkdl_tpu.ops import ssd_scan as ssd
+    real = ssd.ssd_scan
+
+    def restarted(x, dt, A, B, C, D, **kw):
+        half = x.shape[1] // 2
+        parts = [real(x[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], D, **kw)
+                 for sl in (slice(0, half), slice(half, None))]
+        return jnp.concatenate([y for y, _ in parts], axis=1), parts[1][1]
+
+    monkeypatch.setattr(ssd, "ssd_scan", restarted)
+    with pytest.raises(AssertionError):
+        chip_smoke.check_ssd_scan(
+            np.random.RandomState(0), interpret=True, seq=32, heads=2,
+            head_dim=8, states=4, chunk=8, dense_heads=2)
 
 
 def test_server_phase_tiny():

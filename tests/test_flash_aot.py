@@ -245,6 +245,113 @@ def test_the_scan_compiles_at_a_ragged_length_in_float32(one_chip):
     assert text.count("tpu_custom_call") == 2
 
 
+# -- the chunked scan, and the flash kernels under a third decoder (PR 36) ------------
+
+# sha256 of each flash kernel's Mosaic module, printed without debug
+# locations, at the shapes of the other two decoders' attention calls, as the
+# tree before PR 36 lowered them (``ops/flash_attention.py`` is untouched by
+# it; this container's jax): the Phi cell's windowed and full differential
+# attention (40 heads, values 128 wide) and the Granite cell's one layer (the
+# LFM2 cell's call at half the batch, ``q`` handed over already scaled: no
+# ``scale`` argument went into the kernels).
+OTHER_CELLS_KERNELS = {
+    "phi-window-512": ((1, 40, 128, 512), {
+        "flash_attention_fwd":
+            "0a77f802976315a2063a622524f4fc68c3b4cf56ba05219bf7fc4770b63332f8",
+        "flash_attention_bwd_dkv":
+            "c9a184054e5a98f9a36a53642e1abd3287e2b76066c1be810aff0c277cb48b14",
+        "flash_attention_bwd_dq":
+            "dbeda05144dd43d246b3cfaa918fb88cedc8cab7535a9b2c73e4d30e7220a9b8"}),
+    "phi-full": ((1, 40, 128, None), {
+        "flash_attention_fwd":
+            "ccd220eb872d9feadb03775cc611a3c1fcc2addb50466e68e4046d200e4cc783",
+        "flash_attention_bwd_dkv":
+            "9c1bc0764d45a12770bfe74b6d7f1d2b3ab5a2ed43808d2bfc46e97c5d5306f6",
+        "flash_attention_bwd_dq":
+            "db0b58863592f5e3666f5e69d8dbe081c9c8923b4d53a51b8c3e46e9b379ac3b"}),
+    "granite": ((1, 32, 64, None), {
+        "flash_attention_fwd":
+            "4212efe787dfdf24e57b0a0813b60ad7c9a6ddcddbcc9685a4e897cf56a2646c",
+        "flash_attention_bwd_dkv":
+            "f7d13badffe3356dfefbbb4edf9306628003dcf21c117d0a0458382ab0fae152",
+        "flash_attention_bwd_dq":
+            "8dc8c671f917707e649d09c6d72d52fc3b715117de274794c2ccf1fdbfc4a0a8"}),
+}
+
+
+@pytest.mark.parametrize("call", OTHER_CELLS_KERNELS.values(),
+                         ids=OTHER_CELLS_KERNELS.keys())
+def test_the_other_decoders_flash_kernels_lower_to_the_programs_they_were(
+        one_chip, call):
+    import hashlib
+    (b, h, dv, window), pinned = call
+    qk = jax.ShapeDtypeStruct((b, h, 8192, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, 8192, dv), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, window=window,
+                                interpret=False).astype(jnp.float32)
+                ** 2).sum()
+
+    modules = _kernel_modules(jax.jit(jax.grad(
+        loss, argnums=(0, 1, 2))).lower(qk, qk, v).as_text())
+    assert set(modules) == set(pinned)
+    for name, module in modules.items():
+        assert hashlib.sha256(module.encode()).hexdigest() == pinned[name], \
+            name
+
+
+def _ssd_args(one_chip, s, h, p, n, dtype):
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (sd((1, s, h, p), dtype), sd((1, s, h), jnp.float32),
+            sd((h,), jnp.float32), sd((1, s, 1, n), dtype),
+            sd((1, s, 1, n), dtype), sd((h,), jnp.float32))
+
+
+def test_the_cells_chunked_scan_gradient_keeps_no_tile_and_no_state_a_position(
+        one_chip):
+    """One sequence of 8192 positions, 64 heads of 64, 128 states, ``x, B,
+    C`` in bf16 and ``dt`` in float32, chunks of 256 — the Granite cell's
+    Mamba-2 layer: the forward and the backward kernel compile at the default
+    head block, by name, and no buffer of the compiled gradient holds
+    ``S / Q * H * Q * Q`` elements or more (the decay-masked score tiles,
+    537 MB in float32, which the plain chunked form writes several times a
+    layer) — so none holds a state a position either (``S * H * P * N``, 128
+    times that). The largest are ``x``'s own 33.5 M."""
+    from sparkdl_tpu.ops.ssd_scan import ssd_scan
+    s, h, p, n, q = 8192, 64, 64, 128, 256
+
+    def loss(*a):
+        return (ssd_scan(*a, chunk=q, interpret=False)[0].astype(jnp.float32)
+                ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *_ssd_args(one_chip, s, h, p, n, jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < s // q * h * q * q, largest
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_chunked_scan_compiles_at_a_ragged_length_in_float32(one_chip):
+    """The tests' dtype, a length that is no multiple of the chunk, heads as
+    wide as the lanes."""
+    from sparkdl_tpu.ops.ssd_scan import ssd_scan
+    text = jax.jit(jax.grad(lambda *t: ssd_scan(
+        *t, chunk=128, interpret=False)[0].sum(),
+        argnums=tuple(range(6)))).lower(
+            *_ssd_args(one_chip, 300, 4, 128, 64, jnp.float32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
 # -- the routed layer's row movement (PR 35) --------------------------------------
 
 def _entry_results(text: str) -> list:
