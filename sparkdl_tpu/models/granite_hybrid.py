@@ -24,8 +24,12 @@ any kind (``position_embedding_type`` ``nope``) and four multipliers::
   and the state in float32); then the gate BEFORE the norm:
   ``RMSNorm(y * silu(z)) W_out`` over all ``d_inner`` channels (one group).
 
-Each layer is recomputed in the backward pass (``nn.remat``). Trained through
-``ctx.fit`` like any other model::
+Each layer is recomputed in the backward pass (``nn.remat``), all but what
+its kernel wrote, which is kept by name (``ops.SAVE_KERNEL_RESIDUALS``)
+because the backward kernels read it and a forward kernel is the dearest
+thing in a layer to run again: a Mamba-2 layer's ``y`` and chunk-start states
+(67 + 67 MB a layer at 8192 positions), the attention layer's ``o`` and
+``lse`` (34 + 1 MB). Trained through ``ctx.fit`` like any other model::
 
     model = GraniteHybridForCausalLM(cfg, dtype=jnp.bfloat16)
     ctx.fit(loss_fn=causal_lm_loss_fn(), apply_fn=model.apply_with_counters,
@@ -43,6 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import SAVE_KERNEL_RESIDUALS
 from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
                             dt_bias_init)
 from .llama import RMSNorm
@@ -257,7 +262,8 @@ class GraniteHybridForCausalLM(nn.Module):
             (c.vocab_size, c.hidden_size))["embedding"]
         x = (jnp.take(emb, ids, axis=0)
              * c.embedding_multiplier).astype(self.dtype)
-        layer = nn.remat(GraniteHybridDecoderLayer)
+        layer = nn.remat(GraniteHybridDecoderLayer,
+                         policy=SAVE_KERNEL_RESIDUALS)
         for i, l in enumerate(c.layers):
             x = layer(c, l, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
         x = RMSNorm(c.rms_norm_eps, name="final_layernorm")(x)

@@ -11,9 +11,13 @@ linear maps are without bias.
 
     y  = x + op_i(RMSNorm(x));   x' = y + ffn_i(RMSNorm(y))
 
-Each layer is recomputed in the backward pass (``nn.remat`` on the layer):
-``fit(remat=True)`` wraps the whole forward, which does not lower the peak.
-Trained through ``ctx.fit`` like any other model::
+Each layer is recomputed in the backward pass (``nn.remat`` on the layer:
+``fit(remat=True)`` wraps the whole forward, which does not lower the peak),
+all but what its kernel wrote: the flash forward's ``o`` and ``lse`` are kept
+by name (``ops.SAVE_KERNEL_RESIDUALS``; 67 + 2 MB for the one attention layer
+at 2 x 8192 positions in bf16), because the backward kernels read them and
+the forward kernel is the dearest thing in the layer to run again. Trained
+through ``ctx.fit`` like any other model::
 
     model = Lfm2ForCausalLM(cfg, dtype=jnp.bfloat16)
     ctx.fit(loss_fn=causal_lm_loss_fn(), apply_fn=model.apply_with_counters,
@@ -30,6 +34,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import SAVE_KERNEL_RESIDUALS
 from ..parallel.moe import RoutedExperts
 from .llama import RMSNorm
 
@@ -213,7 +218,7 @@ class Lfm2ForCausalLM(nn.Module):
             "embedding": nn.initializers.normal(0.02)(k, s)},
             (c.vocab_size, c.hidden_size))["embedding"]
         x = jnp.take(emb, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(Lfm2DecoderLayer)
+        layer = nn.remat(Lfm2DecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
         for i in range(len(c.layer_types)):
             x = layer(c, i, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
         x = RMSNorm(c.norm_eps, name="embedding_norm")(x)

@@ -20,9 +20,14 @@ mixer goes by the PUBLISHED index ``l`` of the layer (a cut keeps it:
 - ``l`` odd, ``l >= n/2 + 3``: cross attention, its own queries over layer
   ``n/2 + 1``'s keys and values.
 
-Each layer is recomputed in the backward pass (``nn.remat``); what a layer
-hands on is an output of it, so it is kept and its cotangent flows back.
-Trained through ``ctx.fit`` like any other model::
+Each layer is recomputed in the backward pass (``nn.remat``), all but what
+its kernels wrote, which is kept by name (``ops.SAVE_KERNEL_RESIDUALS``)
+because the backward kernels read it and a forward kernel is the dearest
+thing in a layer to run again: an attention layer's ``o`` and ``lse`` (84 +
+1.3 MB a layer at 8192 positions in bf16), a Mamba layer's ``y`` and
+chunk-start states (84 + 21 MB). What a layer hands on is an output of it, so
+it is kept and its cotangent flows back. Trained through ``ctx.fit`` like any
+other model::
 
     model = Phi4FlashForCausalLM(cfg, dtype=jnp.bfloat16)
     ctx.fit(loss_fn=causal_lm_loss_fn(), apply_fn=model.apply_with_counters,
@@ -40,6 +45,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import SAVE_KERNEL_RESIDUALS
 from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
                             dt_bias_init)
 from .lm_loss import folded_counters
@@ -312,7 +318,7 @@ class Phi4FlashForCausalLM(nn.Module):
             "embedding": nn.initializers.normal(0.02)(k, s)},
             (c.vocab_size, c.hidden_size))["embedding"]
         x = jnp.take(emb, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(Phi4FlashDecoderLayer)
+        layer = nn.remat(Phi4FlashDecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
         handed = {}
         for i, l in enumerate(c.layers):
             kind = c.kind_of(l)

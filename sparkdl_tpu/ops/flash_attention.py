@@ -45,7 +45,12 @@ Design (the standard streaming-softmax factorization, written for the MXU):
   the flash-attention memory contract — and no score-sized array is ever
   an HBM operand. The dkv kernel holds its score tile transposed too, so
   the row statistics broadcast from their lane-oriented blocks and no
-  score-sized transpose is needed in any kernel.
+  score-sized transpose is needed in any kernel. The ``fwd`` rule names
+  what the backward reads of the forward's outputs (``RESIDUAL_NAMES``: ``o``
+  and ``lse``, through ``checkpoint_name``), so a ``jax.checkpoint`` whose
+  policy saves those names (``ops.SAVE_KERNEL_RESIDUALS``, on the decoder
+  layers' ``nn.remat``) runs the forward kernel once; under any other
+  checkpoint, or none, the names are the identity.
 
 ``interpret=True`` (or platform != tpu) runs the same kernel through the
 Pallas interpreter — how CPU tests validate kernel semantics; a TPU-gated
@@ -59,6 +64,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
@@ -574,10 +580,16 @@ def _flash_core(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
     return o
 
 
+# What the forward kernel writes and the backward pair reads, by the names
+# ``_flash_fwd`` gives them (``ops.SAVE_KERNEL_RESIDUALS`` keeps them).
+RESIDUAL_NAMES = ("flash_attention_o", "flash_attention_lse")
+
+
 def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
                window):
-    o, lse = _fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
-                  window)
+    o, lse = map(checkpoint_name, _fwd(
+        q, k, v, kv_mask, causal, block_q, block_k, interpret, window),
+        RESIDUAL_NAMES)
     return o, (q, k, v, kv_mask, o, lse)
 
 

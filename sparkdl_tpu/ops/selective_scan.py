@@ -47,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _LANES = 128
@@ -297,8 +298,16 @@ def _scan_core(u, dt, A, B, C, D, chunk: int, block_c: int, interpret: bool):
     return y, last
 
 
+# What the forward kernel writes, by the names ``_scan_fwd`` gives them
+# (``ops.SAVE_KERNEL_RESIDUALS`` keeps them: the backward reads ``starts``,
+# the layer ``y`` and the counter ``last``).
+RESIDUAL_NAMES = ("selective_scan_y", "selective_scan_starts",
+                  "selective_scan_last")
+
+
 def _scan_fwd(u, dt, A, B, C, D, chunk, block_c, interpret):
-    y, starts, last = _fwd(u, dt, A, B, C, D, chunk, block_c, interpret)
+    y, starts, last = map(checkpoint_name, _fwd(
+        u, dt, A, B, C, D, chunk, block_c, interpret), RESIDUAL_NAMES)
     return (y, last), (u, dt, A, B, C, D, starts)
 
 

@@ -53,6 +53,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from .flash_attention import _LANES, _NT, _TN, _as_column, _resolve
@@ -368,8 +369,15 @@ def _scan_core(x, dt, A, B, C, D, chunk: int, block_h: int, interpret: bool):
     return y, last
 
 
+# What the forward kernel writes, by the names ``_scan_fwd`` gives them
+# (``ops.SAVE_KERNEL_RESIDUALS`` keeps them: the backward reads ``starts``,
+# the layer ``y`` and the counter ``last``).
+RESIDUAL_NAMES = ("ssd_scan_y", "ssd_scan_starts", "ssd_scan_last")
+
+
 def _scan_fwd(x, dt, A, B, C, D, chunk, block_h, interpret):
-    y, starts, last = _fwd(x, dt, A, B, C, D, chunk, block_h, interpret)
+    y, starts, last = map(checkpoint_name, _fwd(
+        x, dt, A, B, C, D, chunk, block_h, interpret), RESIDUAL_NAMES)
     return (y, last), (x, dt, A, B, C, D, starts)
 
 
