@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import SAVE_KERNEL_RESIDUALS
+from ..utils import scopes
 from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
                             dt_bias_init)
 from .llama import RMSNorm
@@ -131,11 +132,11 @@ class GraniteHybridMamba(nn.Module):
         heads, groups = c.mamba_n_heads, c.mamba_n_groups
         bsz, s, _ = u.shape
         wide = di + 2 * groups * n
-        with jax.named_scope("mamba_in_proj"):
+        with scopes.layer("mamba_in_proj"):
             z, xbc, dt = jnp.split(
                 dense(di + wide + heads, self.dtype, "in_proj")(u),
                 [di, di + wide], axis=-1)
-        with jax.named_scope("mamba_conv"):
+        with scopes.layer("mamba_conv"):
             bound = 1.0 / math.sqrt(taps)
             kernel = self.param(
                 "conv_kernel", lambda k, shp: jax.random.uniform(
@@ -165,11 +166,11 @@ class GraniteHybridMamba(nn.Module):
         # on the fastest heads (-570 to -670 a chunk of 256 as seeded)
         count(self, "ssd_chunk_log_decay_min", jnp.min(
             chunk_decay(dt, a_neg, c.mamba_chunk_size)))
-        with jax.named_scope("mamba_gated_norm"):
+        with scopes.layer("mamba_gated_norm"):
             gated = y.reshape(bsz, s, di).astype(jnp.float32) \
                 * jax.nn.silu(z.astype(jnp.float32))
             gated = RMSNorm(c.rms_norm_eps, name="norm")(gated)
-        with jax.named_scope("mamba_out_proj"):
+        with scopes.layer("mamba_out_proj"):
             return dense(c.hidden_size, self.dtype, "out_proj")(
                 gated.astype(self.dtype))
 
@@ -195,7 +196,7 @@ class GraniteHybridAttention(nn.Module):
             t = dense(n * hd, self.dtype, name)(u)
             return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
 
-        with jax.named_scope("nope_attention"):
+        with scopes.layer("nope_attention"):
             # the attention functions scale by 1 / sqrt(d)
             q = heads("q_proj", h) * (c.attention_multiplier * math.sqrt(hd))
             k, v = (jnp.repeat(heads(name, hkv), h // hkv, axis=1)
@@ -260,14 +261,15 @@ class GraniteHybridForCausalLM(nn.Module):
         emb = self.param("embed_tokens", lambda k, s: {
             "embedding": nn.initializers.normal(0.02)(k, s)},
             (c.vocab_size, c.hidden_size))["embedding"]
-        x = (jnp.take(emb, ids, axis=0)
-             * c.embedding_multiplier).astype(self.dtype)
+        with scopes.layer("embed_tokens"):
+            x = (jnp.take(emb, ids, axis=0)
+                 * c.embedding_multiplier).astype(self.dtype)
         layer = nn.remat(GraniteHybridDecoderLayer,
                          policy=SAVE_KERNEL_RESIDUALS)
         for i, l in enumerate(c.layers):
             x = layer(c, l, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
         x = RMSNorm(c.rms_norm_eps, name="final_layernorm")(x)
-        with jax.named_scope("lm_head_loss"):
+        with scopes.layer("lm_head_loss"):
             return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
                               preferred_element_type=jnp.float32) \
                 / c.logits_scaling

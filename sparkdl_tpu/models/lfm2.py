@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..ops import SAVE_KERNEL_RESIDUALS
 from ..parallel.moe import RoutedExperts
+from ..utils import scopes
 from .llama import RMSNorm
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -121,7 +122,7 @@ class Lfm2ShortConv(nn.Module):
     @nn.compact
     def __call__(self, u):
         d, taps = self.cfg.hidden_size, self.cfg.conv_L_cache
-        with jax.named_scope("short_conv"):
+        with scopes.layer("short_conv"):
             b, c, z = jnp.split(_dense(3 * d, self.dtype, "in_proj")(u), 3,
                                 axis=-1)
             kernel = self.param("conv_kernel", nn.initializers.normal(0.02),
@@ -217,12 +218,13 @@ class Lfm2ForCausalLM(nn.Module):
         emb = self.param("embed_tokens", lambda k, s: {
             "embedding": nn.initializers.normal(0.02)(k, s)},
             (c.vocab_size, c.hidden_size))["embedding"]
-        x = jnp.take(emb, ids, axis=0).astype(self.dtype)
+        with scopes.layer("embed_tokens"):
+            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
         layer = nn.remat(Lfm2DecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
         for i in range(len(c.layer_types)):
             x = layer(c, i, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
         x = RMSNorm(c.norm_eps, name="embedding_norm")(x)
-        with jax.named_scope("lm_head_loss"):
+        with scopes.layer("lm_head_loss"):
             return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
                               preferred_element_type=jnp.float32)
 

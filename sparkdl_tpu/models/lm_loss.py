@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..utils import scopes
+
 
 def causal_lm_loss_fn():
     """Next-token loss for RunnerContext.fit: batch = {input_ids} (labels =
@@ -21,7 +23,7 @@ def causal_lm_loss_fn():
         ids = batch["input_ids"]
         out = apply_fn(params, ids)
         logits, counters = out if isinstance(out, tuple) else (out, {})
-        with jax.named_scope("lm_head_loss"):
+        with scopes.layer("lm_head_loss"):
             logits = logits[:, :-1].astype(jnp.float32)
             loss = optax.softmax_cross_entropy_with_integer_labels(
                 logits, ids[:, 1:]).mean()

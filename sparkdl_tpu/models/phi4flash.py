@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import SAVE_KERNEL_RESIDUALS
+from ..utils import scopes
 from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
                             dt_bias_init)
 from .lm_loss import folded_counters
@@ -145,10 +146,10 @@ class Phi4FlashMamba(nn.Module):
         from ..ops.selective_scan import selective_scan
         c = self.cfg
         di, n, taps, s = c.d_inner, c.mamba_d_state, c.mamba_d_conv, x.shape[1]
-        with jax.named_scope("mamba_in_proj"):
+        with scopes.layer("mamba_in_proj"):
             u, z = jnp.split(dense(2 * di, self.dtype, "in_proj")(x), 2,
                              axis=-1)
-        with jax.named_scope("mamba_conv"):
+        with scopes.layer("mamba_conv"):
             bound = 1.0 / math.sqrt(taps)
             kernel = self.param(
                 "conv_kernel", lambda k, shp: jax.random.uniform(
@@ -171,7 +172,7 @@ class Phi4FlashMamba(nn.Module):
         y, last = selective_scan(u, dt, -jnp.exp(a_log), b_t, c_t, skip)
         count(self, "ssm_state_absmax", jnp.max(jnp.abs(last)))
         count(self, "ssm_dt_mean", jnp.mean(dt))
-        with jax.named_scope("mamba_out_proj"):
+        with scopes.layer("mamba_out_proj"):
             out = dense(c.hidden_size, self.dtype, "out_proj")(
                 y * jax.nn.silu(z))
         return out, y
@@ -202,7 +203,7 @@ class Phi4FlashAttention(nn.Module):
             return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
 
         scope = "cross_attention" if kind == CROSS else "diff_attention"
-        with jax.named_scope(scope):
+        with scopes.layer(scope):
             if kind == CROSS:
                 q = heads(dense(h * hd, self.dtype, "Wq")(x), h)
                 k, v = kv
@@ -246,7 +247,7 @@ class Phi4FlashGatedMemory(nn.Module):
 
     @nn.compact
     def __call__(self, x, m):
-        with jax.named_scope("gated_memory"):
+        with scopes.layer("gated_memory"):
             gate = jax.nn.silu(dense(self.cfg.d_inner, self.dtype,
                                       "in_proj")(x))
             return dense(self.cfg.hidden_size, self.dtype, "out_proj")(
@@ -317,7 +318,8 @@ class Phi4FlashForCausalLM(nn.Module):
         emb = self.param("embed_tokens", lambda k, s: {
             "embedding": nn.initializers.normal(0.02)(k, s)},
             (c.vocab_size, c.hidden_size))["embedding"]
-        x = jnp.take(emb, ids, axis=0).astype(self.dtype)
+        with scopes.layer("embed_tokens"):
+            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
         layer = nn.remat(Phi4FlashDecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
         handed = {}
         for i, l in enumerate(c.layers):
@@ -329,7 +331,7 @@ class Phi4FlashForCausalLM(nn.Module):
                 handed[kind] = out
         x = nn.LayerNorm(epsilon=c.layer_norm_eps, dtype=self.dtype,
                          name="final_layernorm")(x)
-        with jax.named_scope("lm_head_loss"):
+        with scopes.layer("lm_head_loss"):
             return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
                               preferred_element_type=jnp.float32)
 

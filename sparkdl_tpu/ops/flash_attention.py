@@ -67,6 +67,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from ..utils import scopes
+
 NEG_INF = -1e30
 
 
@@ -393,7 +395,7 @@ def _fwd(q, k, v, kv_mask, causal: bool, block_q: int, block_k: int,
         interpret=interpret,
         name="flash_attention_fwd",
     )
-    with jax.named_scope("flash_attention_fwd"):
+    with scopes.layer("flash_attention_fwd"):
         o3, lse2 = call(q3, k3, v3, m4)
     return (o3[:, :s].reshape(b, h, s, dv),
             lse2.reshape(b * h, s_pad)[:, :s].reshape(b, h, s))
@@ -595,7 +597,7 @@ def _flash_fwd(q, k, v, kv_mask, causal, block_q, block_k, interpret,
 
 def _flash_bwd(causal, block_q, block_k, interpret, window, res, do):
     q, k, v, kv_mask, o, lse = res
-    with jax.named_scope("flash_attention_bwd"):
+    with scopes.layer("flash_attention_bwd"):
         dq, dk, dv = _bwd(q, k, v, kv_mask, o, lse, do, causal, block_q,
                           block_k, interpret, window)
     return dq, dk, dv, jnp.zeros_like(kv_mask)

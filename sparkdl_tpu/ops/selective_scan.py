@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from ..utils import scopes
+
 _LANES = 128
 DEFAULT_CHUNK = 128
 DEFAULT_BLOCK_C = 1024
@@ -240,7 +242,7 @@ def _fwd(u, dt, A, B, C, D, chunk: int, block_c: int, interpret: bool):
         scratch_shapes=[f32((n, block_c)), f32((chunk, block_c)),
                         f32((chunk, block_c)), f32((chunk, block_c))],
         name="selective_scan_fwd", **_params(interpret))
-    with jax.named_scope("selective_scan_fwd"):
+    with scopes.layer("selective_scan_fwd"):
         y, starts, last = call(*ops)
     return y[:, :s, :c], starts, last
 
@@ -283,7 +285,7 @@ def _bwd(u, dt, A, B, C, D, starts, dy, chunk: int, block_c: int,
         scratch_shapes=[f32((n, block_c)), f32((chunk + 1, n, block_c))]
         + [f32((chunk, block_c))] * 5,
         name="selective_scan_bwd", **_params(interpret))
-    with jax.named_scope("selective_scan_bwd"):
+    with scopes.layer("selective_scan_bwd"):
         du, ddt, dbt, dct, da, dd = call(*ops, dy, starts)
     return (du[:, :s, :c], ddt[:, :s, :c],
             da.sum(0)[:, :c].T.astype(A.dtype),

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from ..utils import scopes
 from .flash_attention import _LANES, _NT, _TN, _as_column, _resolve
 
 DEFAULT_CHUNK = 256
@@ -306,7 +307,7 @@ def _fwd(x, dt, A, B, C, D, chunk: int, block_h: int, interpret: bool):
                    jax.ShapeDtypeStruct((bsz, h * p, n), _F32)],
         scratch_shapes=[pltpu.VMEM((wide, n), _F32)],
         name="ssd_scan_fwd", **_params(interpret))
-    with jax.named_scope("ssd_scan_fwd"):
+    with scopes.layer("ssd_scan_fwd"):
         y, starts, last = call(*ops)
     return y[:, :s].reshape(bsz, s, h, p), starts, last
 
@@ -347,7 +348,7 @@ def _bwd(x, dt, A, B, C, D, starts, dy, chunk: int, block_h: int,
                    jax.ShapeDtypeStruct((bsz, 1, h * p), _F32)],
         scratch_shapes=[pltpu.VMEM((wide, n), _F32)],
         name="ssd_scan_bwd", **_params(interpret))
-    with jax.named_scope("ssd_scan_bwd"):
+    with scopes.layer("ssd_scan_bwd"):
         dx, drow, db, dc, dd = call(*ops, dy, starts)
         # d s goes back to dt and A through the running sum's own transpose
         by_head = drow.reshape(bsz, n_h, 2, hb, s_pad).transpose(

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..utils import scopes
+
 
 class _ExpertFFN(nn.Module):
     d_ff: int
@@ -201,7 +203,7 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
     """
     n, k = idx.shape
     held = w1.shape[0]
-    with jax.named_scope("moe_dispatch"):
+    with scopes.layer("moe_dispatch"):
         flat = idx.T.reshape(k * n) - first_held        # pick j, token t: j*n+t
         is_held = (flat >= 0) & (flat < held)
         local = jnp.where(is_held, flat, held)          # absent sort last
@@ -212,13 +214,13 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         n_held = jnp.sum(group_sizes)
         held_kn = is_held.reshape(k, n)
         rows = _gather_tokens(h, order % n, inv_order, held_kn)
-    with jax.named_scope("moe_experts"):
+    with scopes.layer("moe_experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                                 preferred_element_type=h.dtype)
         a = dot(rows, w1.astype(h.dtype))
         b = dot(rows, w3.astype(h.dtype))
         y = dot(jax.nn.silu(a) * b, w2.astype(h.dtype))
-    with jax.named_scope("moe_combine"):
+    with scopes.layer("moe_combine"):
         y = _permute_rows(y, inv_order, order).reshape(k, n, -1)
         out = jnp.einsum(
             "knd,kn->nd",
@@ -272,7 +274,7 @@ class RoutedExperts(nn.Module):
                 ("w1", "w3", "w2"), jax.random.split(k, 3),
                 ((held, d, self.d_ff), (held, d, self.d_ff),
                  (held, self.d_ff, d)))}, None)
-        with jax.named_scope("moe_router"):
+        with scopes.layer("moe_router"):
             idx, w = sigmoid_topk_route(
                 xf, w_router, bias, self.top_k,
                 norm_topk_prob=self.norm_topk_prob,

@@ -23,7 +23,11 @@ utilization breakdown:
 Beside the host's spans, :func:`device_time_by_scope` reads a profile's
 device plane: seconds per ``jax.named_scope`` / flax module prefix and per
 phase (forward, backward, ``optimizer_update``), so that ``fusion.106`` has
-a name. ``python -m sparkdl_tpu.runner.analysis --profile DIR`` prints it.
+a name. ``python -m sparkdl_tpu.runner.analysis --profile DIR`` prints it,
+and with ``--named`` the seconds under each of the program's own scopes
+(``utils.scopes``). :func:`step_program_scopes` gives the same names to a
+trace that lost them: the scope of every operation of the step program the
+newest ``fit`` ran, by the operation's name.
 
 Attribution names the **dominant stage** (highest busy fraction) and the
 Amdahl-style projection: with the dominant stage wall-busy fraction f,
@@ -39,12 +43,15 @@ import glob
 import json
 import os
 import re
+import time
 from typing import Iterable
 
 __all__ = ["intervals_from_events", "read_span_stream", "load_event_dir",
            "union_seconds", "analyze", "utilization_from_events",
            "format_report", "request_summary", "format_request_summary",
-           "scope_seconds", "device_time_by_scope", "format_scope_report"]
+           "scope_seconds", "device_time_by_scope", "format_scope_report",
+           "named_scope_of", "hlo_scopes", "note_step_program",
+           "step_program_scopes"]
 
 _EVENT_FILE_RE = re.compile(r"events_rank(\d+)\.jsonl$")
 # Span names that are not pipeline *stages*: whole-run envelopes whose
@@ -427,6 +434,8 @@ _SCOPE_STAT = "tf_op"
 _PHASES = ("forward", "backward", "optimizer_update", "grad_allreduce",
            "unscoped")
 _WRAPPER = re.compile(r"^(?:jit|pjit|shard_map|checkpoint|remat)\b")
+UNSCOPED = "(unscoped)"      # by_name: an operation under no scope at all
+MODULE_ONLY = "(module)"     # by_name: under flax module names alone
 
 
 def _varint(buf, i: int):
@@ -530,6 +539,41 @@ def _scope_parts(scope: str) -> list:
     return parts[:-1]
 
 
+def _unwrapped(part: str) -> str:
+    """``transpose(jvp(lm_head_loss))`` -> ``lm_head_loss``: a transform is
+    written around the first scope opened under it, whatever that scope is."""
+    while part.endswith(")") and "(" in part:
+        part = part[part.index("(") + 1:-1]
+    return part
+
+
+def _innermost(parts: list, names) -> str:
+    for part in reversed(parts):
+        part = _unwrapped(part)
+        if part in names:
+            return part
+    return ""
+
+
+def named_scope_of(op_name: str, names) -> str:
+    """The innermost path component of an operation's ``op_name`` / ``tf_op``
+    that is one of ``names`` (``utils.scopes.names()``), "" if none is. The
+    jit wrappers in front and the primitive at the end are no scopes, and a
+    ``jvp(...)`` / ``transpose(...)`` around a component is looked through:
+    ``jit(step)/transpose(jvp(M))/layers_1/checkpoint/mamba_conv/mul`` is
+    ``mamba_conv``, and so is ``jit(step)/jvp(mamba_conv)/mul``."""
+    return _innermost(_scope_parts(op_name), names)
+
+
+def _name_key(parts: list, names) -> str:
+    """``by_name``'s key: the innermost registered name; ``(module)`` where
+    the path holds none (flax module names alone); ``(unscoped)`` where it
+    holds no scope at all once the wrappers are gone."""
+    if all(_WRAPPER.match(p) for p in parts):
+        return UNSCOPED
+    return _innermost(parts, names) or MODULE_ONLY
+
+
 def _phase(parts: list) -> str:
     for name in ("optimizer_update", "grad_allreduce"):
         if name in parts:
@@ -541,17 +585,22 @@ def _phase(parts: list) -> str:
     return "unscoped"
 
 
-def scope_seconds(triples: Iterable[tuple], depth: int = 2) -> dict:
+def scope_seconds(triples: Iterable[tuple], depth: int = 2,
+                  names=None) -> dict:
     """Device seconds by scope prefix and by phase, from ``(scope, start_ns,
     dur_ns)`` triples of ONE device line. An operation that encloses others
     (a loop and its body) counts its self time only, so the sums never pass
-    the line's busy time. A pure function of its triples.
+    the line's busy time. A pure function of its arguments.
 
     Returns ``{"total_s", "by_scope": {prefix: s}, "by_phase": {phase: s}}``
     with prefixes cut to ``depth`` parts (jit wrappers and the primitive's
     own name dropped) and phases forward (``jvp(...)``), backward
     (``transpose(jvp(...))``), ``optimizer_update``, ``grad_allreduce`` and
-    unscoped."""
+    unscoped. With ``names`` (``utils.scopes.names()``) also ``"by_name":
+    {name: s}``: each operation under the innermost of those names on its
+    path (:func:`named_scope_of`; forward, recomputation and backward
+    together), ``"(module)"`` where the path holds flax module names alone
+    and ``"(unscoped)"`` where it holds nothing."""
     evs = sorted(((s, s + d, sc) for sc, s, d in triples),
                  key=lambda e: (e[0], -e[1]))
     self_ns = [e[1] - e[0] for e in evs]
@@ -564,20 +613,28 @@ def scope_seconds(triples: Iterable[tuple], depth: int = 2) -> dict:
         stack.append(i)
     by_scope: dict = {}
     by_phase = dict.fromkeys(_PHASES, 0.0)
+    by_name: dict = {}
     for (_, _, scope), ns in zip(evs, self_ns):
         parts = _scope_parts(scope)
-        key = "/".join(parts[:depth]) or "(unscoped)"
+        key = "/".join(parts[:depth]) or UNSCOPED
         by_scope[key] = by_scope.get(key, 0.0) + ns / 1e9
         by_phase[_phase(parts)] += ns / 1e9
-    return {"total_s": sum(self_ns) / 1e9, "by_scope": by_scope,
-            "by_phase": by_phase}
+        if names is not None:
+            key = _name_key(parts, names)
+            by_name[key] = by_name.get(key, 0.0) + ns / 1e9
+    out = {"total_s": sum(self_ns) / 1e9, "by_scope": by_scope,
+           "by_phase": by_phase}
+    if names is not None:
+        out["by_name"] = by_name
+    return out
 
 
-def device_time_by_scope(profile_dir: str, depth: int = 2) -> dict:
+def device_time_by_scope(profile_dir: str, depth: int = 2,
+                         names=None) -> dict:
     """:func:`scope_seconds` of the newest ``.xplane.pb`` under
     ``profile_dir`` (what ``fit(profile_dir=...)`` or ``runner.trace``
     wrote), averaged over the device planes that have an ``XLA Ops`` line.
-    Adds ``"planes"`` and ``"path"``."""
+    Adds ``"planes"`` and ``"path"``; with ``names``, ``"by_name"`` too."""
     hits = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
                             recursive=True), key=os.path.getmtime)
     if not hits:
@@ -586,17 +643,18 @@ def device_time_by_scope(profile_dir: str, depth: int = 2) -> dict:
     if not planes:
         raise ValueError(f"{hits[-1]} has no {_OPS_LINE!r} line: the profile "
                          "holds no device operations")
-    reps = [scope_seconds(((sc, s, d) for _, sc, s, d in ops), depth)
+    reps = [scope_seconds(((sc, s, d) for _, sc, s, d in ops), depth, names)
             for ops in planes.values()]
     n = len(reps)
     out = {"total_s": sum(r["total_s"] for r in reps) / n,
            "by_scope": {}, "by_phase": dict.fromkeys(_PHASES, 0.0),
            "planes": sorted(planes), "path": hits[-1]}
+    if names is not None:
+        out["by_name"] = {}
     for r in reps:
-        for k, v in r["by_scope"].items():
-            out["by_scope"][k] = out["by_scope"].get(k, 0.0) + v / n
-        for k, v in r["by_phase"].items():
-            out["by_phase"][k] += v / n
+        for table in ("by_scope", "by_phase", "by_name"):
+            for k, v in r.get(table, {}).items():
+                out[table][k] = out[table].get(k, 0.0) + v / n
     return out
 
 
@@ -605,16 +663,107 @@ def format_scope_report(rep: dict, top: int = 20) -> str:
     lines = [f"device seconds by scope ({rep.get('path', '')}; "
              f"{len(rep.get('planes', []))} device plane(s), "
              f"{rep['total_s']:.4f} s busy per plane)"]
-    for k, v in sorted(rep["by_scope"].items(), key=lambda kv: -kv[1])[:top]:
+
+    def row(k, v):
         lines.append(f"  {100 * v / total:6.2f}%  {v:9.5f} s  {k}")
+
+    for k, v in sorted(rep["by_scope"].items(), key=lambda kv: -kv[1])[:top]:
+        row(k, v)
     lines.append("by phase:")
     for k in _PHASES:
-        v = rep["by_phase"][k]
-        lines.append(f"  {100 * v / total:6.2f}%  {v:9.5f} s  {k}")
+        row(k, rep["by_phase"][k])
+    if "by_name" in rep:
+        lines.append("by the program's own scopes (utils.scopes), forward, "
+                     "recomputation and backward together:")
+        for k, v in sorted(rep["by_name"].items(), key=lambda kv: -kv[1]):
+            row(k, v)
     return "\n".join(lines)
 
 
-if __name__ == "__main__":
+# -- the step program's own table of scopes (ISSUE 38) -------------------------
+# A trace read without its metadata (``jax.profiler.ProfileData``: the
+# benchmark's reader) names an operation ``fusion.106`` and nothing more. The
+# compiled program knows better: every instruction of its optimised HLO carries
+# the ``op_name`` it was traced under. ``fit`` leaves behind what it takes to
+# print that program again; the table is built only for who asks.
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_STEP_PROGRAM: dict | None = None   # the newest fit's; see note_step_program
+
+
+def hlo_scopes(text: str) -> dict:
+    """``{instruction name: op_name}`` for every instruction of every
+    computation of an optimised HLO module's text (``compiled.as_text()``):
+    ``fusion.106``, ``while.3``, ``flash_attention_fwd.1``, a loop body's
+    and a fusion's own instructions alike (names are unique across a
+    module). "" for an instruction the compiler made without metadata."""
+    table = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            scope = _OP_NAME.search(line, m.end())
+            table[m.group(1)] = scope.group(1) if scope else ""
+    return table
+
+
+def note_step_program(make_step, *args) -> None:
+    """Keep what names the step: ``make_step()`` gives the step function
+    ``fit`` dispatches, ``args`` are its arguments, kept as
+    ``ShapeDtypeStruct``s with their shardings. One ``tree_map`` over the
+    arguments, no device work, no trace; it replaces what an earlier ``fit``
+    left. The step function itself is NOT kept: while it lives its
+    executable stays loaded, and on a TPU the program's scratch with it
+    (3.5 GB reserved in the LFM2 cell: my chip run, PR 38), which the next
+    thing the process runs may need."""
+    global _STEP_PROGRAM
+    import jax
+    try:
+        avals = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), args)
+    except AttributeError:      # a leaf that is no device array
+        avals = None
+    _STEP_PROGRAM = {"make_step": make_step, "avals": avals, "table": None,
+                     "build_s": None}
+
+
+def step_program_scopes() -> dict | None:
+    """:func:`hlo_scopes` of the step program the newest ``fit`` of this
+    process ran: the step function made again, traced and lowered for the
+    noted arguments and compiled, then read as text; the second executable
+    is dropped at once. The persistent compile cache answers the compile
+    where the module is the one ``fit`` compiled, byte for byte. It is that
+    but for the Pallas kernels' serialized bodies, which hold the Python
+    call stack of the trace as debug locations: where those reach up to
+    ``fit``'s own frames (the scan kernels) the first build in a cache
+    directory compiles once more, the same program under another key, and
+    every later build there hits (my chip runs, PR 38: PERF.md). Built on
+    the first call and kept (:func:`step_program_build_s` says what that
+    cost); None, never part of a table, where no ``fit`` has run or its step
+    is not a jit function."""
+    slot = _STEP_PROGRAM
+    if slot is None:
+        return None
+    if slot["table"] is None and slot["avals"] is not None:
+        t0 = time.perf_counter()
+        step_fn = slot["make_step"]()
+        if hasattr(step_fn, "lower"):
+            text = step_fn.lower(*slot["avals"]).compile().as_text()
+            slot["table"] = hlo_scopes(text)
+            slot["build_s"] = time.perf_counter() - t0
+        # the recipe has served: let go of what it closes over
+        slot["make_step"] = slot["avals"] = None
+    return slot["table"]
+
+
+def step_program_build_s() -> float | None:
+    """Seconds the first :func:`step_program_scopes` call took (lowering,
+    the compile or its cache load, the text, the parse); None before it."""
+    return _STEP_PROGRAM["build_s"] if _STEP_PROGRAM else None
+
+
+def main(argv=None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         description="Device time by named scope, from a jax profile")
@@ -623,7 +772,26 @@ if __name__ == "__main__":
     ap.add_argument("--depth", type=int, default=2)
     ap.add_argument("--top", type=int, default=20)
     ap.add_argument("--json", action="store_true")
-    args = ap.parse_args()
-    report = device_time_by_scope(args.profile, args.depth)
+    ap.add_argument("--named", action="store_true",
+                    help="also by the program's own scopes: those this "
+                    "process has opened (utils.scopes.names()), or --scopes")
+    ap.add_argument("--scopes", default="",
+                    help="a,b,c: the scope names, where the profile is read "
+                    "in another process than the one that traced the program")
+    args = ap.parse_args(argv)
+    names = None
+    if args.named or args.scopes:
+        from ..utils import scopes
+        names = frozenset(filter(None, args.scopes.split(","))) \
+            or scopes.names()
+        if not names:
+            ap.error("--named: this process has opened no scope; name them "
+                     "with --scopes a,b,c")
+    report = device_time_by_scope(args.profile, args.depth, names)
     print(json.dumps(report) if args.json
           else format_scope_report(report, args.top))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

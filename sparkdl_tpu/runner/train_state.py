@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..utils import scopes
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -62,7 +64,7 @@ class TrainState:
     def apply_gradients(self, grads: Any) -> "TrainState":
         # flax names the model's operations in the profile; the step's own
         # work gets its scope here, once for every step variant
-        with jax.named_scope("optimizer_update"):
+        with scopes.layer("optimizer_update"):
             updates, new_opt = self.tx.update(grads, self.opt_state,
                                               self.params)
             params = optax.apply_updates(self.params, updates)
@@ -321,7 +323,7 @@ def make_shard_map_step(loss_fn: Callable, mesh: Mesh,
                 loss_wrapped = jax.checkpoint(loss_wrapped)
             (loss, (aux, new_ms)), grads = jax.value_and_grad(
                 loss_wrapped, has_aux=True)(state.params)
-            with jax.named_scope("grad_allreduce"):
+            with scopes.layer("grad_allreduce"):
                 new_ms = jax.lax.pmean(new_ms, axis_name=data_axis)
         else:
             def loss_wrapped(params):
@@ -334,7 +336,7 @@ def make_shard_map_step(loss_fn: Callable, mesh: Mesh,
                 loss_wrapped, has_aux=True)(state.params)
             new_ms = None
         # THE collective: gradient mean over the data axis (ICI ring).
-        with jax.named_scope("grad_allreduce"):
+        with scopes.layer("grad_allreduce"):
             grads = jax.lax.pmean(grads, axis_name=data_axis)
             loss = jax.lax.pmean(loss, axis_name=data_axis)
             aux = jax.lax.pmean(aux, axis_name=data_axis)

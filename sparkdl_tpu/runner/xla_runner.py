@@ -21,6 +21,7 @@ DCN carries the cross-host legs of the collectives, ICI the intra-slice legs.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
@@ -32,6 +33,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import runtime
+from . import analysis as analysis_lib
 from . import chaos
 from . import data as data_lib
 from . import events
@@ -307,10 +309,11 @@ class RunnerContext:
         # addressable on every mesh device.
         state = self.put_replicated(state)
 
-        step_fn = self.make_train_step(
-            loss_fn, explicit_collectives=explicit_collectives,
-            mutable=mutable, with_rng=with_rng, remat=remat,
-            accum_steps=accum_steps)
+        make_step = functools.partial(
+            self.make_train_step, loss_fn,
+            explicit_collectives=explicit_collectives, mutable=mutable,
+            with_rng=with_rng, remat=remat, accum_steps=accum_steps)
+        step_fn = make_step()
         meter = self.meter()
         meter.flops_per_step = flops_per_step
         estimate_flops = (flops_per_step is None
@@ -496,6 +499,14 @@ class RunnerContext:
                     # in microseconds) — record it as the compile cost.
                     events.event("compile", step=i,
                                  dur_s=round(sp.seconds, 6))
+                    # What names the step's operations for whoever asks
+                    # afterwards (analysis.step_program_scopes): how the step
+                    # function is made, and the shapes and shardings every
+                    # later step is dispatched with (the state this call
+                    # returned). Not `step_fn` itself: the function keeps its
+                    # executable loaded, and with it the program's scratch
+                    # reserved on the chip, for as long as it lives.
+                    analysis_lib.note_step_program(make_step, state, sharded)
                 # Liveness beacon for the gang supervisor's hang watchdog
                 # (no-op unless SPARKDL_HEARTBEAT_DIR is set). AFTER the
                 # step call, not before it: a rank becomes watchdog-
