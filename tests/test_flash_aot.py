@@ -409,3 +409,45 @@ def test_the_routed_layer_moves_each_held_row_once(one_chip):
                if op not in ("custom-call", "bitcast", "reshape")]
     assert "broadcast" not in written, written
     assert len(written) <= 8, written
+
+
+# -- the loss around the tied head (PR 39) -------------------------------------
+
+def test_the_head_and_loss_gradient_lays_the_logits_out_once(one_chip):
+    """The tied head's product and ``causal_lm_loss_fn`` over it, with the
+    gradient, at the Phi cell's shape (8,192 positions, 2,560 wide, a
+    vocabulary of 25,008 = 195 x 128 + 48): the program keeps two blocks of
+    the logits' size, the float32 logits (819 MB) and their cotangent
+    ``(softmax - [v == label]) / N``, which one fusion writes in bf16 for
+    the two backward products (410 MB). With a label picked by
+    ``take_along_axis`` the transpose was a scatter, for which the compiler
+    flattened the gradient to ``f32[204840528]`` and laid it back in two
+    ``while`` loops with a zero fill each, beside a slice's copy to 8,191
+    rows (2.46 GB of temporaries). Structure, not time: nothing runs."""
+    from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
+    s, d, v = 8192, 2560, 25008
+
+    def loss(x, emb, ids):
+        def head(_, got):
+            return jnp.einsum("bsd,vd->bsv", x, emb.astype(x.dtype),
+                              preferred_element_type=jnp.float32)
+        return causal_lm_loss_fn()(None, head, {"input_ids": ids})[0]
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((v, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    for op in ("while", "scatter", "gather", "dynamic-update-slice",
+               "dynamic-slice"):
+        assert not re.search(r" %s\(" % op, text), op
+    assert str(s * v) not in text and str((s - 1) * v) not in text
+    assert not re.search(r"\[(1,)?%d,%d\]|\[(1,)?%d,%d\]"
+                         % (s - 1, v, v, s - 1), text)
+    logits_sized = [(op, dtype, dims) for op, dtype, dims
+                    in _entry_results(text) if math.prod(dims) >= s * v
+                    and op not in ("get-tuple-element", "bitcast")]
+    assert sorted((dtype, math.prod(dims), op)
+                  for op, dtype, dims in logits_sized) == [
+        ("bf16", s * v, "fusion"), ("f32", s * v, "fusion")], logits_sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * 6 * s * v
