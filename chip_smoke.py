@@ -70,6 +70,14 @@ SCAN_TOL = 2.0 ** -7
 # mask, chunk edge or carried state is O(1).
 SSD_TOL = 2.0 ** -6
 
+# The gated delta rule's kernel pair against its plain recurrence, as a share
+# of the reference's largest entry. Its sums over positions are MXU products
+# too, and a chunk's ``V'`` passes the bf16 triangular inverse and two more
+# products before the state takes it in: more roundings stacked than the
+# chunked scan's, over states that live for thousands of positions. 2^-5; a
+# wrong mask, chunk edge or carried state is O(1).
+GATED_DELTA_TOL = 2.0 ** -5
+
 
 class CompileClock:
     """Seconds XLA spent compiling, from jax's own monitoring events."""
@@ -275,7 +283,7 @@ def _cache_ref(q, k_all, v_all, qpos, pads):
 def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
                           grad_seqs=(2048, 8192), grad_heads=64,
                           grad_head_dim=64, dense_heads=2,
-                          grad_window=512) -> dict:
+                          grad_window=512, grad_wide=(16, 256)) -> dict:
     """Causal prefill at S=seq, and the gradient through the backward
     kernel pair at the training cell's head shape (``grad_heads`` heads of
     ``grad_head_dim``, bf16) at each of ``grad_seqs``. No default engine
@@ -291,7 +299,9 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
     ratio of the 2-norms. At the longest of ``grad_seqs`` the gradient is
     taken once more under a window of ``grad_window`` keys
     (``flash_attention_grad_S*_w*``), against dense attention under the same
-    band."""
+    band, and once at ``grad_wide`` heads of that width
+    (``flash_attention_grad_S*_d*``: 16 heads of 256, the widest head a cell
+    calls the kernels at)."""
     import jax
     import jax.numpy as jnp
 
@@ -310,11 +320,13 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
             lambda a, b, c, w: (attn(a, b, c).astype(jnp.float32) * w).sum(),
             argnums=(0, 1, 2)))(*qkvw)
 
-    cases = [(s, None) for s in grad_seqs]
+    cases = [(s, None, grad_heads, grad_head_dim) for s in grad_seqs]
     if grad_window:
-        cases.append((max(grad_seqs), grad_window))
-    for s, window in cases:
-        q, k, v, w = (jnp.asarray(rng.randn(1, grad_heads, s, grad_head_dim),
+        cases.append((max(grad_seqs), grad_window, grad_heads, grad_head_dim))
+    if grad_wide:
+        cases.append((max(grad_seqs), None, *grad_wide))
+    for s, window, n_heads, width in cases:
+        q, k, v, w = (jnp.asarray(rng.randn(1, n_heads, s, width),
                                   jnp.bfloat16) for _ in range(4))
         got = grads(lambda a, b, c: flash_attention(
             a, b, c, True, interpret=interpret, window=window), q, k, v, w)
@@ -323,9 +335,10 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
                 a, b, c, True, None, window),
                 *(jnp.asarray(x[:, :dense_heads], jnp.float32)
                   for x in (q, k, v, w)))
-        rec = {"S": s, "heads": grad_heads, "max_err": 0.0}
+        rec = {"S": s, "heads": n_heads, "max_err": 0.0}
         if window:
             rec["window"] = window
+        wide = f"_d{width}" if width != grad_head_dim else ""
         for name, g, r in zip(("dq", "dk", "dv"), got, want):
             assert g.dtype == jnp.bfloat16 and np.isfinite(
                 np.asarray(g, np.float32)).all(), name
@@ -336,7 +349,7 @@ def check_flash_attention(rng, *, interpret, seq, heads, head_dim,
             rec["max_err"] = max(rec["max_err"],
                                  float(np.abs(g - r).max() / np.abs(r).max()))
         out[f"flash_attention_grad_S{s}" + (f"_w{window}" if window
-                                            else "")] = rec
+                                            else wide)] = rec
     return out
 
 
@@ -484,6 +497,76 @@ def check_ssd_scan(rng, *, interpret, seq=8192, heads=64, head_dim=64,
     return {"ssd_scan": rec}
 
 
+def check_gated_delta(rng, *, interpret, seq=8192, key_heads=16,
+                      value_heads=32, head_dim=128, chunk=128,
+                      dense_heads=4) -> dict:
+    """The gated delta rule's kernel pair at the Qwen3-Next cell's shape (one
+    sequence, ``q, k, v`` in bf16, q and k of unit length, ``g`` and ``beta``
+    float32, half-lives of 64 to 8,192 positions): forward, last state and
+    every gradient at all the heads, against the plain position-by-position
+    recurrence in float32 on the first ``dense_heads`` value heads and the key
+    heads that serve them (heads do not mix). ``max_err`` is the largest gap
+    as a share of the reference's largest entry, held to
+    ``GATED_DELTA_TOL``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparkdl_tpu.ops import gated_delta as gd
+    rep = value_heads // key_heads
+
+    def recurrence(v, q, k, g, beta):
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        q, k = (jnp.repeat(t, rep, axis=2) for t in (q, k))
+
+        def step(state, inp):
+            q_t, k_t, v_t, g_t, b_t = inp
+            state = jnp.exp(g_t)[..., None, None] * state
+            delta = b_t[..., None] * (v_t - jnp.sum(
+                state * k_t[..., :, None], axis=-2))
+            state = state + k_t[..., :, None] * delta[..., None, :]
+            return state, jnp.sum(state * q_t[..., :, None], axis=-2)
+
+        last, o = jax.lax.scan(
+            step, jnp.zeros((v.shape[0], v.shape[2], q.shape[3], v.shape[3])),
+            tuple(jnp.swapaxes(t, 0, 1) for t in (q, k, v, g, beta)))
+        return jnp.swapaxes(o, 0, 1) / np.sqrt(q.shape[-1]), last
+
+    def unit(shape):
+        x = rng.randn(*shape)
+        return jnp.asarray(x / np.linalg.norm(x, axis=-1, keepdims=True),
+                           jnp.bfloat16)
+
+    q, k = (unit((1, seq, key_heads, head_dim)) for _ in range(2))
+    v, w = (jnp.asarray(rng.randn(1, seq, value_heads, head_dim),
+                        jnp.bfloat16) for _ in range(2))
+    life = np.exp(rng.uniform(np.log(64.0), np.log(8192.0),
+                              (1, seq, value_heads)))
+    g = jnp.asarray(-np.log(2.0) / life, jnp.float32)
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(1, seq, value_heads),
+                                      jnp.float32))
+    args, nh = (v, q, k, g, beta), dense_heads
+
+    def first(t):
+        """The first ``dense_heads`` value heads of an operand, or the key
+        heads that serve them."""
+        return t[:, :, :nh if t.shape[2] == value_heads else nh // rep]
+
+    def rule(v, q, k, g, beta):
+        return gd.gated_delta_rule(q, k, v, g, beta, chunk=chunk,
+                                   interpret=interpret)
+
+    rec = {"S": seq, "heads": value_heads, **_scan_gaps(
+        lambda *a: rule(*a)[0], lambda *a: recurrence(*a)[0], args, w, first,
+        ("dv", "dq", "dk", "dg", "dbeta"), ())}
+    last, want = jax.jit(rule)(*args)[1], jax.jit(recurrence)(
+        *(first(t) for t in args))[1]
+    rec["last_state_err"] = _max_err(last[:, :nh], want) / float(
+        jnp.abs(want).max())
+    rec["max_err"] = max(rec["max_err"], rec["last_state_err"])
+    assert rec["max_err"] <= GATED_DELTA_TOL, rec
+    return {"gated_delta": rec}
+
+
 def check_flash_decode(rng, *, interpret, slots, heads, kv_heads, head_dim,
                        max_len) -> dict:
     """The un-paged engine's decode step: a per-row ``[B]`` fill vector,
@@ -544,13 +627,13 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                   heads: int = 16, kv_heads: int = 8, head_dim: int = 128,
                   max_len: int = 2048, block_size: int = 16,
                   verify_window: int = 5, kv_dtypes=(None, "int8"),
-                  atol: float = KERNEL_ATOL, scan=None, ssd=None,
+                  atol: float = KERNEL_ATOL, scan=None, ssd=None, delta=None,
                   **flash_grad) -> dict:
     """Each Pallas kernel against the dense reference at the shapes the
     server phase serves (and, for the flash kernel's backward pair, the
     training cell's: ``flash_grad`` overrides ``check_flash_attention``'s
     ``grad_*`` sizes, ``scan`` ``check_selective_scan``'s, ``ssd``
-    ``check_ssd_scan``'s). ``interpret`` is
+    ``check_ssd_scan``'s, ``delta`` ``check_gated_delta``'s). ``interpret`` is
     passed to every call explicitly:
     False compiles through Mosaic, True is the CPU test's interpreter."""
     rng = np.random.RandomState(0)
@@ -562,6 +645,7 @@ def phase_kernels(*, interpret: bool, seq: int = 2048, slots: int = 8,
                                 **flash_grad),
         **check_selective_scan(rng, interpret=interpret, **(scan or {})),
         **check_ssd_scan(rng, interpret=interpret, **(ssd or {})),
+        **check_gated_delta(rng, interpret=interpret, **(delta or {})),
         **check_flash_decode(rng, **shape)}
     for kv in kv_dtypes:
         checks.update(check_paged_flash_decode(

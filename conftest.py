@@ -24,3 +24,18 @@ tiny.TINY.setdefault("granite-4.0-h-micro.sft-s8192-b1", {
                "mamba_chunk_size": 8},
     "traffic": {"per_chip_batch": 4, "warmup_steps": 10,
                 "inputs": {"input_ids": {"shape": [32]}}}})
+
+tiny.TINY.setdefault("qwen3-next-80b-a3b.sft-s8192-b1", {
+    # 256 wide as the Granite cell's, for the same reason; both kinds of
+    # layer twice, 4 of 16 experts held at top 4, a chunk of 8 in 32 positions
+    "config": {"vocab_size": 256, "hidden_size": 256,
+               "num_attention_heads": 4, "num_key_value_heads": 2,
+               "head_dim": 32, "full_attention_interval": 2,
+               "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+               "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+               "moe_intermediate_size": 64,
+               "shared_expert_intermediate_size": 64, "num_experts": 4,
+               "num_routed_experts": 16, "num_experts_per_tok": 4,
+               "gated_delta_chunk": 8},
+    "traffic": {"per_chip_batch": 4, "warmup_steps": 10,
+                "inputs": {"input_ids": {"shape": [32]}}}})

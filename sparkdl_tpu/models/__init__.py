@@ -5,6 +5,7 @@ from .lfm2 import Lfm2Config, Lfm2ForCausalLM
 from .llama import LlamaConfig, LlamaModel, lora_mask, lora_optimizer
 from .lm_loss import causal_lm_loss_fn
 from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 from .pretrained import (CheckpointMismatch, cast_float_leaves,
                          import_hf_bert, import_hf_llama,
                          import_keras_inception, import_keras_resnet,
@@ -25,6 +26,7 @@ __all__ = [
     "LlamaConfig", "LlamaModel", "causal_lm_loss_fn", "lora_mask",
     "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM", "Phi4FlashConfig",
     "Phi4FlashForCausalLM", "GraniteHybridConfig", "GraniteHybridForCausalLM",
+    "Qwen3NextConfig", "Qwen3NextForCausalLM",
     "load_pretrained", "import_hf_llama", "import_hf_bert",
     "import_keras_resnet", "import_keras_vgg", "import_keras_inception",
     "import_keras_xception",

@@ -31,12 +31,12 @@ def dt_bias_init(key, shape):
     return dt0 + jnp.log(-jnp.expm1(-dt0))
 
 
-def decay_mask(params):
+def decay_mask(params, also: tuple = ()):
     """True for the leaves weight decay touches: the matrices and the
-    embedding (``optax.adamw(..., mask=decay_mask)``); none on norms,
-    ``A_log``, ``D``, biases, the convolution's taps, the ``lambda``
-    vectors."""
+    embedding (``optax.adamw(..., mask=decay_mask)``), and the leaves named
+    in ``also`` (a routed model's expert stacks); none on norms, ``A_log``,
+    ``D``, biases, the convolution's taps, the ``lambda`` vectors."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: getattr(path[-1], "key", None) in ("kernel",
-                                                           "embedding"),
+        lambda path, _: getattr(path[-1], "key", None) in (
+            "kernel", "embedding", *also),
         params)

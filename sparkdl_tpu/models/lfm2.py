@@ -95,17 +95,19 @@ class Lfm2Config:
                    layer_types=(CONV, ATTENTION, CONV), num_dense_layers=1)
 
 
-def rope_rotate_half(x, theta: float):
-    """Rotate-half RoPE over all of the head's dims. ``x``: ``[B, H, S, D]``,
+def rope_rotate_half(x, theta: float, rotary_dim: int | None = None):
+    """Rotate-half RoPE over the first ``rotary_dim`` of the head's dims (all
+    of them by default); the rest pass through. ``x``: ``[B, H, S, D]``,
     positions 0..S-1."""
-    d = x.shape[-1]
+    d = rotary_dim or x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] * inv
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    xf = x.astype(jnp.float32)
+    xf = x[..., :d].astype(jnp.float32)
     rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
-    return (xf * cos + rot * sin).astype(x.dtype)
+    return jnp.concatenate([(xf * cos + rot * sin).astype(x.dtype),
+                            x[..., d:]], axis=-1)
 
 
 def _dense(features: int, dtype, name: str):

@@ -4,6 +4,7 @@ import jax
 
 from .flash_attention import RESIDUAL_NAMES as _FLASH
 from .flash_attention import auto_attn_fn, flash_attention, resolve_attn_fn
+from .gated_delta import RESIDUAL_NAMES as _GATED_DELTA
 from .selective_scan import RESIDUAL_NAMES as _SELECTIVE_SCAN
 from .ssd_scan import RESIDUAL_NAMES as _SSD_SCAN
 
@@ -13,7 +14,7 @@ from .ssd_scan import RESIDUAL_NAMES as _SSD_SCAN
 # keeps the residuals of whichever kernels it calls; outside a checkpoint
 # that carries this policy the names are the identity.
 SAVE_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
-    *_FLASH, *_SELECTIVE_SCAN, *_SSD_SCAN)
+    *_FLASH, *_SELECTIVE_SCAN, *_SSD_SCAN, *_GATED_DELTA)
 
 __all__ = ["flash_attention", "auto_attn_fn", "resolve_attn_fn",
            "SAVE_KERNEL_RESIDUALS"]

@@ -65,16 +65,21 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 _F32 = jnp.float32
 
 
+def chunk_cumsum(a, chunk: int):
+    """``[B, S, H]``: the running sum of ``a`` inside each chunk of ``chunk``
+    positions (it starts anew at every chunk), float32."""
+    bsz, s, h = a.shape
+    n_k = pl.cdiv(s, chunk)
+    a = jnp.pad(a.astype(_F32), ((0, 0), (0, n_k * chunk - s), (0, 0)))
+    return jnp.cumsum(a.reshape(bsz, n_k, chunk, h), axis=2).reshape(
+        bsz, n_k * chunk, h)[:, :s]
+
+
 def chunk_decay(dt, A, chunk: int = DEFAULT_CHUNK):
     """``s [B, S, H]``: the running sum of ``dt * A`` inside each chunk of
     ``chunk`` positions, float32. Its entry at a chunk's end is the log of
     what that chunk hands on of a state."""
-    bsz, s, h = dt.shape
-    n_k = pl.cdiv(s, chunk)
-    a = jnp.pad(dt.astype(_F32) * A.astype(_F32),
-                ((0, 0), (0, n_k * chunk - s), (0, 0)))
-    return jnp.cumsum(a.reshape(bsz, n_k, chunk, h), axis=2).reshape(
-        bsz, n_k * chunk, h)[:, :s]
+    return chunk_cumsum(dt.astype(_F32) * A.astype(_F32), chunk)
 
 
 def _as_row(col):

@@ -13,10 +13,10 @@ tokens beyond ``capacity_factor * tokens/experts`` at an expert are dropped
 (pass through the residual). The load-balancing auxiliary loss is sowed
 into the ``intermediates`` collection as ``moe_aux_loss``.
 
-:class:`RoutedExperts` is its successor: top-k over sigmoid scores, no token
-ever dropped, told which experts it holds, grouped matrix products over the
-rows sorted by expert. Its cost goes with the assignments that land on the
-held experts, not with tokens x experts x capacity.
+:class:`RoutedExperts` is its successor: top-k over sigmoid or softmax
+scores, no token ever dropped, told which experts it holds, grouped matrix
+products over the rows sorted by expert. Its cost goes with the assignments
+that land on the held experts, not with tokens x experts x capacity.
 """
 
 from __future__ import annotations
@@ -120,6 +120,22 @@ def sigmoid_topk_route(h, w_router, expert_bias, k: int, *,
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def softmax_topk_route(h, w_router, k: int, *, norm_topk_prob: bool = True,
+                       scaling: float = 1.0):
+    """``(idx [N, k] int32, w [N, k] float32)`` of ``h [N, D]``: softmax
+    scores over every expert in float32 (the product at ``highest``, as
+    :func:`sigmoid_topk_route`'s); the top ``k`` of them; the weights are the
+    scores themselves, over their sum where ``norm_topk_prob``. No bias takes
+    part."""
+    s = jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(s, k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), w * scaling
 
 
@@ -241,10 +257,12 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
 class RoutedExperts(nn.Module):
     """No-drop top-k routed SwiGLU experts: ``(B, T, D) -> (B, T, D)``.
 
-    The router scores all ``num_experts``; this layer holds the experts
-    ``held[0] .. held[0] + held[1] - 1`` (``None``: all of them) and returns
-    their part of the result (:func:`held_experts_ffn`). ``expert_bias``
-    moves the selection only, takes no gradient, and no rule here moves it.
+    The router scores all ``num_experts`` by ``scoring``, sigmoid or softmax
+    scores (:func:`sigmoid_topk_route`, :func:`softmax_topk_route`); this
+    layer holds the experts ``held[0] .. held[0] + held[1] - 1`` (``None``:
+    all of them) and returns their part of the result
+    (:func:`held_experts_ffn`). ``expert_bias`` (sigmoid scores only) moves
+    the selection only, takes no gradient, and no rule here moves it.
     Parameters: ``router/kernel``, ``expert_bias``, and ``experts/{w1,w3,w2}``
     with a leading held-experts axis (``moe_rules`` shards it over ``ep``).
     The layer's counters are summed into the ``counters`` collection.
@@ -257,9 +275,12 @@ class RoutedExperts(nn.Module):
     routed_scaling_factor: float = 1.0
     use_expert_bias: bool = True
     dtype: Any = jnp.float32
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
         b, t, d = x.shape
         first, held = self.held or (0, self.num_experts)
         init = nn.initializers.normal(0.02)
@@ -267,18 +288,21 @@ class RoutedExperts(nn.Module):
         w_router = self.param("router", lambda k, s: {
             "kernel": init(k, s)}, (d, self.num_experts))["kernel"]
         bias = self.param("expert_bias", nn.initializers.zeros,
-                          (self.num_experts,)) if self.use_expert_bias \
-            else None
+                          (self.num_experts,)) \
+            if self.use_expert_bias and self.scoring == "sigmoid" else None
         experts = self.param("experts", lambda k, _: {
             n: init(kk, s) for n, kk, s in zip(
                 ("w1", "w3", "w2"), jax.random.split(k, 3),
                 ((held, d, self.d_ff), (held, d, self.d_ff),
                  (held, self.d_ff, d)))}, None)
         with scopes.layer("moe_router"):
-            idx, w = sigmoid_topk_route(
-                xf, w_router, bias, self.top_k,
-                norm_topk_prob=self.norm_topk_prob,
-                scaling=self.routed_scaling_factor)
+            how = dict(norm_topk_prob=self.norm_topk_prob,
+                       scaling=self.routed_scaling_factor)
+            if self.scoring == "sigmoid":
+                idx, w = sigmoid_topk_route(xf, w_router, bias, self.top_k,
+                                            **how)
+            else:
+                idx, w = softmax_topk_route(xf, w_router, self.top_k, **how)
         out, counters = held_experts_ffn(
             xf, idx, w, experts["w1"], experts["w3"], experts["w2"], first)
         for name, v in counters.items():

@@ -50,9 +50,12 @@ def test_kernel_phase_tiny_interpreted():
         interpret=True, seq=256, slots=3, heads=4, kv_heads=2,
         head_dim=32, max_len=128, block_size=8, verify_window=3,
         grad_seqs=(256,), grad_heads=3, grad_head_dim=32, grad_window=40,
+        grad_wide=(2, 48),
         scan=dict(seq=40, channels=200, states=4, dense_channels=128),
         ssd=dict(seq=40, heads=6, head_dim=8, states=4, chunk=8,
-                 dense_heads=2))
+                 dense_heads=2),
+        delta=dict(seq=40, key_heads=2, value_heads=4, head_dim=8, chunk=8,
+                   dense_heads=2))
     assert rec["interpret"] is True
     assert abs(rec["flash_attention_grad_S256"]["dk_norm_ratio"] - 1) < 1e-2
     assert {"flash_attention", "flash_decode", "paged_flash_decode_bf16_S1",
@@ -67,6 +70,12 @@ def test_kernel_phase_tiny_interpreted():
     assert rec["ssd_scan"]["max_err"] <= chip_smoke.SSD_TOL
     assert {"dx_err", "ddt_err", "dA_err", "dB_err", "dC_err",
             "dD_err"} <= set(rec["ssd_scan"])
+    assert rec["gated_delta"]["max_err"] <= chip_smoke.GATED_DELTA_TOL
+    assert {"dv_err", "dq_err", "dk_err", "dg_err", "dbeta_err",
+            "last_state_err"} <= set(rec["gated_delta"])
+    assert rec["flash_attention_grad_S256_d48"]["heads"] == 2
+    assert abs(rec["flash_attention_grad_S256_d48"]["dv_norm_ratio"] - 1) \
+        < 1e-2
 
 
 def test_the_scan_check_sees_a_scan_that_restarts_its_state(monkeypatch):
@@ -110,6 +119,27 @@ def test_the_chunked_scan_check_sees_a_state_that_is_not_handed_on(
         chip_smoke.check_ssd_scan(
             np.random.RandomState(0), interpret=True, seq=32, heads=2,
             head_dim=8, states=4, chunk=8, dense_heads=2)
+
+
+def test_the_delta_rule_check_sees_a_state_that_is_not_handed_on(monkeypatch):
+    """A kernel pair that starts the second half from nothing is O(1) off, and
+    the check says so."""
+    import jax.numpy as jnp
+    import numpy as np
+    from sparkdl_tpu.ops import gated_delta as gd
+    real = gd.gated_delta_rule
+
+    def restarted(q, k, v, g, beta, **kw):
+        half = q.shape[1] // 2
+        parts = [real(*(t[:, sl] for t in (q, k, v, g, beta)), **kw)
+                 for sl in (slice(0, half), slice(half, None))]
+        return jnp.concatenate([o for o, _ in parts], axis=1), parts[1][1]
+
+    monkeypatch.setattr(gd, "gated_delta_rule", restarted)
+    with pytest.raises(AssertionError):
+        chip_smoke.check_gated_delta(
+            np.random.RandomState(0), interpret=True, seq=32, key_heads=1,
+            value_heads=2, head_dim=8, chunk=8, dense_heads=2)
 
 
 def test_server_phase_tiny():

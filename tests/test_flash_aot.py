@@ -352,6 +352,85 @@ def test_the_chunked_scan_compiles_at_a_ragged_length_in_float32(one_chip):
     assert text.count("tpu_custom_call") == 2
 
 
+# -- a head of 256 and the gated delta rule (PR 40) --------------------------------
+
+def test_the_three_flash_kernels_hold_their_tiles_at_a_head_of_256(one_chip):
+    """One sequence of 8192 positions, 16 heads of 256, bf16, causal -- the
+    Qwen3-Next cell's attention layer, the widest head any cell calls the
+    kernels at (tiles of ``[512, 256]``): forward and the backward pair
+    compile at the default 512-blocks, by name, with no score-sized buffer."""
+    b, h, s, d = 1, 16, 8192, 256
+    compiled = _compiled_grad(one_chip, (b, h, s, d), jnp.bfloat16, True)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert name in text
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < b * h * s * 512, largest
+
+
+def _delta_args(one_chip, s, hk, hv, d, dtype):
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (sd((1, s, hk, d), dtype), sd((1, s, hk, d), dtype),
+            sd((1, s, hv, d), dtype), sd((1, s, hv), jnp.float32),
+            sd((1, s, hv), jnp.float32))
+
+
+def test_the_cells_delta_rule_gradient_hands_its_kernels_no_tile_and_no_state_a_position(
+        one_chip):
+    """One sequence of 8192 positions, 16 key and 32 value heads of 128,
+    ``q, k, v`` in bf16 and ``g, beta`` in float32, chunks of 128 -- the
+    Qwen3-Next cell's linear-attention layer: the preparation's kernel and
+    the recurrence's forward and backward kernels compile at their default
+    head blocks, by name; no operand or result of the recurrence's two is a
+    ``[Q, Q]`` tile a chunk (the preparation writes ``T`` for its transpose,
+    ``S / Q * Hv * Q * Q`` elements) and the largest any of the three touches
+    is as large as the chunk-start states; no buffer of the whole gradient
+    holds a state a position (``S * Hv * Dk * Dv``)."""
+    from sparkdl_tpu.ops.gated_delta import gated_delta_rule
+    s, hk, hv, d, q = 8192, 16, 32, 128, 128
+
+    def loss(*a):
+        return (gated_delta_rule(*a, chunk=q, interpret=False)[0].astype(
+            jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        *_delta_args(one_chip, s, hk, hv, d, jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("gated_delta_fwd_prep", "gated_delta_fwd.", "gated_delta_bwd"):
+        assert name in text
+    states = s // q * hv * d * d
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or " custom-call(" not in line:
+            continue
+        shapes = [tuple(map(int, dims.split(","))) for dims in
+                  re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", line)]
+        assert shapes and max(map(math.prod, shapes)) == states, line[:200]
+        tiles = [x for x in shapes if x[-2:] == (q, q) and len(x) > 3]
+        assert bool(tiles) == ("gated_delta_fwd_prep" in line), line[:200]
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < s * hv * d * d // 64, largest
+
+
+def test_the_delta_rule_compiles_at_a_ragged_length_in_float32(one_chip):
+    """The tests' dtype, a length that is no multiple of the chunk, one value
+    head a key head."""
+    from sparkdl_tpu.ops.gated_delta import gated_delta_rule
+    text = jax.jit(jax.grad(lambda *t: gated_delta_rule(
+        *t, chunk=128, interpret=False)[0].sum(),
+        argnums=tuple(range(5)))).lower(
+            *_delta_args(one_chip, 300, 4, 4, 128, jnp.float32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
 # -- the routed layer's row movement (PR 35) --------------------------------------
 
 def _entry_results(text: str) -> list:

@@ -9,6 +9,7 @@ policy. CPU, kernels interpreted, tiny sizes.
 its own."""
 
 import collections
+import dataclasses
 import functools
 
 import jax
@@ -17,14 +18,15 @@ import numpy as np
 import pytest
 
 from sparkdl_tpu import ops
-from sparkdl_tpu.models import granite_hybrid, lfm2, phi4flash
+from sparkdl_tpu.models import granite_hybrid, lfm2, phi4flash, qwen3_next
 from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
+from sparkdl_tpu.ops import gated_delta as delta_module
 from sparkdl_tpu.ops import selective_scan as selective_module
 from sparkdl_tpu.ops import ssd_scan as ssd_module
 from sparkdl_tpu.ops.flash_attention import flash_attention
 
 FORWARD_KERNELS = ("flash_attention_fwd", "selective_scan_fwd",
-                   "ssd_scan_fwd")
+                   "ssd_scan_fwd", "gated_delta_fwd")
 FLASH = functools.partial(flash_attention, block_q=8, block_k=8,
                           interpret=True)
 
@@ -72,6 +74,12 @@ MODELS = {
                 mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
                 mamba_chunk_size=8), attn_fn=FLASH),
         {"flash_attention_fwd": 1, "ssd_scan_fwd": 3}),
+    # linear 0, 2; full 1, 3. (The preparation's kernel, whose outputs are
+    # not kept, runs again with the rest of the layer: it is no recurrence.)
+    "qwen3_next": (qwen3_next, lambda: qwen3_next.Qwen3NextForCausalLM(
+        dataclasses.replace(qwen3_next.Qwen3NextConfig.tiny(),
+                            num_hidden_layers=4), attn_fn=FLASH),
+        {"flash_attention_fwd": 2, "gated_delta_fwd": 2}),
 }
 
 
@@ -136,9 +144,25 @@ def _ssd_scan_loss():
     return loss, (x, dt, a, b, c, d)
 
 
+def _gated_delta_loss():
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q, k = (jax.random.normal(key, (1, 24, 2, 8)) for key in keys[:2])
+    q, k = (t / jnp.linalg.norm(t, axis=-1, keepdims=True) for t in (q, k))
+    v = jax.random.normal(keys[2], (1, 24, 4, 8))
+    g = -0.05 * jax.nn.softplus(jax.random.normal(keys[3], (1, 24, 4)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 24, 4)))
+
+    def loss(*operands):
+        o, last = delta_module.gated_delta_rule(*operands, chunk=8,
+                                                block_h=2, interpret=True)
+        return jnp.sum(o ** 2) + jnp.max(jnp.abs(last))   # a counter's read
+    return loss, (q, k, v, g, beta)
+
+
 KERNELS = {"flash_attention": _flash_loss,
            "selective_scan": _selective_scan_loss,
-           "ssd_scan": _ssd_scan_loss}
+           "ssd_scan": _ssd_scan_loss,
+           "gated_delta": _gated_delta_loss}
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))

@@ -19,7 +19,7 @@ from sparkdl_tpu.models.lfm2 import (ATTENTION, CONV, Lfm2Attention,
                                      Lfm2ShortConv, trainable_mask)
 from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
 from sparkdl_tpu.parallel.moe import (RoutedExperts, held_experts_ffn,
-                                      sigmoid_topk_route)
+                                      sigmoid_topk_route, softmax_topk_route)
 from sparkdl_tpu.runner import XlaRunner
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -352,6 +352,56 @@ def test_expert_bias_moves_the_selection_and_not_the_weights():
         w1, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
     g = jax.grad(lambda b: sigmoid_topk_route(h, wr, b, 4)[1].sum())(bias)
     assert not np.asarray(g).any()
+
+
+@pytest.mark.parametrize("norm", [True, False], ids=["normalised", "raw"])
+def test_softmax_scores_against_a_plain_top_k(norm):
+    """Softmax over every expert in float32, the largest k of them a token by
+    a plain sort, their scores over their own sum where asked; no bias."""
+    wr = 0.5 * jax.random.normal(jax.random.PRNGKey(12), (32, 24))
+    h = jax.random.normal(jax.random.PRNGKey(13), (64, 32))
+    idx, w = softmax_topk_route(h, wr, 5, norm_topk_prob=norm)
+    p = np.asarray(jax.nn.softmax(h @ wr, axis=-1))
+    order = np.argsort(-p, axis=-1)[:, :5]
+    np.testing.assert_array_equal(np.sort(idx, -1), np.sort(order, -1))
+    picked = np.take_along_axis(p, np.asarray(idx), axis=-1)
+    want = picked / picked.sum(-1, keepdims=True) if norm else picked
+    np.testing.assert_allclose(w, want, rtol=1e-5)
+    assert idx.dtype == jnp.int32 and w.dtype == jnp.float32
+    assert (np.diff(np.asarray(w), axis=-1) <= 0).all()   # largest first
+    _, scaled = softmax_topk_route(h, wr, 5, norm_topk_prob=norm, scaling=2.5)
+    np.testing.assert_allclose(scaled, 2.5 * w, rtol=1e-6)
+
+
+def test_the_scoring_field_leaves_sigmoid_layers_as_they_were():
+    """``scoring="sigmoid"`` is the default: the same parameter tree, the
+    same output bit for bit, as the layer built without the field; softmax
+    has no ``expert_bias`` and other outputs; anything else is refused."""
+    x = jax.random.normal(jax.random.PRNGKey(14), (2, 12, 32))
+    kw = dict(num_experts=8, top_k=3, d_ff=16, held=(2, 4))
+    plain, named = RoutedExperts(**kw), RoutedExperts(**kw, scoring="sigmoid")
+    v = plain.init(jax.random.PRNGKey(0), x)
+    v2 = named.init(jax.random.PRNGKey(0), x)
+    assert jax.tree_util.tree_structure(v) == jax.tree_util.tree_structure(v2)
+    for a, b in zip(jax.tree_util.tree_leaves(v), jax.tree_util.tree_leaves(v2)):
+        np.testing.assert_array_equal(a, b)
+    assert set(v["params"]) == {"router", "expert_bias", "experts"}
+    out = plain.apply(v, x, mutable=["counters"])[0]
+    np.testing.assert_array_equal(out, named.apply(v, x,
+                                                   mutable=["counters"])[0])
+    soft = RoutedExperts(**kw, scoring="softmax")
+    vs = soft.init(jax.random.PRNGKey(0), x)
+    assert set(vs["params"]) == {"router", "experts"}
+    got = soft.apply(vs, x, mutable=["counters"])[0]
+    idx, w = softmax_topk_route(x.reshape(24, 32),
+                                vs["params"]["router"]["kernel"], 3)
+    e = vs["params"]["experts"]
+    want, _ = held_experts_ffn(x.reshape(24, 32), idx, w, e["w1"], e["w3"],
+                               e["w2"], 2)
+    np.testing.assert_allclose(got.reshape(24, 32), want, rtol=1e-6)
+    assert float(jnp.abs(got - out).max()) > 1e-6
+    with pytest.raises(ValueError, match="sigmoid or softmax"):
+        RoutedExperts(**kw, scoring="tanh").init(jax.random.PRNGKey(0), x)
 
 
 def fit_three_steps(c, w, batches, lr=1e-3):

@@ -20,21 +20,28 @@ from harness import loader, scope_time  # noqa: E402
 LFM2 = "lfm2-8b-a1b.pretrain-s8192-b2"
 PHI = "phi-4-mini-flash.sft-s8192-b1"
 GRANITE = "granite-4.0-h-micro.sft-s8192-b1"
+QWEN = "qwen3-next-80b-a3b.sft-s8192-b1"     # PR 40: appended to the lists
 EVERY = tuple(w["name"] for w in loader.load_benchmark()["workloads"])
 # metric -> (the scopes it sums, the cells it is read in, its layer)
 READERS = {
     "unscoped_share": (("(unscoped)",), EVERY, "train step"),
-    "lm_head_loss_share": (("lm_head_loss",), (LFM2, PHI, GRANITE),
+    "lm_head_loss_share": (("lm_head_loss",), (LFM2, PHI, GRANITE, QWEN),
                            "head and loss"),
     "moe_dispatch_combine_share": (
-        ("moe_router", "moe_dispatch", "moe_combine"), (LFM2,),
+        ("moe_router", "moe_dispatch", "moe_combine"), (LFM2, QWEN),
         "expert layer"),
-    "moe_experts_share": (("moe_experts",), (LFM2,), "expert layer"),
+    "moe_experts_share": (("moe_experts",), (LFM2, QWEN), "expert layer"),
     "short_conv_share": (("short_conv",), (LFM2,), "short convolution"),
     "mamba_proj_share": (("mamba_in_proj", "mamba_out_proj"),
-                         (PHI, GRANITE), "state-space layer"),
-    "mamba_conv_share": (("mamba_conv",), (PHI, GRANITE),
+                         (PHI, GRANITE, QWEN), "state-space layer"),
+    "mamba_conv_share": (("mamba_conv",), (PHI, GRANITE, QWEN),
                          "state-space layer"),
+}
+# PR 40's by-scope readers, appended behind PR 38's seven
+LATER = {
+    "gated_delta_prep_share": (("gated_delta_prep", "gdn_gated_norm"),
+                               (QWEN,), "linear-attention layer"),
+    "moe_router_share": (("moe_router",), (QWEN, LFM2), "expert layer"),
 }
 MS = 1e6
 STEP = 100 * MS     # one step program every 100 ms; the window holds 3
@@ -129,9 +136,9 @@ def test_no_table_or_no_window_reads_none(monkeypatch, table):
     assert _read("unscoped_share", _ctx()) is None
 
 
-@pytest.mark.parametrize("metric", sorted(READERS))
+@pytest.mark.parametrize("metric", sorted({**READERS, **LATER}))
 def test_each_reader_resolves_and_is_on_its_cells(metric, monkeypatch):
-    summed, cells, layer = READERS[metric]
+    summed, cells, layer = {**READERS, **LATER}[metric]
     bench = loader.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
     assert entry == {
@@ -155,5 +162,9 @@ def test_each_reader_resolves_and_is_on_its_cells(metric, monkeypatch):
 
 def test_the_entries_are_appended_and_nothing_else_changed():
     names = [m["name"] for m in loader.load_benchmark()["per_layer"]]
-    assert names[-7:] == list(READERS)
-    assert len(names) == len(set(names)) == 27
+    assert names[20:27] == list(READERS)
+    # PR 40 appended five behind them, two of them by-scope readers
+    assert names[27:] == ["gated_delta_fwd_roofline",
+                          "gated_delta_bwd_roofline", "gated_delta_share",
+                          *LATER]
+    assert len(names) == len(set(names)) == 32
