@@ -158,35 +158,8 @@ def _permute_rows_bwd(saved, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-@jax.custom_vjp
-def _gather_tokens(h, token, inv_order, held_kn):
-    """``h[token]``: the row of ``h [N, D]`` that each of the ``k * N`` sorted
-    slots holds, out of ``h`` itself (no ``[N * k, D]`` copy of it is made).
-    ``token`` holds each of the N tokens k times, so the gradient is a sum
-    over a token's k slots: the cotangent is gathered by ``inv_order`` into
-    pick-major order (a gather, never a scatter-add), viewed ``[k, N, D]``,
-    the picks that are not held (``held_kn [k, N]`` false: dead slots, which
-    a grouped product's transpose leaves unwritten) selected away, and summed
-    over k in float32."""
-    return h[token]
-
-
-def _gather_tokens_fwd(h, token, inv_order, held_kn):
-    return h[token], (inv_order, held_kn)
-
-
-def _gather_tokens_bwd(saved, g):
-    inv_order, held_kn = saved
-    k, n = held_kn.shape
-    g_kn = jnp.where(held_kn[..., None], g[inv_order].reshape(k, n, -1), 0)
-    return (jnp.sum(g_kn.astype(jnp.float32), axis=0).astype(g.dtype),
-            None, None, None)
-
-
-_gather_tokens.defvjp(_gather_tokens_fwd, _gather_tokens_bwd)
-
-
-def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
+def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0,
+                     interpret: bool | None = None):
     """The held experts' part of a routed SwiGLU layer, and its counters.
 
     ``h [N, D]``; ``idx, w [N, k]`` from the router, over ALL experts;
@@ -195,28 +168,33 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
     a token that are held of w_e * E_e(h)``, ``[N, D]`` in ``h``'s type:
     what the absent experts would add is left out.
 
-    No token is dropped. The ``N x k`` assignments are numbered PICK-MAJOR:
-    pick ``j`` of token ``t`` is assignment ``j * N + t``, so every buffer
-    over the assignments is ``[k, N, ...]``, k contiguous ``[N, ...]`` slabs,
-    and the sum over a token's picks adds slabs. They are sorted by expert
-    (those of absent experts last; inside an expert by pick, then token),
-    each sorted slot's row is gathered once out of ``h``, and each projection
-    is ONE grouped product over the held experts' groups
-    (``jax.lax.ragged_dot``). Shapes are static: the row buffer holds all
-    ``N x k`` assignments, the worst case, and the slots past the held
-    groups (the dead slots) belong to no group.
+    No token is dropped. The ``N x k`` assignments are numbered PICK-MAJOR
+    (pick ``j`` of token ``t`` is assignment ``j * N + t``) and sorted by
+    expert (those of absent experts last; inside an expert by pick, then
+    token), and each projection is ONE grouped product over the held
+    experts' groups (``jax.lax.ragged_dot``). Shapes are static: the row
+    buffer holds all ``N x k`` assignments, the worst case; the first
+    ``n_held`` sorted slots are the held groups' and the slots past them (the
+    dead slots) belong to no group.
 
-    A grouped product neither reads nor WRITES the dead slots, going forward
-    or going backward: on the chip they hold whatever was there. What enters
-    the experts is therefore not masked, and what comes out is masked where
-    it is next read, behind the gather that takes it back to pick-major
-    order: the slot an assignment sorted into is dead exactly when the
-    assignment is not held, so there the mask is ``is_held`` viewed
-    ``[k, N]`` and fuses into the reduction that follows -- the weighted sum
-    over the picks going forward, and going backward the sum of ``h``'s
-    gradient over the picks (:func:`_gather_tokens`). Each mask is a SELECT,
-    never a product by zero: an unwritten row may hold ``inf`` or ``nan``.
+    The row movement around the products touches the ``n_held`` live slots
+    alone (``ops/moe_rows.py``, a Pallas kernel pair; ``interpret`` as
+    there), forward and backward: the dispatch gathers ``h``'s row of each
+    live slot (``moe_gather_rows``) and the combine sums each token's live
+    slots, weighted, in float32 (``moe_scatter_rows``); going backward the
+    dispatch is that sum (weight 1) and the combine the gather of ``w * g``,
+    with the weights' gradient read beside it. A grouped product neither
+    reads nor WRITES the dead slots, going forward or going backward: on the
+    chip they hold whatever was there, ``inf`` or ``nan``. No kernel reads
+    them: each walks the slots below ``n_held``, its trip count read on the
+    chip, so the dead rows of ``y`` and of the products' ``d rows`` never
+    enter a sum, and the dead rows the dispatch and the combine's gradient
+    leave are never read in their turn. What the combine's gradient gives
+    the weights of dead slots is selected away (a SELECT, never a product by
+    zero).
     """
+    from ..ops import moe_rows      # ops imports this package's sharding
+
     n, k = idx.shape
     held = w1.shape[0]
     with scopes.layer("moe_dispatch"):
@@ -228,8 +206,8 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
             jnp.int32)
         n_held = jnp.sum(group_sizes)
-        held_kn = is_held.reshape(k, n)
-        rows = _gather_tokens(h, order % n, inv_order, held_kn)
+        tok, n_live = order % n, n_held.reshape(1)
+        rows = moe_rows.dispatch(h, tok, n_live, interpret)
     with scopes.layer("moe_experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                                 preferred_element_type=h.dtype)
@@ -237,11 +215,8 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         b = dot(rows, w3.astype(h.dtype))
         y = dot(jax.nn.silu(a) * b, w2.astype(h.dtype))
     with scopes.layer("moe_combine"):
-        y = _permute_rows(y, inv_order, order).reshape(k, n, -1)
-        out = jnp.einsum(
-            "knd,kn->nd",
-            jnp.where(held_kn[..., None], y, 0).astype(jnp.float32),
-            jnp.where(held_kn, w.T, 0.0))
+        w_slot = _permute_rows(w.T.reshape(k * n), order, inv_order)
+        out = moe_rows.combine(y, w_slot, tok, n_live, n, interpret)
     sizes = group_sizes.astype(jnp.float32)
     assigned_here = jnp.sum(is_held)
     counters = {
@@ -251,7 +226,7 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0):
         "moe_held_load_mean": jnp.mean(sizes),
         # every held assignment has a row in a group: the buffer is N x k
         "moe_dropped": (assigned_here - n_held).astype(jnp.float32)}
-    return out.astype(h.dtype), counters
+    return out, counters
 
 
 class RoutedExperts(nn.Module):

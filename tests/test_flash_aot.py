@@ -449,19 +449,22 @@ def _entry_results(text: str) -> list:
     return out
 
 
-def test_the_routed_layer_moves_each_held_row_once(one_chip):
+@pytest.mark.parametrize("n,k,f,held", [(16384, 4, 1792, 8),
+                                         (8192, 10, 512, 32)],
+                         ids=["lfm2", "qwen3next"])
+def test_the_routed_layer_moves_each_held_row_once(one_chip, n, k, f, held):
     """``held_experts_ffn`` and its gradient under ``jax.checkpoint`` at the
     LFM2 cell's shape (16,384 tokens, top 4, 2048 wide, experts of 1792, 8
-    held of 32, bf16): the assignment rows are ``[65536, 2048]`` or its
-    pick-major view ``[4, 16384, 2048]``, 268 MB each. Outside the grouped
-    products, the compiled program writes such a buffer 8 times (the six
-    gathers, the sum of the two products' ``d rows``, the combine's
-    gradient; 15 times before PR 35), none of them token-major
-    (``[16384, 4, 2048]``, which the compiler tiles ``T(4,128)``: half-empty
-    registers), none in float32 and none a broadcast of ``h``. Structure,
+    held) and the Qwen3-Next cell's (8,192 tokens, top 10, experts of 512, 32
+    held), bf16: the assignment rows are ``[N k, 2048]`` (268 and 336 MB).
+    Outside the grouped products and the row kernels (``custom-call``s), the
+    compiled program writes such a buffer ONCE, the sum of the two products'
+    ``d rows`` (8 times before the row kernels: the six gathers, that sum and
+    the combine's gradient), in bf16, and never a broadcast of ``h``: every
+    other move of a row is a kernel's, over the held slots alone. Structure,
     not time: nothing runs."""
     from sparkdl_tpu.parallel.moe import held_experts_ffn
-    n, k, d, f, held = 16384, 4, 2048, 1792, 8
+    d = 2048
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -472,7 +475,7 @@ def test_the_routed_layer_moves_each_held_row_once(one_chip):
 
     @jax.checkpoint
     def layer(h, w, w1, w3, w2, idx):
-        return held_experts_ffn(h, idx, w, w1, w3, w2)[0]
+        return held_experts_ffn(h, idx, w, w1, w3, w2, interpret=False)[0]
 
     def loss(*a):
         return (layer(*a).astype(jnp.float32) ** 2).sum()
@@ -481,13 +484,114 @@ def test_the_routed_layer_moves_each_held_row_once(one_chip):
         *args).compile().as_text()
     rows = [(op, dtype, dims) for op, dtype, dims in _entry_results(text)
             if math.prod(dims) == n * k * d]
-    assert rows and {dims for _, _, dims in rows} <= {
-        (n * k, d), (k, n, d)}, rows
+    assert rows and {dims for _, _, dims in rows} == {(n * k, d)}, rows
     assert {dtype for _, dtype, _ in rows} == {"bf16"}, rows
-    written = [op for op, _, _ in rows
-               if op not in ("custom-call", "bitcast", "reshape")]
-    assert "broadcast" not in written, written
-    assert len(written) <= 8, written
+    written = [op for op, _, _ in rows if op not in (
+        "custom-call", "bitcast", "reshape", "get-tuple-element")]
+    assert written == ["add"], written
+    for name in ("moe_gather_rows", "moe_scatter_rows"):
+        assert name in text
+
+
+def _kernel_bodies(lowered_text: str) -> list:
+    """``(kernel name, its Mosaic module as serialized)`` of every
+    ``tpu_custom_call`` of a lowered program, in order."""
+    out = []
+    for line in lowered_text.splitlines():
+        if "tpu_custom_call" in line:
+            out.append((re.search(r'kernel_name = "(\w+)"', line).group(1),
+                        re.search(r'\\22body\\22: \\22([^\\]+)',
+                                  line).group(1)))
+    return out
+
+
+def test_the_row_kernels_lower_to_one_program_whatever_the_rows(one_chip):
+    """Each row kernel's Mosaic module, printed without debug locations, at
+    the LFM2 cell's 16,384 x 4 slots and the Qwen3-Next cell's 8,192 x 10
+    differs in its numbers alone (shapes and trip counts), and stays under
+    100,000 characters: the rows are walked by a loop whose trip count is
+    read on the chip, eight a step, never unrolled over a block's 1,024
+    slots (which would print one body a slot)."""
+    from sparkdl_tpu.ops import moe_rows
+    d = 2048
+
+    def modules(n, k):
+        def sd(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        tok, live = sd((n * k,), jnp.int32), sd((1,), jnp.int32)
+        w, y = sd((n * k,), jnp.float32), sd((n * k, d), jnp.bfloat16)
+        h = sd((n, d), jnp.bfloat16)
+        text = "\n".join(jax.jit(f).lower(*a).as_text() for f, a in (
+            (lambda h, t, l: moe_rows.gather_rows(h, t, l)[0], (h, tok, live)),
+            (lambda h, t, l, w, y: moe_rows.gather_rows(h, t, l, w, y),
+             (h, tok, live, w, y)),
+            (lambda y, t, l, w: moe_rows.scatter_rows(y, t, l, w, n=n),
+             (y, tok, live, w)),
+            (lambda y, t, l: moe_rows.scatter_rows(y, t, l, n=n),
+             (y, tok, live))))
+        return [re.sub(r"[0-9]+", "0", m)
+                for m in _kernel_modules_in_order(text)]
+
+    lfm2, qwen = modules(16384, 4), modules(8192, 10)
+    assert len(lfm2) == len(qwen) == 4
+    for a, b in zip(lfm2, qwen):
+        assert a == b
+        assert len(a) < 100_000, len(a)
+
+
+def _kernel_modules_in_order(lowered_text: str) -> list:
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jaxlib.mlir import ir
+    out = []
+    for _, body in _kernel_bodies(lowered_text):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            out.append(module.operation.get_asm(enable_debug_info=False))
+    return out
+
+
+def test_two_routed_layers_hold_each_row_kernel_as_one_layer_does(one_chip):
+    """The gradient of two routed layers, each under ``jax.checkpoint``,
+    lowered for the chip: each row kernel is ONE ``jax.jit`` a shape and
+    dtype, so the second layer, the recomputation and the backward call the
+    lowerings the first layer made, and the lowered program holds each
+    kernel's Mosaic body exactly as often as the one-layer program does --
+    the forward's gather once more for the recomputation, every other body
+    once. Nothing is lowered a layer."""
+    import collections
+
+    from sparkdl_tpu.parallel.moe import held_experts_ffn
+    n, k, d, f, held = 1024, 4, 256, 128, 8
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(h, w, w1, w3, w2, idx):
+        return held_experts_ffn(h, idx, w, w1, w3, w2, interpret=False)[0]
+
+    def bodies(layers):
+        def loss(h, w, experts, idx):
+            for e in experts:
+                h = layer(h, w, *e, idx)
+            return (h.astype(jnp.float32) ** 2).sum()
+        e = (sd((held, d, f), jnp.float32), sd((held, d, f), jnp.float32),
+             sd((held, f, d), jnp.float32))
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            sd((n, d), jnp.bfloat16), sd((n, k), jnp.float32),
+            (e,) * layers, sd((n, k), jnp.int32)).as_text()
+        return collections.Counter(_kernel_bodies(text))
+
+    one, two = bodies(1), bodies(2)
+    assert one == two
+    assert sorted(collections.Counter(
+        name for name, _ in one.elements()).items()) == [
+        ("moe_gather_rows", 3), ("moe_scatter_rows", 2)]
+    assert sorted(one.values()) == [1, 1, 1, 2]
 
 
 # -- the loss around the tied head (PR 39) -------------------------------------
