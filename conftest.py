@@ -1,10 +1,10 @@
-"""The tiny sizes of the cell PR 36 added, registered where pytest loads them
-whichever test file under ``benchmark/tests/`` is named. The cells before it
-are registered in ``benchmark/tests/conftest.py`` and ``benchmark/conftest.py``,
-accepted benchmark files that only a ``benchmark`` PR may edit, so this entry
-sits one directory further up (pytest reads every ``conftest.py`` from the
-root down to the test's directory). ``tiny`` imports no JAX; the tests under
-``tests/`` never read its table."""
+"""The tiny sizes of the later decoder cells (Granite, Qwen3-Next,
+SmallThinker), registered where pytest loads them whichever test file under
+``benchmark/tests/`` is named. The cells before them are registered in
+``benchmark/tests/conftest.py`` and ``benchmark/conftest.py``, accepted
+benchmark files, so these entries sit one directory further up (pytest reads
+every ``conftest.py`` from the root down to the test's directory). ``tiny``
+imports no JAX; the tests under ``tests/`` never read its table."""
 
 import os
 import sys
@@ -39,3 +39,17 @@ tiny.TINY.setdefault("qwen3-next-80b-a3b.sft-s8192-b1", {
                "gated_delta_chunk": 8},
     "traffic": {"per_chip_batch": 4, "warmup_steps": 10,
                 "inputs": {"input_ids": {"shape": [32]}}}})
+
+tiny.TINY.setdefault("smallthinker-21b-a3b.sft-s16384-b1", {
+    # 256 wide as the Granite cell's, for the same reason; a group of 7 query
+    # heads over one key/value head, the published layers 0-3 (a global NoPE
+    # layer, three RoPE layers under a window of 8), 4 of 16 experts held at
+    # the published top 6; 64 positions, not 32: at 32 the float8 control's
+    # first gradient read under the cell's limits on two of three seeds
+    "config": {"vocab_size": 256, "hidden_size": 256,
+               "num_attention_heads": 7, "num_key_value_heads": 1,
+               "head_dim": 32, "sliding_window_size": 8,
+               "moe_ffn_hidden_size": 64, "moe_num_primary_experts": 4,
+               "num_routed_experts": 16},
+    "traffic": {"per_chip_batch": 4, "warmup_steps": 10,
+                "inputs": {"input_ids": {"shape": [64]}}}})

@@ -6,6 +6,7 @@ from .llama import LlamaConfig, LlamaModel, lora_mask, lora_optimizer
 from .lm_loss import causal_lm_loss_fn
 from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
+from .smallthinker import SmallThinkerConfig, SmallThinkerForCausalLM
 from .pretrained import (CheckpointMismatch, cast_float_leaves,
                          import_hf_bert, import_hf_llama,
                          import_keras_inception, import_keras_resnet,
@@ -26,7 +27,8 @@ __all__ = [
     "LlamaConfig", "LlamaModel", "causal_lm_loss_fn", "lora_mask",
     "lora_optimizer", "Lfm2Config", "Lfm2ForCausalLM", "Phi4FlashConfig",
     "Phi4FlashForCausalLM", "GraniteHybridConfig", "GraniteHybridForCausalLM",
-    "Qwen3NextConfig", "Qwen3NextForCausalLM",
+    "Qwen3NextConfig", "Qwen3NextForCausalLM", "SmallThinkerConfig",
+    "SmallThinkerForCausalLM",
     "load_pretrained", "import_hf_llama", "import_hf_bert",
     "import_keras_resnet", "import_keras_vgg", "import_keras_inception",
     "import_keras_xception",

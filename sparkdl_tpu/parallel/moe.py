@@ -158,15 +158,20 @@ def _permute_rows_bwd(saved, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0,
-                     interpret: bool | None = None):
-    """The held experts' part of a routed SwiGLU layer, and its counters.
+                     interpret: bool | None = None, activation: str = "silu"):
+    """The held experts' part of a routed gated layer, and its counters.
 
     ``h [N, D]``; ``idx, w [N, k]`` from the router, over ALL experts;
     ``w1, w3 [H, D, F]`` and ``w2 [H, F, D]`` are the H held experts
-    ``first_held .. first_held + H - 1``. Returns ``sum over the picks e of
-    a token that are held of w_e * E_e(h)``, ``[N, D]`` in ``h``'s type:
-    what the absent experts would add is left out.
+    ``first_held .. first_held + H - 1``, each ``E_e(h) = (act(h w1) * (h
+    w3)) w2`` with ``act`` the gate's ``activation``: ``"silu"`` (SwiGLU) or
+    ``"relu"`` (ReGLU). Returns ``sum over the picks e of a token that are
+    held of w_e * E_e(h)``, ``[N, D]`` in ``h``'s type: what the absent
+    experts would add is left out.
 
     No token is dropped. The ``N x k`` assignments are numbered PICK-MAJOR
     (pick ``j`` of token ``t`` is assignment ``j * N + t``) and sorted by
@@ -192,9 +197,15 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0,
     leave are never read in their turn. What the combine's gradient gives
     the weights of dead slots is selected away (a SELECT, never a product by
     zero).
+
+    Under ``"relu"`` two counters more: ``moe_reglu_units``, the gate's
+    units on the live slots (``n_held x F``), and ``moe_reglu_active``, those
+    of them the ReLU leaves nonzero.
     """
     from ..ops import moe_rows      # ops imports this package's sharding
 
+    if activation not in _GATES:
+        raise ValueError(f"activation {activation!r}: {' or '.join(_GATES)}")
     n, k = idx.shape
     held = w1.shape[0]
     with scopes.layer("moe_dispatch"):
@@ -213,31 +224,42 @@ def held_experts_ffn(h, idx, w, w1, w3, w2, first_held: int = 0,
                                 preferred_element_type=h.dtype)
         a = dot(rows, w1.astype(h.dtype))
         b = dot(rows, w3.astype(h.dtype))
-        y = dot(jax.nn.silu(a) * b, w2.astype(h.dtype))
+        y = dot(_GATES[activation](a) * b, w2.astype(h.dtype))
+        counters = {}
+        if activation == "relu":
+            # the dead slots' rows of ``a`` hold whatever was there: a select
+            live = jnp.arange(n * k) < n_held
+            counters["moe_reglu_active"] = jnp.sum(
+                jnp.where(live[:, None], a > 0, False)).astype(jnp.float32)
+            counters["moe_reglu_units"] = (n_held * a.shape[1]).astype(
+                jnp.float32)
     with scopes.layer("moe_combine"):
         w_slot = _permute_rows(w.T.reshape(k * n), order, inv_order)
         out = moe_rows.combine(y, w_slot, tok, n_live, n, interpret)
     sizes = group_sizes.astype(jnp.float32)
     assigned_here = jnp.sum(is_held)
-    counters = {
+    counters.update({
         "moe_assignments": jnp.float32(n * k),
         "moe_assignments_held": assigned_here.astype(jnp.float32),
         "moe_held_load_max": jnp.max(sizes),
         "moe_held_load_mean": jnp.mean(sizes),
         # every held assignment has a row in a group: the buffer is N x k
-        "moe_dropped": (assigned_here - n_held).astype(jnp.float32)}
+        "moe_dropped": (assigned_here - n_held).astype(jnp.float32)})
     return out, counters
 
 
 class RoutedExperts(nn.Module):
-    """No-drop top-k routed SwiGLU experts: ``(B, T, D) -> (B, T, D)``.
+    """No-drop top-k routed gated experts: ``(B, T, D) -> (B, T, D)``.
 
     The router scores all ``num_experts`` by ``scoring``, sigmoid or softmax
-    scores (:func:`sigmoid_topk_route`, :func:`softmax_topk_route`); this
-    layer holds the experts ``held[0] .. held[0] + held[1] - 1`` (``None``:
-    all of them) and returns their part of the result
-    (:func:`held_experts_ffn`). ``expert_bias`` (sigmoid scores only) moves
-    the selection only, takes no gradient, and no rule here moves it.
+    scores (:func:`sigmoid_topk_route`, :func:`softmax_topk_route`), of
+    ``route_from`` where the call is given one (a tensor of ``x``'s shape: a
+    layer whose router reads its input before the attention) and of ``x``
+    itself where not; this layer holds the experts ``held[0] .. held[0] +
+    held[1] - 1`` (``None``: all of them) and returns their part of the
+    result (:func:`held_experts_ffn`, the gate's ``activation`` ``"silu"`` or
+    ``"relu"``). ``expert_bias`` (sigmoid scores only) moves the selection
+    only, takes no gradient, and no rule here moves it.
     Parameters: ``router/kernel``, ``expert_bias``, and ``experts/{w1,w3,w2}``
     with a leading held-experts axis (``moe_rules`` shards it over ``ep``).
     The layer's counters are summed into the ``counters`` collection.
@@ -251,15 +273,18 @@ class RoutedExperts(nn.Module):
     use_expert_bias: bool = True
     dtype: Any = jnp.float32
     scoring: str = "sigmoid"
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
         b, t, d = x.shape
         first, held = self.held or (0, self.num_experts)
         init = nn.initializers.normal(0.02)
         xf = x.reshape(b * t, d).astype(self.dtype)
+        rf = xf if route_from is None else \
+            route_from.reshape(b * t, d).astype(self.dtype)
         w_router = self.param("router", lambda k, s: {
             "kernel": init(k, s)}, (d, self.num_experts))["kernel"]
         bias = self.param("expert_bias", nn.initializers.zeros,
@@ -274,12 +299,13 @@ class RoutedExperts(nn.Module):
             how = dict(norm_topk_prob=self.norm_topk_prob,
                        scaling=self.routed_scaling_factor)
             if self.scoring == "sigmoid":
-                idx, w = sigmoid_topk_route(xf, w_router, bias, self.top_k,
+                idx, w = sigmoid_topk_route(rf, w_router, bias, self.top_k,
                                             **how)
             else:
-                idx, w = softmax_topk_route(xf, w_router, self.top_k, **how)
+                idx, w = softmax_topk_route(rf, w_router, self.top_k, **how)
         out, counters = held_experts_ffn(
-            xf, idx, w, experts["w1"], experts["w3"], experts["w2"], first)
+            xf, idx, w, experts["w1"], experts["w3"], experts["w2"], first,
+            activation=self.activation)
         for name, v in counters.items():
             self.sow("counters", name, v, init_fn=lambda: jnp.float32(0),
                      reduce_fn=lambda a, c: a + c)

@@ -199,6 +199,33 @@ def test_differential_attentions_gradient_at_the_cells_shape(one_chip,
     assert largest < b * h * s * 512, largest
 
 
+def test_a_window_of_a_quarter_of_16384_positions_at_28_heads(one_chip):
+    """28 heads of 128 at S = 16384 under a window of 4096, bf16 — the
+    SmallThinker cell's window layers (its key/value heads repeated to 28
+    before the call): forward and the backward pair compile, and the
+    innermost grid axis of all three spans the band's 9 tiles of 512, not 32.
+    Structure, not time: nothing runs."""
+    b, h, s = 1, 28, 16384
+    x = jax.ShapeDtypeStruct((b, h, s, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, window=4096,
+                                interpret=False).astype(jnp.float32)
+                ** 2).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+    grids = [re.search(r"iteration_bounds = array<i64: ([0-9, ]+)>",
+                       module).group(1)
+             for module in _kernel_modules(lowered.as_text()).values()]
+    assert grids == ["28, 32, 9"] * 3, grids
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    largest = max(
+        math.prod(map(int, dims.split(",")))
+        for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest < b * h * s * 512, largest
+
+
 def test_the_cells_scan_gradient_keeps_no_state_per_position(one_chip):
     """One sequence of 8192 positions, 5120 channels, 16 states, ``u, B, C``
     in bf16 and ``dt`` in float32 — the new cell's Mamba layer: the forward

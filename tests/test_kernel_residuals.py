@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 
 from sparkdl_tpu import ops
-from sparkdl_tpu.models import granite_hybrid, lfm2, phi4flash, qwen3_next
+from sparkdl_tpu.models import (granite_hybrid, lfm2, phi4flash, qwen3_next,
+                                smallthinker)
 from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
 from sparkdl_tpu.ops import gated_delta as delta_module
 from sparkdl_tpu.ops import selective_scan as selective_module
@@ -80,6 +81,11 @@ MODELS = {
         dataclasses.replace(qwen3_next.Qwen3NextConfig.tiny(),
                             num_hidden_layers=4), attn_fn=FLASH),
         {"flash_attention_fwd": 2, "gated_delta_fwd": 2}),
+    # global 0; under the window 1, 2, 3
+    "smallthinker": (
+        smallthinker, lambda: smallthinker.SmallThinkerForCausalLM(
+            smallthinker.SmallThinkerConfig.tiny(), attn_fn=FLASH),
+        {"flash_attention_fwd": 4}),
 }
 
 

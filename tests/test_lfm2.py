@@ -5,6 +5,7 @@ program), at ``Lfm2Config.tiny()`` on the CPU, with seeded weights and a
 nonzero ``expert_bias``."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -286,6 +287,92 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
     assert float(counters["moe_assignments_held"]) == 160.0
     assert float(counters["moe_held_load_max"]) == 40.0
     assert float(counters["moe_held_load_mean"]) == 20.0
+
+
+def dense_reglu(h, idx, w, w1, w3, w2, first=0):
+    """``dense_experts`` with a ReLU for the gate (ReGLU)."""
+    out = 0.0
+    for j in range(w1.shape[0]):
+        y = (jax.nn.relu(h @ w1[j]) * (h @ w3[j])) @ w2[j]
+        out = out + jnp.sum(jnp.where(idx == first + j, w, 0.0), -1,
+                            keepdims=True) * y
+    return out
+
+
+@pytest.mark.parametrize("first,held", [(0, 32), (8, 8)],
+                         ids=["all_experts_held", "uneven_groups_of_8_held"])
+def test_relu_experts_and_their_gradient_against_the_dense_form(first, held):
+    """``activation="relu"``: output, ``dh``, the three weights' gradients
+    and the router's against every held expert run on every token, and the
+    two ReGLU counters against a count of the live slots' gate units. Both
+    sides in float32; they differ by the order of their sums (~1e-6 of the
+    largest entry), far under bfloat16's 2^-8."""
+    wr, _, w1, w3, w2 = expert_weights(jax.random.PRNGKey(19), held=held)
+    h = jax.random.normal(jax.random.PRNGKey(20), (24, 32))
+    cot = jax.random.normal(jax.random.PRNGKey(21), h.shape)
+
+    def layer(x, router, experts, ffn):
+        idx, w = softmax_topk_route(x, router, 4)
+        out = ffn(x, idx, w, *experts, first)
+        return (out[0] if isinstance(out, tuple) else out) * cot
+
+    grad = jax.grad(lambda *a: layer(*a).sum(), argnums=(0, 1, 2))
+    relu = functools.partial(held_experts_ffn, activation="relu")
+    got = layer(h, wr, (w1, w3, w2), relu)
+    np.testing.assert_allclose(got, layer(h, wr, (w1, w3, w2), dense_reglu),
+                               rtol=2e-4, atol=1e-6)
+    silu = layer(h, wr, (w1, w3, w2), held_experts_ffn)
+    assert float(jnp.abs(got - silu).max()) > 1e-2     # another gate
+    for name, mine, theirs in zip(
+            ("h", "router", "w1", "w3", "w2"),
+            jax.tree_util.tree_leaves(grad(h, wr, (w1, w3, w2), relu)),
+            jax.tree_util.tree_leaves(grad(h, wr, (w1, w3, w2),
+                                           dense_reglu))):
+        assert np.asarray(theirs).any(), name
+        np.testing.assert_allclose(mine, theirs, rtol=2e-4, atol=2e-6,
+                                   err_msg=name)
+    idx, w = softmax_topk_route(h, wr, 4)
+    _, counters = relu(h, idx, w, w1, w3, w2, first)
+    units = active = 0
+    for t, e in zip(*np.nonzero((np.asarray(idx) >= first)
+                                & (np.asarray(idx) < first + held))):
+        gate = np.asarray(h[t] @ w1[int(idx[t, e]) - first])
+        units, active = units + gate.size, active + int((gate > 0).sum())
+    assert float(counters["moe_reglu_units"]) == units > 0
+    assert float(counters["moe_reglu_active"]) == active
+    assert 0.3 * units < active < 0.7 * units
+    _, plain = held_experts_ffn(h, idx, w, w1, w3, w2, first)
+    assert set(counters) - set(plain) == {"moe_reglu_units",
+                                          "moe_reglu_active"}
+    with pytest.raises(ValueError, match="silu or relu"):
+        held_experts_ffn(h, idx, w, w1, w3, w2, activation="tanh")
+
+
+def test_route_from_and_activation_leave_the_layer_as_it_was_by_default():
+    """``activation="silu"`` is the default and ``route_from=None`` routes on
+    ``x``: the same tree and the same output bit for bit as the layer built
+    and called without them. Given another tensor, the picks follow it and
+    the experts still compute on ``x``."""
+    x = jax.random.normal(jax.random.PRNGKey(22), (2, 12, 32))
+    r = jax.random.normal(jax.random.PRNGKey(23), (2, 12, 32))
+    kw = dict(num_experts=8, top_k=3, d_ff=16, held=(2, 4),
+              scoring="softmax")
+    plain, named = RoutedExperts(**kw), RoutedExperts(**kw, activation="silu")
+    v = plain.init(jax.random.PRNGKey(0), x)
+    assert jax.tree_util.tree_structure(v) == jax.tree_util.tree_structure(
+        named.init(jax.random.PRNGKey(0), x))
+    out = plain.apply(v, x, mutable=["counters"])[0]
+    np.testing.assert_array_equal(
+        out, named.apply(v, x, route_from=x, mutable=["counters"])[0])
+    elsewhere = plain.apply(v, x, route_from=r, mutable=["counters"])[0]
+    e = v["params"]["experts"]
+    idx, w = softmax_topk_route(r.reshape(24, 32),
+                                v["params"]["router"]["kernel"], 3)
+    want, _ = held_experts_ffn(x.reshape(24, 32), idx, w, e["w1"], e["w3"],
+                               e["w2"], 2)
+    np.testing.assert_array_equal(elsewhere.reshape(24, 32), want)
+    assert float(jnp.abs(elsewhere - out).max()) > \
+        0.1 * float(jnp.abs(out).max())
 
 
 @pytest.mark.parametrize("left", [1e4, float("nan"), float("inf")],

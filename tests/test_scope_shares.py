@@ -21,16 +21,18 @@ LFM2 = "lfm2-8b-a1b.pretrain-s8192-b2"
 PHI = "phi-4-mini-flash.sft-s8192-b1"
 GRANITE = "granite-4.0-h-micro.sft-s8192-b1"
 QWEN = "qwen3-next-80b-a3b.sft-s8192-b1"     # PR 40: appended to the lists
+ST = "smallthinker-21b-a3b.sft-s16384-b1"    # appended after QWEN
 EVERY = tuple(w["name"] for w in loader.load_benchmark()["workloads"])
 # metric -> (the scopes it sums, the cells it is read in, its layer)
 READERS = {
     "unscoped_share": (("(unscoped)",), EVERY, "train step"),
-    "lm_head_loss_share": (("lm_head_loss",), (LFM2, PHI, GRANITE, QWEN),
-                           "head and loss"),
+    "lm_head_loss_share": (("lm_head_loss",),
+                           (LFM2, PHI, GRANITE, QWEN, ST), "head and loss"),
     "moe_dispatch_combine_share": (
-        ("moe_router", "moe_dispatch", "moe_combine"), (LFM2, QWEN),
+        ("moe_router", "moe_dispatch", "moe_combine"), (LFM2, QWEN, ST),
         "expert layer"),
-    "moe_experts_share": (("moe_experts",), (LFM2, QWEN), "expert layer"),
+    "moe_experts_share": (("moe_experts",), (LFM2, QWEN, ST),
+                          "expert layer"),
     "short_conv_share": (("short_conv",), (LFM2,), "short convolution"),
     "mamba_proj_share": (("mamba_in_proj", "mamba_out_proj"),
                          (PHI, GRANITE, QWEN), "state-space layer"),
@@ -41,7 +43,10 @@ READERS = {
 LATER = {
     "gated_delta_prep_share": (("gated_delta_prep", "gdn_gated_norm"),
                                (QWEN,), "linear-attention layer"),
-    "moe_router_share": (("moe_router",), (QWEN, LFM2), "expert layer"),
+    "moe_router_share": (("moe_router",), (QWEN, LFM2, ST), "expert layer"),
+    # the SmallThinker cell's two, appended behind them
+    "attn_window_share": (("attn_window",), (ST,), "attention layer"),
+    "attn_global_share": (("attn_global",), (ST,), "attention layer"),
 }
 MS = 1e6
 STEP = 100 * MS     # one step program every 100 ms; the window holds 3
@@ -163,8 +168,8 @@ def test_each_reader_resolves_and_is_on_its_cells(metric, monkeypatch):
 def test_the_entries_are_appended_and_nothing_else_changed():
     names = [m["name"] for m in loader.load_benchmark()["per_layer"]]
     assert names[20:27] == list(READERS)
-    # PR 40 appended five behind them, two of them by-scope readers
+    # later cells appended seven behind them, four of them by-scope readers
     assert names[27:] == ["gated_delta_fwd_roofline",
                           "gated_delta_bwd_roofline", "gated_delta_share",
                           *LATER]
-    assert len(names) == len(set(names)) == 32
+    assert len(names) == len(set(names)) == 34
