@@ -1,6 +1,6 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464) in its chunked
-form: plain-jax preparation of a chunk's tiles and a Pallas TPU kernel pair
-for the recurrence across chunks.
+form: a Pallas TPU kernel pair for the preparation of a chunk's tiles and
+one for the recurrence across chunks.
 
 A value head's state ``S`` is ``[Dk, Dv]``; ``g_t <= 0`` is the log of its
 decay and ``beta_t`` the strength of the write, one number a position and
@@ -32,10 +32,14 @@ Design:
   writes ``U``, ``W`` and ``T`` in the inputs' dtype. ``T`` is the product
   ``(I - A)(I + A^2)(I + A^4)...`` (``A`` is nilpotent, so the product is
   exact after ``log2 Q`` factors): matrix products only, each at three bf16
-  passes. Going backward their gradients (to ``k``, ``v``, ``beta`` and
-  ``g``) are jax's own transposes of the plain-jax forms (:func:`_apply`,
-  :func:`_tiles`) around the inverse's cotangent ``-T^T dT T^T``. What is
-  plain jax stands under the scope ``gated_delta_prep``.
+  passes. Going backward a second kernel (``gated_delta_bwd_prep``) reads
+  ``T`` as the first wrote it, never rebuilding the inverse, and takes
+  ``dU`` and ``dW`` to ``dk``, ``dv``, ``dG`` and ``dbeta`` a chunk at a
+  time around the inverse's cotangent ``-T^T dT T^T``, every ``[Q, Q]``
+  tile in VMEM and every product at one bf16 pass. The running sum, its
+  transpose and the rest that is plain jax stand under the scope
+  ``gated_delta_prep``; :func:`_apply` and :func:`_tiles` are the two
+  kernels' plain-jax reference.
 - the recurrence is the kernel pair. grid = (batch, value-head blocks,
   chunks), the chunk axis innermost and ``arbitrary``: the ``[block_h * Dk,
   Dv]`` float32 state persists in VMEM scratch across a sequence's chunks.
@@ -82,6 +86,7 @@ from .ssd_scan import (_as_row, _causal, _decay_tile, _dot, _params,
 DEFAULT_CHUNK = 128
 DEFAULT_BLOCK_H = 8
 PREP_BLOCK_H = 4       # value heads a grid step of the preparation
+PREP_BWD_BLOCK_H = 8   # ... and of its backward (PERF.md section 5)
 _F32 = jnp.float32
 
 
@@ -100,7 +105,8 @@ def _by_chunk(t, chunk: int):
 
 def _tiles(k, gamma, beta, chunk: int):
     """``A [B, S / Q, Hv, Q, Q]`` float32, the strictly lower part of
-    ``diag(beta) K K^T * exp(G_t - G_r)``: plain jax, for its transpose."""
+    ``diag(beta) K K^T * exp(G_t - G_r)``: the preparation kernels' plain-jax
+    reference."""
     kc, gc = _by_chunk(k, chunk), _by_chunk(gamma, chunk)
     kk = jnp.repeat(jnp.einsum("bnhtd,bnhrd->bnhtr", kc, kc,
                                preferred_element_type=_F32),
@@ -113,8 +119,8 @@ def _tiles(k, gamma, beta, chunk: int):
 
 def _apply(t, k, v, gamma, beta, chunk: int):
     """``(U, W) = (T diag(beta) V, T diag(beta) (K * exp(G)))`` as ``[B, S,
-    Hv, D]`` in ``v``'s dtype, ``t [B, S / Q, Hv, Q, Q]`` given: plain jax,
-    for its transpose."""
+    Hv, D]`` in ``v``'s dtype, ``t [B, S / Q, Hv, Q, Q]`` given: the
+    preparation kernels' plain-jax reference."""
     bsz, s, hv, _ = v.shape
     gc, bc = _by_chunk(gamma, chunk), _by_chunk(beta, chunk)
     bv = (_by_chunk(v, chunk).astype(_F32) * bc[..., None]).astype(v.dtype)
@@ -184,8 +190,6 @@ def _prep_call(k, v, gamma, beta, chunk: int, interpret: bool):
     hv, dv = v.shape[2:]
     rep = hv // hk
     hb = _block_h(hv, rep, PREP_BLOCK_H)
-    rows = jnp.concatenate([_gamma_rows(t, hb) for t in (gamma, beta)],
-                           axis=2)
     call = pl.pallas_call(
         functools.partial(_prep_kernel, hb=hb, rep=rep, dk=dk, dv=dv),
         grid=(bsz, hv // hb, s // chunk),
@@ -206,15 +210,110 @@ def _prep_call(k, v, gamma, beta, chunk: int, interpret: bool):
                                         v.dtype)],
         name="gated_delta_fwd_prep", **_params(interpret))
     with scopes.layer("gated_delta_fwd_prep"):
-        u, w, t = call(_flat(k), _flat(v), rows)
+        u, w, t = call(_flat(k), _flat(v), _prep_rows(gamma, beta, hb))
     return u.reshape(bsz, s, hv, dv), w.reshape(bsz, s, hv, dk), t
+
+
+def _prep_rows(gamma, beta, hb: int):
+    """``[B, Hv / hb, 2 hb, S]``: a block's ``G`` rows, then its ``beta``."""
+    return jnp.concatenate([_gamma_rows(t, hb) for t in (gamma, beta)],
+                           axis=2)
+
+
+def _prep_bwd_kernel(k_ref, v_ref, row_ref, t_ref, du_ref, dw_ref, dk_ref,
+                     dv_ref, drow_ref, *, hb: int, rep: int, dk: int,
+                     dv: int):
+    """The transpose of :func:`_prep_kernel` for one chunk of ``hb`` value
+    heads, from the ``T`` it wrote. ``drow_ref`` takes ``dG`` in rows ``0 ..
+    hb - 1`` and ``dbeta`` in the next ``hb``, as ``row_ref`` holds ``G`` and
+    ``beta``. With ``Y = dU V^T`` and ``Z = dW K^T``, ``dT = Y diag(beta) +
+    Z diag(beta e^G)``, and the row sums of ``(T^T dU) * V`` and ``(T^T dW)
+    * K`` are the column sums of ``T * Y`` and ``T * Z``: every sum a
+    position comes out as a row but one. A head's ``dkk`` (the cotangent of
+    ``K K^T``) is summed over its key head's value heads and taken to ``dk``
+    by one product."""
+    dtype, q = k_ref.dtype, k_ref.shape[1]
+    below = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) > \
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (2 * hb, 1), 0)
+    drow = jnp.zeros((2 * hb, q), _F32)
+
+    def colsum(t):
+        return jnp.sum(t, axis=0, keepdims=True)
+
+    for jk in range(hb // rep):
+        of_key = slice(jk * dk, (jk + 1) * dk)
+        kh = k_ref[0, :, of_key]
+        kk = _dot(kh, kh, _NT)                                  # (Q, Q): t, r
+        dkk, dkey = jnp.zeros((q, q), _F32), jnp.zeros((q, dk), _F32)
+        for i in range(jk * rep, (jk + 1) * rep):
+            rows, cols = slice(i * dk, (i + 1) * dk), slice(i * dv,
+                                                            (i + 1) * dv)
+            g_row, g_col, _ = _head(row_ref, i)
+            b_row = row_ref[0, 0, hb + i:hb + i + 1, :]
+            b_col = _as_column(b_row)[:, :1]
+            e_row = jnp.exp(g_row)
+            decay = jnp.exp(jnp.where(below, g_col - g_row, -jnp.inf))
+            t, du, dw = t_ref[0, 0, i], du_ref[0, :, cols], dw_ref[0, :, rows]
+            y = _dot(du, v_ref[0, :, cols], _NT)                # dU V^T
+            z = _dot(dw, kh, _NT)                               # dW K^T
+            ndt = (y * -b_row + z * -(e_row * b_row)).astype(dtype)
+            # dA = -T^T dT T^T, strictly lower: D is 0 everywhere else
+            p = _dot(_dot(t, ndt, _TN).astype(dtype), t, _NT) * decay
+            pk = p * kk
+            dbv, dbk = _dot(t, du, _TN), _dot(t, dw, _TN)       # T^T dU, dW
+            dv_ref[0, :, cols] = (b_col * dbv).astype(dv_ref.dtype)
+            dkey = dkey + (b_col * jnp.exp(g_col)) * dbk
+            dkk = dkk + b_col * p
+            tf = t.astype(_F32)
+            r_kp = e_row * colsum(tf * z) + colsum(pk.T)
+            dg_row = b_row * r_kp - colsum(b_col * pk)
+            drow = jnp.where(sublane == i, dg_row, drow)
+            drow = jnp.where(sublane == hb + i, colsum(tf * y) + r_kp, drow)
+        dkey = dkey + _dot((dkk + dkk.T).astype(dtype), kh)
+        dk_ref[0, :, of_key] = dkey.astype(dk_ref.dtype)
+    drow_ref[0, 0] = drow
+
+
+def _prep_bwd_call(k, v, gamma, beta, t, du, dw, chunk: int,
+                   interpret: bool):
+    """``(dk, dv, dG, dbeta)`` of ``(U, W)``'s cotangents ``(du, dw)``, in
+    ``k``'s, ``v``'s and float32; ``t`` as :func:`_prep_call` wrote it."""
+    bsz, s, hk, dk = k.shape
+    hv, dv = v.shape[2:]
+    rep = hv // hk
+    hb = _block_h(hv, rep, PREP_BWD_BLOCK_H)
+    keys = pl.BlockSpec((1, chunk, hb // rep * dk), lambda b, j, c: (b, c, j))
+    values = pl.BlockSpec((1, chunk, hb * dv), lambda b, j, c: (b, c, j))
+    head_rows = pl.BlockSpec((1, 1, 2 * hb, chunk),
+                             lambda b, j, c: (b, j, 0, c))
+    call = pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, hb=hb, rep=rep, dk=dk, dv=dv),
+        grid=(bsz, hv // hb, s // chunk),
+        in_specs=[
+            keys, values, head_rows,
+            pl.BlockSpec((1, 1, hb, chunk, chunk),
+                         lambda b, j, c: (b, c, j, 0, 0)),
+            values,
+            pl.BlockSpec((1, chunk, hb * dk), lambda b, j, c: (b, c, j))],
+        out_specs=[keys, values, head_rows],
+        out_shape=[jax.ShapeDtypeStruct((bsz, s, hk * dk), k.dtype),
+                   jax.ShapeDtypeStruct((bsz, s, hv * dv), v.dtype),
+                   jax.ShapeDtypeStruct((bsz, hv // hb, 2 * hb, s), _F32)],
+        name="gated_delta_bwd_prep", **_params(interpret))
+    with scopes.layer("gated_delta_bwd_prep"):
+        dkey, dval, drow = call(_flat(k), _flat(v),
+                                _prep_rows(gamma, beta, hb), t, _flat(du),
+                                _flat(dw))
+    drow = drow.reshape(bsz, hv // hb, 2, hb, s).transpose(2, 0, 4, 1, 3)
+    return (dkey.reshape(k.shape), dval.reshape(v.shape),
+            *drow.reshape(2, bsz, s, hv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _prep(k, v, gamma, beta, chunk: int, interpret: bool):
-    """``(U, W)``: the kernel ``gated_delta_fwd_prep`` going forward; going
-    backward plain jax's transposes of :func:`_apply` and :func:`_tiles`
-    around the inverse's own cotangent, ``dA = -T^T dT T^T``."""
+    """``(U, W)``: the kernel ``gated_delta_fwd_prep`` going forward and
+    ``gated_delta_bwd_prep`` going backward."""
     return _prep_call(k, v, gamma, beta, chunk, interpret)[:2]
 
 
@@ -224,17 +323,7 @@ def _prep_fwd(k, v, gamma, beta, chunk, interpret):
 
 
 def _prep_bwd(chunk, interpret, res, cts):
-    k, v, gamma, beta, t = res
-    _, through_t = jax.vjp(functools.partial(_apply, chunk=chunk), t, k, v,
-                           gamma, beta)
-    dt, dk1, dv, dg1, db1 = through_t(cts)
-    t_t = jnp.swapaxes(t, -1, -2)
-    da = -jnp.matmul(jnp.matmul(t_t, dt, preferred_element_type=_F32).astype(
-        t.dtype), t_t, preferred_element_type=_F32)
-    _, through_a = jax.vjp(functools.partial(_tiles, chunk=chunk), k, gamma,
-                           beta)
-    dk2, dg2, db2 = through_a(da)
-    return dk1 + dk2, dv, dg1 + dg2, db1 + db2
+    return _prep_bwd_call(*res, *cts, chunk, interpret)
 
 
 _prep.defvjp(_prep_fwd, _prep_bwd)

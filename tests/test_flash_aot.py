@@ -411,13 +411,14 @@ def test_the_cells_delta_rule_gradient_hands_its_kernels_no_tile_and_no_state_a_
         one_chip):
     """One sequence of 8192 positions, 16 key and 32 value heads of 128,
     ``q, k, v`` in bf16 and ``g, beta`` in float32, chunks of 128 -- the
-    Qwen3-Next cell's linear-attention layer: the preparation's kernel and
-    the recurrence's forward and backward kernels compile at their default
-    head blocks, by name; no operand or result of the recurrence's two is a
-    ``[Q, Q]`` tile a chunk (the preparation writes ``T`` for its transpose,
-    ``S / Q * Hv * Q * Q`` elements) and the largest any of the three touches
-    is as large as the chunk-start states; no buffer of the whole gradient
-    holds a state a position (``S * Hv * Dk * Dv``)."""
+    Qwen3-Next cell's linear-attention layer: the preparation's kernel pair
+    and the recurrence's forward and backward kernels compile at their
+    default head blocks, by name; no operand or result of the recurrence's
+    two is a ``[Q, Q]`` tile a chunk (the preparation's forward writes ``T``
+    and its backward reads it, ``S / Q * Hv * Q * Q`` elements) and the
+    largest any of the four touches is as large as the chunk-start states;
+    no buffer of the whole gradient holds a state a position (``S * Hv * Dk
+    * Dv``)."""
     from sparkdl_tpu.ops.gated_delta import gated_delta_rule
     s, hk, hv, d, q = 8192, 16, 32, 128, 128
 
@@ -428,8 +429,9 @@ def test_the_cells_delta_rule_gradient_hands_its_kernels_no_tile_and_no_state_a_
     compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
         *_delta_args(one_chip, s, hk, hv, d, jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("gated_delta_fwd_prep", "gated_delta_fwd.", "gated_delta_bwd"):
+    assert text.count("tpu_custom_call") == 4
+    for name in ("gated_delta_fwd_prep", "gated_delta_fwd.",
+                 "gated_delta_bwd.", "gated_delta_bwd_prep"):
         assert name in text
     states = s // q * hv * d * d
     for line in text.splitlines():
@@ -439,7 +441,9 @@ def test_the_cells_delta_rule_gradient_hands_its_kernels_no_tile_and_no_state_a_
                   re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", line)]
         assert shapes and max(map(math.prod, shapes)) == states, line[:200]
         tiles = [x for x in shapes if x[-2:] == (q, q) and len(x) > 3]
-        assert bool(tiles) == ("gated_delta_fwd_prep" in line), line[:200]
+        assert bool(tiles) == any(
+            name in line for name in ("gated_delta_fwd_prep",
+                                      "gated_delta_bwd_prep")), line[:200]
     largest = max(
         math.prod(map(int, dims.split(",")))
         for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
@@ -455,7 +459,7 @@ def test_the_delta_rule_compiles_at_a_ragged_length_in_float32(one_chip):
         argnums=tuple(range(5)))).lower(
             *_delta_args(one_chip, 300, 4, 4, 128, jnp.float32)
     ).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 4
 
 
 # -- the routed layer's row movement (PR 35) --------------------------------------

@@ -3,6 +3,7 @@ the CPU, against the recurrence walked position by position in float32:
 forward, last state, every gradient, the hand-over between chunks. The
 kernels compiled for a described chip are in ``tests/test_flash_aot.py``."""
 
+import functools
 import math
 
 import jax
@@ -216,6 +217,60 @@ def test_the_preparation_kernel_against_a_plain_inverse(chunk):
     np.testing.assert_allclose(w, ww, rtol=1e-4, atol=1e-5)
 
 
+def plain_prep_bwd(k, v, gamma, beta, t, du, dw, chunk):
+    """``(dk, dv, dG, dbeta)`` by jax's own transposes of :func:`gd._apply`
+    and :func:`gd._tiles` around the inverse's cotangent ``-T^T dT T^T``:
+    the preparation's backward in plain jax, ``gated_delta_bwd_prep``'s
+    reference."""
+    _, through_t = jax.vjp(functools.partial(gd._apply, chunk=chunk), t, k, v,
+                           gamma, beta)
+    dt, dk1, dv, dg1, db1 = through_t((du, dw))
+    t_t = jnp.swapaxes(t, -1, -2)
+    da = -jnp.matmul(jnp.matmul(t_t, dt, preferred_element_type=jnp.float32)
+                     .astype(t.dtype), t_t,
+                     preferred_element_type=jnp.float32)
+    _, through_a = jax.vjp(functools.partial(gd._tiles, chunk=chunk), k,
+                           gamma, beta)
+    dk2, dg2, db2 = through_a(da)
+    return dk1 + dk2, dv, dg1 + dg2, db1 + db2
+
+
+PREP_BWD_SHAPES = {
+    # (S, key heads, value heads, chunk)
+    **{f"chunk-{c}-rep-{r}": (2 * c, 2, 2 * r, c)
+       for c in (8, 64, 128) for r in (1, 2)},
+    "ragged-S": (37, 2, 4, 8),
+}
+
+
+@pytest.mark.parametrize("shape", PREP_BWD_SHAPES.values(),
+                         ids=PREP_BWD_SHAPES.keys())
+def test_the_preparation_backward_kernel_against_plain_transposes(shape):
+    """``gated_delta_bwd_prep`` (through ``_prep``'s own gradient) gives the
+    four gradients plain jax's transposes give from the same ``T``; a
+    ragged ``S`` is padded as :func:`gated_delta_rule` pads it."""
+    s, hk, hv, chunk = shape
+    _, k, v, g, beta = operands(1, s, hk, hv, 16, 8, seed=s + hv,
+                                half_life=(2.0, 64.0))
+    s_pad = -(-s // chunk) * chunk
+    k, v, g, beta = (jnp.pad(x, ((0, 0), (0, s_pad - s))
+                             + ((0, 0),) * (x.ndim - 2))
+                     for x in (k, v, g, beta))
+    gamma = chunk_log_decay(g, chunk)
+    (u, w), pull = jax.vjp(lambda *a: gd._prep(*a, chunk, True), k, v, gamma,
+                           beta)
+    keys = jax.random.split(jax.random.PRNGKey(s), 2)
+    du, dw = (jax.random.normal(key, x.shape) for key, x in zip(keys, (u, w)))
+    t = gd._prep_call(k, v, gamma, beta, chunk, True)[2]
+    want = plain_prep_bwd(k, v, gamma, beta, t, du, dw, chunk)
+    for name, got, ref, x in zip(("dk", "dv", "dG", "dbeta"), pull((du, dw)),
+                                 want, (k, v, gamma, beta)):
+        assert got.shape == x.shape and got.dtype == x.dtype, name
+        np.testing.assert_allclose(got, ref, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(ref).max()),
+                                   err_msg=name)
+
+
 def test_three_bf16_passes_hold_a_float32_product():
     a, b = (jax.random.normal(jax.random.PRNGKey(i), (64, 64)) for i in (0, 1))
     want = jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
@@ -237,10 +292,12 @@ def test_the_kernels_take_no_tile_and_no_state_per_position():
     calls = [e for e in _equations(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert sorted(_name(e) for e in calls) == [
-        "gated_delta_bwd", "gated_delta_fwd", "gated_delta_fwd_prep"]
+        "gated_delta_bwd", "gated_delta_bwd_prep", "gated_delta_fwd",
+        "gated_delta_fwd_prep"]
     states = bsz * (s // chunk) * hv * dk * dv
     for e in calls:
-        if _name(e) == "gated_delta_fwd_prep":   # writes T for its transpose
+        # the preparation's pair: the forward writes T, its backward reads it
+        if _name(e) in ("gated_delta_fwd_prep", "gated_delta_bwd_prep"):
             continue
         for var in (*e.invars, *e.outvars):
             assert math.prod(var.aval.shape) <= max(states,
