@@ -29,11 +29,16 @@ Design:
   (``gated_delta_fwd_prep``) makes them with every ``[Q, Q]`` tile in VMEM
   (in plain jax each factor of the inverse was a pass over 134 MB of float32
   tiles, 9 ms a layer and pass at the cell's shape, PERF.md section 5), and
-  writes ``U``, ``W`` and ``T`` in the inputs' dtype. ``T`` is the product
-  ``(I - A)(I + A^2)(I + A^4)...`` (``A`` is nilpotent, so the product is
-  exact after ``log2 Q`` factors): matrix products only, each at three bf16
-  passes. Going backward a second kernel (``gated_delta_bwd_prep``) reads
-  ``T`` as the first wrote it, never rebuilding the inverse, and takes
+  writes ``U``, ``W`` and ``T`` in the inputs' dtype. ``T`` is built by
+  block substitution: the 16-wide diagonal blocks of ``I + A`` inverted by
+  forward substitution in float32 on the VPU, then neighbouring blocks
+  merged in pairs, ``T_21 = -T_22 A_21 T_11`` from 16 to ``Q``, each level
+  two products at three bf16 passes (18 MXU passes a head and chunk at ``Q
+  = 128``). It holds where the keys inside a chunk point the same way,
+  where a product of powers ``(I - A)(I + A^2)(I + A^4)...`` does not (the
+  powers grow as binomial sums and cancel: PERF.md section 6). Going
+  backward a second kernel (``gated_delta_bwd_prep``) reads ``T`` as the
+  first wrote it, never rebuilding the inverse, and takes
   ``dU`` and ``dW`` to ``dk``, ``dv``, ``dG`` and ``dbeta`` a chunk at a
   time around the inverse's cotangent ``-T^T dT T^T``, every ``[Q, Q]``
   tile in VMEM and every product at one bf16 pass. The running sum, its
@@ -85,8 +90,9 @@ from .ssd_scan import (_as_row, _causal, _decay_tile, _dot, _params,
 
 DEFAULT_CHUNK = 128
 DEFAULT_BLOCK_H = 8
-PREP_BLOCK_H = 4       # value heads a grid step of the preparation
+PREP_BLOCK_H = 8       # value heads a grid step of the preparation
 PREP_BWD_BLOCK_H = 8   # ... and of its backward (PERF.md section 5)
+_BLOCK = 16            # widest diagonal block of T solved by substitution
 _F32 = jnp.float32
 
 
@@ -138,25 +144,76 @@ def _apply(t, k, v, gamma, beta, chunk: int):
 def _dot3(a, b):
     """A float32 product at three bf16 passes (the MXU's ``HIGH``): inside a
     kernel a float32 product at the default precision is ONE bf16 pass, which
-    six squarings in a row would not bear."""
+    the inverse's merges would not bear."""
     ah, bh = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
     al = (a - ah.astype(_F32)).astype(jnp.bfloat16)
     bl = (b - bh.astype(_F32)).astype(jnp.bfloat16)
     return _dot(ah, bh) + _dot(ah, bl) + _dot(al, bh)
 
 
+def _diagonal_inverse(a, w: int):
+    """``(I + a_bb)^-1 - I`` of every ``w``-wide diagonal block ``a_bb`` of a
+    strictly lower ``(Q, Q)`` float32 tile, as one block-diagonal tile:
+    forward substitution in float32 on the VPU, no product on the MXU. The
+    blocks' rows lie folded onto one ``(w, Q)`` slab, block ``b``'s on its
+    own lanes, so that a step of the substitution is one multiply-add over
+    the slab for every block at once. ``w``: a power of two that divides
+    ``Q`` and the lanes of a vreg."""
+    q = a.shape[0]
+    lanes, shift = min(q, _LANES), w.bit_length() - 1
+    diagonal = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >> shift) == (
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1) >> shift)
+    p = jnp.sum(jnp.where(diagonal, a, 0.0).reshape(q // w, w, q), axis=0)
+    # every step's coefficients by one lane gather: c[k w + i, l] =
+    # a_bb[i, k] on every lane l of block b
+    at = (jax.lax.broadcasted_iota(jnp.int32, (w * w, lanes), 1) & -w) + (
+        jax.lax.broadcasted_iota(jnp.int32, (w * w, lanes), 0) >> shift)
+    stacked = jnp.concatenate([p] * w, axis=0)
+    c = jnp.concatenate([
+        jnp.take_along_axis(stacked[:, j:j + lanes], at, axis=1,
+                            mode="promise_in_bounds")
+        for j in range(0, q, lanes)], axis=1)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (w, q), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (w, q), 1) & (w - 1)
+           ).astype(_F32)
+    x = eye
+    for k in range(w - 1):
+        x = x - c[k * w:(k + 1) * w] * x[k:k + 1]   # row k is final
+    return jnp.where(diagonal, jnp.concatenate([x - eye] * (q // w), axis=0),
+                     0.0)
+
+
+def _merge(n, a, w: int):
+    """``n = T - I``, ``T`` the inverse of ``I + a``'s ``w``-wide diagonal
+    blocks, with neighbouring blocks merged in pairs: ``T_21 = -T_22 a_21
+    T_11`` for all pairs by two float32 products over the whole tile and a
+    mask (a ragged last block waits for a later level); ``w`` a power of two.
+    The products take ``n``, not ``T``: ``T_22 a_21 T_11 = P + P n_11`` with
+    ``P = a_21 + n_22 a_21``, the identity's part added exactly, so that
+    their rounding scales with ``n``."""
+    q = a.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    pair = w.bit_length()                           # log2 of a pair's width
+    lower_left = (((rows & w) != 0) & ((cols & w) == 0)
+                  & ((rows >> pair) == (cols >> pair)))
+    p = jnp.where(lower_left, a, 0.0)
+    p = p + _dot3(n, p)
+    return n - jnp.where(lower_left, p + _dot3(p, n), 0.0)
+
+
 def _unit_lower_inverse(a):
     """``(I + a)^-1`` for a strictly lower-triangular ``(Q, Q)`` float32 tile
-    in VMEM: ``(I - a)(I + a^2)(I + a^4)...``, exact once the power reaches
-    ``Q`` since ``a^Q = 0``."""
+    in VMEM: the diagonal blocks, ``_BLOCK`` wide or the widest power of two
+    that divides ``Q``, by substitution, then merged level by level up to
+    ``Q`` (three levels, six three-pass products, at ``Q = 128``)."""
     q = a.shape[0]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)).astype(_F32)
-    t, power, width = eye - a, a, 1
-    while 2 * width < q:
-        power, width = _dot3(power, power), 2 * width
-        t = t + _dot3(t, power)
-    return t
+    w = math.gcd(q, _BLOCK)
+    n = _diagonal_inverse(a, w)
+    while w < q:
+        n, w = _merge(n, a, w), 2 * w
+    return n + (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)).astype(_F32)
 
 
 def _prep_kernel(k_ref, v_ref, row_ref, u_ref, w_ref, t_ref, *, hb: int,

@@ -198,11 +198,12 @@ def test_chunk_log_decay_restarts_at_every_chunk():
     np.testing.assert_allclose(s[0, :, 1], 2 * s[0, :, 0])
 
 
-@pytest.mark.parametrize("chunk", [2, 8, 64, 128])
+@pytest.mark.parametrize("chunk", [2, 8, 48, 64, 128])
 def test_the_preparation_kernel_against_a_plain_inverse(chunk):
     """``T = (I + A)^-1`` by a library inverse, ``U = T (beta V)`` and ``W = T
-    (beta K e^G)``, a chunk and value head at a time; the kernel's product of
-    ``log2 Q`` factors is exact up to its three-pass products."""
+    (beta K e^G)``, a chunk and value head at a time; the kernel's substitution
+    and merges are exact up to their three-pass products (48: a ragged last
+    block to merge)."""
     q, k, v, g, beta = operands(1, 2 * chunk, 1, 2, 8, 8, seed=chunk,
                                 half_life=(2.0, 64.0))
     gamma = chunk_log_decay(g, chunk)
@@ -215,6 +216,83 @@ def test_the_preparation_kernel_against_a_plain_inverse(chunk):
     uu, ww = gd._apply(want, k, v, gamma, beta, chunk)
     np.testing.assert_allclose(u, uu, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(w, ww, rtol=1e-4, atol=1e-5)
+
+
+def correlated_keys(s, hk, dk, cosine, seed):
+    """Keys of unit length whose pairwise cosine is ``cosine`` on average: a
+    direction shared by a key head's positions plus noise of their own."""
+    shared, noise = (jax.random.normal(key, shape) for key, shape in zip(
+        jax.random.split(jax.random.PRNGKey(seed)),
+        ((1, 1, hk, dk), (1, s, hk, dk))))
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    return unit(math.sqrt(cosine) * unit(shared)
+                + math.sqrt(1.0 - cosine) * unit(noise))
+
+
+def largest_gap(got, want):
+    """``max |got - want| / max |want|``, the worst over ``[..., Q, D]``
+    tiles."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float((np.abs(got - want).max(axis=(-2, -1))
+                  / np.abs(want).max(axis=(-2, -1))).max())
+
+
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+@pytest.mark.parametrize("cosine", [0.3, 0.5, 0.9])
+def test_the_preparation_kernel_holds_where_keys_correlate(cosine, chunk):
+    """Keys that point the same way inside a chunk (a fine-tune's neighbours
+    do), ``beta`` up to 0.95, the cell's half-lives of 64 to 8,192
+    positions: ``T`` within 1e-4 of its largest entry of a float64 inverse,
+    and ``U``, ``W`` of what that inverse gives. A product of powers of
+    ``A`` loses every digit here: its powers grow as binomial sums."""
+    s, hk, hv, d = 2 * chunk, 1, 2, 16
+    _, _, v, g, _ = operands(1, s, hk, hv, d, d, seed=chunk,
+                             half_life=(64.0, 8192.0))
+    k = correlated_keys(s, hk, d, cosine, seed=chunk + 1)
+    beta = jax.random.uniform(jax.random.PRNGKey(chunk + 2), (1, s, hv),
+                              minval=0.5, maxval=0.95)
+    gamma = chunk_log_decay(g, chunk)
+    u, w, t = gd._prep_call(k, v, gamma, beta, chunk, True)
+    a = np.asarray(gd._tiles(k, gamma, beta, chunk), np.float64)
+    want = np.linalg.inv(np.eye(chunk) + a)
+    assert largest_gap(t, want) < 1e-4
+    uu, ww = gd._apply(jnp.asarray(want, jnp.float32), k, v, gamma, beta,
+                       chunk)
+    for got, ref in ((u, uu), (w, ww)):
+        assert largest_gap(gd._by_chunk(got, chunk),
+                           gd._by_chunk(ref, chunk)) < 1e-4
+
+
+@pytest.mark.parametrize("q, width", [(128, 16), (48, 16), (32, 16),
+                                      (128, 8)])
+def test_the_substitution_and_a_merge_invert_a_tile_of_equal_keys(q, width):
+    """Every key alike, ``beta = 0.95``, no decay: ``A = 0.95`` below the
+    diagonal, where the product form's powers reach ``C(127, 63)`` and lose
+    every digit. The diagonal blocks by substitution (exact in float32) are
+    the library's inverse of ``I + A``'s diagonal blocks, one merge level
+    that of its blocks twice as wide (a ragged last block as it stands), and
+    the whole inverse that of ``I + A``, each within 1e-4 of its largest
+    entry (1) as the kernel tests hold ``T``."""
+    a = 0.95 * jnp.tril(jnp.ones((q, q), jnp.float32), -1)
+    rows, cols = np.indices((q, q))
+
+    def inverse_of_blocks(w):
+        blocks = np.where(rows // w == cols // w, np.asarray(a, np.float64),
+                          0.0)
+        return np.linalg.inv(np.eye(q) + blocks)
+
+    n = gd._diagonal_inverse(a, width)              # T - I, as it is merged
+    np.testing.assert_allclose(n + np.eye(q), inverse_of_blocks(width),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(gd._merge(n, a, width) + np.eye(q),
+                               inverse_of_blocks(2 * width), rtol=0,
+                               atol=1e-4)
+    np.testing.assert_allclose(gd._unit_lower_inverse(a),
+                               np.linalg.inv(np.eye(q) + np.asarray(
+                                   a, np.float64)), rtol=0, atol=1e-4)
 
 
 def plain_prep_bwd(k, v, gamma, beta, t, du, dw, chunk):
