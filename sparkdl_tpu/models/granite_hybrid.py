@@ -47,12 +47,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import SAVE_KERNEL_RESIDUALS
 from ..utils import scopes
-from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
-                            dt_bias_init)
-from .llama import RMSNorm
-from .lm_loss import folded_counters
+from .decoder_parts import (RMSNorm, apply_counted,  # noqa: F401
+                            causal_taps, count, decay_mask, dense,
+                            dt_bias_init, embed, grouped_query_attention,
+                            head, layer_stack, merge_heads, split_heads)
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -143,9 +142,7 @@ class GraniteHybridMamba(nn.Module):
                     k, shp, jnp.float32, -bound, bound),
                 (taps, wide)).astype(self.dtype)
             bias = self.param("conv_bias", nn.initializers.zeros, (wide,))
-            g = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
-            xbc = jax.nn.silu(sum(kernel[j] * g[:, j:j + s]
-                                  for j in range(taps))
+            xbc = jax.nn.silu(causal_taps(xbc, kernel)
                               + bias.astype(self.dtype))
             x, b_t, c_t = jnp.split(xbc, [di, di + groups * n], axis=-1)
             dt = jax.nn.softplus(
@@ -186,25 +183,18 @@ class GraniteHybridAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.flash_attention import resolve_attn_fn
-        from ..parallel.ring_attention import dense_attention
         c = self.cfg
-        bsz, s, _ = u.shape
         h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
 
         def heads(name, n):
-            t = dense(n * hd, self.dtype, name)(u)
-            return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
+            return split_heads(dense(n * hd, self.dtype, name)(u), n)
 
         with scopes.layer("nope_attention"):
             # the attention functions scale by 1 / sqrt(d)
             q = heads("q_proj", h) * (c.attention_multiplier * math.sqrt(hd))
-            k, v = (jnp.repeat(heads(name, hkv), h // hkv, axis=1)
-                    for name in ("k_proj", "v_proj"))
-            attn = resolve_attn_fn(self.attn_fn) or dense_attention
-            o = attn(q, k, v, causal=True)
-            o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
-            return dense(c.hidden_size, self.dtype, "o_proj")(o)
+            o = grouped_query_attention(q, heads("k_proj", hkv),
+                                        heads("v_proj", hkv), self.attn_fn)
+            return dense(c.hidden_size, self.dtype, "o_proj")(merge_heads(o))
 
 
 class GraniteHybridMLP(nn.Module):
@@ -258,21 +248,10 @@ class GraniteHybridForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.cfg
-        emb = self.param("embed_tokens", lambda k, s: {
-            "embedding": nn.initializers.normal(0.02)(k, s)},
-            (c.vocab_size, c.hidden_size))["embedding"]
-        with scopes.layer("embed_tokens"):
-            x = (jnp.take(emb, ids, axis=0)
-                 * c.embedding_multiplier).astype(self.dtype)
-        layer = nn.remat(GraniteHybridDecoderLayer,
-                         policy=SAVE_KERNEL_RESIDUALS)
-        for i, l in enumerate(c.layers):
-            x = layer(c, l, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
-        x = RMSNorm(c.rms_norm_eps, name="final_layernorm")(x)
-        with scopes.layer("lm_head_loss"):
-            return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
-                              preferred_element_type=jnp.float32) \
-                / c.logits_scaling
+        x, emb = embed(self, ids, c.embedding_multiplier)
+        x = layer_stack(self, GraniteHybridDecoderLayer, x, c.layers)
+        return head(self, RMSNorm(c.rms_norm_eps, name="final_layernorm")(x),
+                    tied=emb, logits_scaling=c.logits_scaling)
 
     def apply_with_counters(self, variables, ids):
         """``fit``'s ``apply_fn``: ``(logits, counters)`` over the Mamba
@@ -282,5 +261,4 @@ class GraniteHybridForCausalLM(nn.Module):
         heads: the log of how much of a state a chunk hands on at the least
         (under -87 its ``exp`` is 0 in float32: such a head hands on nothing),
         and the range the chunked form's exponents live in."""
-        logits, mut = self.apply(variables, ids, mutable=["counters"])
-        return logits, folded_counters(mut, _FOLDS)
+        return apply_counted(self, variables, ids, _FOLDS)

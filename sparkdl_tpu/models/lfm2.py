@@ -34,10 +34,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import SAVE_KERNEL_RESIDUALS
 from ..parallel.moe import RoutedExperts
 from ..utils import scopes
-from .llama import RMSNorm
+from .decoder_parts import (MOE_FOLDS, RMSNorm, apply_counted, causal_taps,
+                            dense, embed, grouped_query_attention, head,
+                            layer_stack, merge_heads, rope_rotate_half,
+                            split_heads)
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -95,26 +97,6 @@ class Lfm2Config:
                    layer_types=(CONV, ATTENTION, CONV), num_dense_layers=1)
 
 
-def rope_rotate_half(x, theta: float, rotary_dim: int | None = None):
-    """Rotate-half RoPE over the first ``rotary_dim`` of the head's dims (all
-    of them by default); the rest pass through. ``x``: ``[B, H, S, D]``,
-    positions 0..S-1."""
-    d = rotary_dim or x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] * inv
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
-    xf = x[..., :d].astype(jnp.float32)
-    rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
-    return jnp.concatenate([(xf * cos + rot * sin).astype(x.dtype),
-                            x[..., d:]], axis=-1)
-
-
-def _dense(features: int, dtype, name: str):
-    return nn.Dense(features, use_bias=False, dtype=dtype, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
-
-
 class Lfm2ShortConv(nn.Module):
     """``[B, C, z] = split3(u W_in)``; a causal depthwise convolution of
     ``conv_L_cache`` taps over ``B * z``; ``(C * conv) W_out``."""
@@ -125,14 +107,12 @@ class Lfm2ShortConv(nn.Module):
     def __call__(self, u):
         d, taps = self.cfg.hidden_size, self.cfg.conv_L_cache
         with scopes.layer("short_conv"):
-            b, c, z = jnp.split(_dense(3 * d, self.dtype, "in_proj")(u), 3,
+            b, c, z = jnp.split(dense(3 * d, self.dtype, "in_proj")(u), 3,
                                 axis=-1)
             kernel = self.param("conv_kernel", nn.initializers.normal(0.02),
                                 (taps, d)).astype(self.dtype)
-            g = jnp.pad(b * z, ((0, 0), (taps - 1, 0), (0, 0)))
-            s = u.shape[1]
-            conv = sum(kernel[j] * g[:, j:j + s] for j in range(taps))
-            return _dense(d, self.dtype, "out_proj")(c * conv)
+            return dense(d, self.dtype, "out_proj")(
+                c * causal_taps(b * z, kernel))
 
 
 class Lfm2Attention(nn.Module):
@@ -145,27 +125,19 @@ class Lfm2Attention(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.flash_attention import resolve_attn_fn
-        from ..parallel.ring_attention import dense_attention
         c = self.cfg
-        bsz, s, _ = u.shape
         h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
 
         def heads(name, n):
-            t = _dense(n * hd, self.dtype, name)(u)
-            return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
+            return split_heads(dense(n * hd, self.dtype, name)(u), n)
 
         q, k, v = heads("q_proj", h), heads("k_proj", hkv), heads("v_proj", hkv)
         q = rope_rotate_half(RMSNorm(c.norm_eps, name="q_layernorm")(q),
                              c.rope_theta)
         k = rope_rotate_half(RMSNorm(c.norm_eps, name="k_layernorm")(k),
                              c.rope_theta)
-        # each key/value head serves h // hkv query heads
-        k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))
-        attn = resolve_attn_fn(self.attn_fn) or dense_attention
-        o = attn(q, k, v, causal=True)
-        o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
-        return _dense(c.hidden_size, self.dtype, "out_proj")(o)
+        o = grouped_query_attention(q, k, v, self.attn_fn)
+        return dense(c.hidden_size, self.dtype, "out_proj")(merge_heads(o))
 
 
 class Lfm2MLP(nn.Module):
@@ -175,9 +147,9 @@ class Lfm2MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         f = self.cfg.intermediate_size
-        gate = jax.nn.silu(_dense(f, self.dtype, "w1")(x))
-        return _dense(self.cfg.hidden_size, self.dtype, "w2")(
-            gate * _dense(f, self.dtype, "w3")(x))
+        gate = jax.nn.silu(dense(f, self.dtype, "w1")(x))
+        return dense(self.cfg.hidden_size, self.dtype, "w2")(
+            gate * dense(f, self.dtype, "w3")(x))
 
 
 class Lfm2DecoderLayer(nn.Module):
@@ -217,29 +189,16 @@ class Lfm2ForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.cfg
-        emb = self.param("embed_tokens", lambda k, s: {
-            "embedding": nn.initializers.normal(0.02)(k, s)},
-            (c.vocab_size, c.hidden_size))["embedding"]
-        with scopes.layer("embed_tokens"):
-            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(Lfm2DecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
-        for i in range(len(c.layer_types)):
-            x = layer(c, i, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
-        x = RMSNorm(c.norm_eps, name="embedding_norm")(x)
-        with scopes.layer("lm_head_loss"):
-            return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+        x, emb = embed(self, ids)
+        x = layer_stack(self, Lfm2DecoderLayer, x, range(len(c.layer_types)))
+        return head(self, RMSNorm(c.norm_eps, name="embedding_norm")(x),
+                    tied=emb)
 
     def apply_with_counters(self, variables, ids):
         """``fit``'s ``apply_fn``: ``(logits, counters)``, the counters summed
         over the expert layers (``moe_assignments``, ``moe_assignments_held``,
         ``moe_held_load_max``, ``moe_held_load_mean``, ``moe_dropped``)."""
-        logits, mut = self.apply(variables, ids, mutable=["counters"])
-        total: dict = {}
-        for path, v in jax.tree_util.tree_flatten_with_path(
-                mut.get("counters", {}))[0]:
-            total[path[-1].key] = total.get(path[-1].key, 0.0) + v
-        return logits, total
+        return apply_counted(self, variables, ids, MOE_FOLDS)
 
 
 def trainable_mask(params):

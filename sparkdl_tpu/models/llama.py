@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .lm_loss import causal_lm_loss_fn  # noqa: F401  (re-exported)
+from .decoder_parts import RMSNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,17 +71,6 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        xf = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
-        return (y * scale).astype(x.dtype)
 
 
 class QuantDense(nn.Module):

@@ -47,9 +47,9 @@ import jax.numpy as jnp
 
 from ..ops import SAVE_KERNEL_RESIDUALS
 from ..utils import scopes
-from .hybrid_common import (count, decay_mask, dense,  # noqa: F401
-                            dt_bias_init)
-from .lm_loss import folded_counters
+from .decoder_parts import (apply_counted, causal_taps,  # noqa: F401
+                            count, decay_mask, dense, dt_bias_init, embed,
+                            head, merge_heads, split_heads)
 
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
 
@@ -145,7 +145,7 @@ class Phi4FlashMamba(nn.Module):
     def __call__(self, x):
         from ..ops.selective_scan import selective_scan
         c = self.cfg
-        di, n, taps, s = c.d_inner, c.mamba_d_state, c.mamba_d_conv, x.shape[1]
+        di, n, taps = c.d_inner, c.mamba_d_state, c.mamba_d_conv
         with scopes.layer("mamba_in_proj"):
             u, z = jnp.split(dense(2 * di, self.dtype, "in_proj")(x), 2,
                              axis=-1)
@@ -156,10 +156,7 @@ class Phi4FlashMamba(nn.Module):
                     k, shp, jnp.float32, -bound, bound),
                 (taps, di)).astype(self.dtype)
             bias = self.param("conv_bias", nn.initializers.zeros, (di,))
-            g = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
-            u = jax.nn.silu(sum(kernel[j] * g[:, j:j + s]
-                                for j in range(taps))
-                            + bias.astype(self.dtype))
+            u = jax.nn.silu(causal_taps(u, kernel) + bias.astype(self.dtype))
             r, b_t, c_t = jnp.split(
                 dense(c.dt_rank + 2 * n, self.dtype, "x_proj")(u),
                 [c.dt_rank, c.dt_rank + n], axis=-1)
@@ -198,20 +195,17 @@ class Phi4FlashAttention(nn.Module):
         bsz, s, _ = x.shape
         h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         pairs, rep = h // 2, h // hkv
-
-        def heads(t, n):
-            return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
-
         scope = "cross_attention" if kind == CROSS else "diff_attention"
         with scopes.layer(scope):
             if kind == CROSS:
-                q = heads(dense(h * hd, self.dtype, "Wq")(x), h)
+                q = split_heads(dense(h * hd, self.dtype, "Wq")(x), h)
                 k, v = kv
             else:
                 q, k, v = jnp.split(
                     dense((h + 2 * hkv) * hd, self.dtype, "Wqkv")(x),
                     [h * hd, (h + hkv) * hd], axis=-1)
-                q, k, v = heads(q, h), heads(k, hkv), heads(v, hkv)
+                q, k, v = split_heads(q, h), split_heads(k, hkv), \
+                    split_heads(v, hkv)
             # query head 2j + e reads key head 2 (j // rep) + e, and both
             # heads of a pair the values [v1 ; v2] of that key/value pair
             k_all = jnp.repeat(k.reshape(bsz, hkv // 2, 2, s, hd), rep,
@@ -236,8 +230,7 @@ class Phi4FlashAttention(nn.Module):
                 "scale": jnp.ones(shp, jnp.float32)}, (2 * hd,))["scale"]
             o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                                   + c.layer_norm_eps) * scale
-            o = (o * (1.0 - lam_init)).astype(self.dtype)
-            o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
+            o = merge_heads((o * (1.0 - lam_init)).astype(self.dtype))
             return dense(c.hidden_size, self.dtype, "out_proj")(o), (k, v)
 
 
@@ -315,11 +308,7 @@ class Phi4FlashForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.cfg
-        emb = self.param("embed_tokens", lambda k, s: {
-            "embedding": nn.initializers.normal(0.02)(k, s)},
-            (c.vocab_size, c.hidden_size))["embedding"]
-        with scopes.layer("embed_tokens"):
-            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
+        x, emb = embed(self, ids)
         layer = nn.remat(Phi4FlashDecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
         handed = {}
         for i, l in enumerate(c.layers):
@@ -331,9 +320,7 @@ class Phi4FlashForCausalLM(nn.Module):
                 handed[kind] = out
         x = nn.LayerNorm(epsilon=c.layer_norm_eps, dtype=self.dtype,
                          name="final_layernorm")(x)
-        with scopes.layer("lm_head_loss"):
-            return jnp.einsum("bsd,vd->bsv", x, emb.astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+        return head(self, x, tied=emb)
 
     def apply_with_counters(self, variables, ids):
         """``fit``'s ``apply_fn``: ``(logits, counters)``. ``ssm_state_absmax``
@@ -341,5 +328,4 @@ class Phi4FlashForCausalLM(nn.Module):
         scan's numeric range), ``ssm_dt_mean`` their mean step size,
         ``diff_lambda_mean`` the mean ``lambda`` over the attention layers
         (the second map's weight, which drifts with training)."""
-        logits, mut = self.apply(variables, ids, mutable=["counters"])
-        return logits, folded_counters(mut, _FOLDS)
+        return apply_counted(self, variables, ids, _FOLDS)

@@ -43,7 +43,6 @@ like any other model::
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any
 
@@ -51,20 +50,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import SAVE_KERNEL_RESIDUALS
 from ..ops.gated_delta import DEFAULT_CHUNK
 from ..parallel.moe import RoutedExperts
 from ..utils import scopes
-from . import hybrid_common
-from .hybrid_common import count, dense
-from .lfm2 import rope_rotate_half
-from .lm_loss import folded_counters
+from .decoder_parts import (MOE_FOLDS, apply_counted, causal_taps, count,
+                            dense, embed, grouped_query_attention, head,
+                            layer_stack, merge_heads, rope_rotate_half,
+                            split_heads)
+from .decoder_parts import routed_decay_mask as decay_mask  # noqa: F401
 
 LINEAR, ATTENTION = "linear_attention", "full_attention"
-
-# weight decay on the matrices, the embedding, the head and the expert stacks
-decay_mask = functools.partial(hybrid_common.decay_mask,
-                               also=("w1", "w3", "w2"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,9 +180,7 @@ class Qwen3NextGatedDeltaNet(nn.Module):
                 "conv_kernel", lambda k, shp: jax.random.uniform(
                     k, shp, jnp.float32, -bound, bound),
                 (taps, 2 * keys + values)).astype(self.dtype)
-            padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
-            qkv = jax.nn.silu(sum(kernel[j] * padded[:, j:j + s]
-                                  for j in range(taps)))
+            qkv = jax.nn.silu(causal_taps(qkv, kernel))
         with scopes.layer("gated_delta_prep"):
             q, k, v = jnp.split(qkv, [keys, 2 * keys], axis=-1)
 
@@ -232,32 +225,25 @@ class Qwen3NextAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.flash_attention import resolve_attn_fn
-        from ..parallel.ring_attention import dense_attention
         c = self.cfg
-        bsz, s, _ = u.shape
         h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         rotary = int(hd * c.partial_rotary_factor)
 
-        def heads(t, n):
-            return t.reshape(bsz, s, n, -1).transpose(0, 2, 1, 3)
+        def heads(name, n):
+            return split_heads(dense(n * hd, self.dtype, name)(u), n)
 
         qg = dense(2 * h * hd, self.dtype, "q_proj")(u)
-        k = heads(dense(hkv * hd, self.dtype, "k_proj")(u), hkv)
-        v = heads(dense(hkv * hd, self.dtype, "v_proj")(u), hkv)
+        k, v = heads("k_proj", hkv), heads("v_proj", hkv)
         with scopes.layer("attn_gate"):
-            q, gate = jnp.split(heads(qg, h), 2, axis=-1)
+            q, gate = jnp.split(split_heads(qg, h), 2, axis=-1)
             q = rope_rotate_half(RMSNorm0(c.rms_norm_eps, name="q_norm")(q),
                                  c.rope_theta, rotary)
             k = rope_rotate_half(RMSNorm0(c.rms_norm_eps, name="k_norm")(k),
                                  c.rope_theta, rotary)
-            # each key/value head serves h // hkv query heads
-            k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))
-        attn = resolve_attn_fn(self.attn_fn) or dense_attention
-        o = attn(q, k, v, causal=True)
+        o = grouped_query_attention(q, k, v, self.attn_fn)
         with scopes.layer("attn_gate"):
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-            o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
+            o = merge_heads(o)
         return dense(c.hidden_size, self.dtype, "o_proj")(o)
 
 
@@ -310,10 +296,7 @@ class Qwen3NextDecoderLayer(nn.Module):
 
 # how the layers' readings of a counter fold into the step's one number
 _FOLDS = {"gated_delta_chunk_log_decay_min": jnp.min,
-          "gated_delta_chunk_log_decay_median": jnp.median,
-          **{name: jnp.sum for name in (
-              "moe_assignments", "moe_assignments_held", "moe_held_load_max",
-              "moe_held_load_mean", "moe_dropped")}}
+          "gated_delta_chunk_log_decay_median": jnp.median, **MOE_FOLDS}
 
 
 class Qwen3NextForCausalLM(nn.Module):
@@ -327,21 +310,9 @@ class Qwen3NextForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.cfg
-        emb = self.param("embed_tokens", lambda k, s: {
-            "embedding": nn.initializers.normal(0.02)(k, s)},
-            (c.vocab_size, c.hidden_size))["embedding"]
-        with scopes.layer("embed_tokens"):
-            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(Qwen3NextDecoderLayer, policy=SAVE_KERNEL_RESIDUALS)
-        for i, l in enumerate(c.layers):
-            x = layer(c, l, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
-        x = RMSNorm0(c.rms_norm_eps, name="norm")(x)
-        with scopes.layer("lm_head_loss"):
-            head = self.param("lm_head", lambda k, s: {
-                "kernel": nn.initializers.normal(0.02)(k, s)},
-                (c.hidden_size, c.vocab_size))["kernel"]
-            return jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+        x, _ = embed(self, ids)
+        x = layer_stack(self, Qwen3NextDecoderLayer, x, c.layers)
+        return head(self, RMSNorm0(c.rms_norm_eps, name="norm")(x))
 
     def apply_with_counters(self, variables, ids):
         """``fit``'s ``apply_fn``: ``(logits, counters)``. The five ``moe_*``
@@ -350,5 +321,4 @@ class Qwen3NextForCausalLM(nn.Module):
         negative and the median ``G`` at a chunk's end over the linear layers,
         their heads and chunks (the log of how much of a state crosses a
         chunk's boundary: at -87 and under nothing does, in float32)."""
-        logits, mut = self.apply(variables, ids, mutable=["counters"])
-        return logits, folded_counters(mut, _FOLDS)
+        return apply_counted(self, variables, ids, _FOLDS)

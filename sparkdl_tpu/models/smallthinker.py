@@ -38,24 +38,17 @@ flash kernel wrote, which is kept by name (``ops.SAVE_KERNEL_RESIDUALS``: its
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ..ops import SAVE_KERNEL_RESIDUALS
 from ..parallel.moe import RoutedExperts
 from ..utils import scopes
-from . import hybrid_common
-from .hybrid_common import dense
-from .lfm2 import rope_rotate_half
-from .llama import RMSNorm
-from .lm_loss import folded_counters
-
-# weight decay on the matrices, the embedding, the head and the expert stacks
-decay_mask = functools.partial(hybrid_common.decay_mask,
-                               also=("w1", "w3", "w2"))
+from .decoder_parts import (MOE_FOLDS, RMSNorm, apply_counted, dense, embed,
+                            grouped_query_attention, head, layer_stack,
+                            merge_heads, rope_rotate_half, split_heads)
+from .decoder_parts import routed_decay_mask as decay_mask  # noqa: F401
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,15 +137,11 @@ class SmallThinkerAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.flash_attention import resolve_attn_fn
-        from ..parallel.ring_attention import dense_attention
         c, window = self.cfg, self.cfg.window(self.l)
-        bsz, s, _ = u.shape
         h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
 
         def heads(name, n):
-            t = dense(n * hd, self.dtype, name)(u)
-            return t.reshape(bsz, s, n, hd).transpose(0, 2, 1, 3)
+            return split_heads(dense(n * hd, self.dtype, name)(u), n)
 
         with scopes.layer("attn_window" if window else "attn_global"):
             q, k, v = heads("q_proj", h), heads("k_proj", hkv), \
@@ -160,13 +149,8 @@ class SmallThinkerAttention(nn.Module):
             if c.rope(self.l):
                 q = rope_rotate_half(q, c.rope_theta)
                 k = rope_rotate_half(k, c.rope_theta)
-            # each key/value head serves h // hkv query heads
-            k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))
-            attn = resolve_attn_fn(self.attn_fn) or dense_attention
-            o = attn(q, k, v, causal=True,
-                     **({"window": window} if window else {}))
-            o = o.transpose(0, 2, 1, 3).reshape(bsz, s, h * hd)
-            return dense(c.hidden_size, self.dtype, "o_proj")(o)
+            o = grouped_query_attention(q, k, v, self.attn_fn, window)
+            return dense(c.hidden_size, self.dtype, "o_proj")(merge_heads(o))
 
 
 class SmallThinkerDecoderLayer(nn.Module):
@@ -192,10 +176,8 @@ class SmallThinkerDecoderLayer(nn.Module):
 
 
 # the routed layers' counters, summed over the layers
-_FOLDS = {name: jnp.sum for name in (
-    "moe_assignments", "moe_assignments_held", "moe_held_load_max",
-    "moe_held_load_mean", "moe_dropped", "moe_reglu_active",
-    "moe_reglu_units")}
+_FOLDS = {**MOE_FOLDS, "moe_reglu_active": jnp.sum,
+          "moe_reglu_units": jnp.sum}
 
 
 class SmallThinkerForCausalLM(nn.Module):
@@ -209,22 +191,9 @@ class SmallThinkerForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, ids):
         c = self.cfg
-        emb = self.param("embed_tokens", lambda k, s: {
-            "embedding": nn.initializers.normal(0.02)(k, s)},
-            (c.vocab_size, c.hidden_size))["embedding"]
-        with scopes.layer("embed_tokens"):
-            x = jnp.take(emb, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(SmallThinkerDecoderLayer,
-                         policy=SAVE_KERNEL_RESIDUALS)
-        for i, l in enumerate(c.layers):
-            x = layer(c, l, self.dtype, self.attn_fn, name=f"layer_{i}")(x)
-        x = RMSNorm(c.rms_norm_eps, name="norm")(x)
-        with scopes.layer("lm_head_loss"):
-            head = self.param("lm_head", lambda k, s: {
-                "kernel": nn.initializers.normal(0.02)(k, s)},
-                (c.hidden_size, c.vocab_size))["kernel"]
-            return jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+        x, _ = embed(self, ids)
+        x = layer_stack(self, SmallThinkerDecoderLayer, x, c.layers)
+        return head(self, RMSNorm(c.rms_norm_eps, name="norm")(x))
 
     def apply_with_counters(self, variables, ids):
         """``fit``'s ``apply_fn``: ``(logits, counters)``, the seven
@@ -232,5 +201,4 @@ class SmallThinkerForCausalLM(nn.Module):
         under ReLU summed over the layers (``moe_reglu_active`` over
         ``moe_reglu_units`` is the share of the live slots' gate units the
         ReLU leaves nonzero)."""
-        logits, mut = self.apply(variables, ids, mutable=["counters"])
-        return logits, folded_counters(mut, _FOLDS)
+        return apply_counted(self, variables, ids, _FOLDS)

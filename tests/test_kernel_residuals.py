@@ -18,8 +18,8 @@ import numpy as np
 import pytest
 
 from sparkdl_tpu import ops
-from sparkdl_tpu.models import (granite_hybrid, lfm2, phi4flash, qwen3_next,
-                                smallthinker)
+from sparkdl_tpu.models import (decoder_parts, granite_hybrid, lfm2,
+                                phi4flash, qwen3_next, smallthinker)
 from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
 from sparkdl_tpu.ops import gated_delta as delta_module
 from sparkdl_tpu.ops import selective_scan as selective_module
@@ -54,9 +54,10 @@ def same_bits(a, b):
             np.asarray(x), np.asarray(y), err_msg=jax.tree_util.keystr(path))
 
 
-# model, its module, (kernel, layers that call it) at the tiny size
+# model, the module whose ``nn.remat`` reads the policy (Phi keeps its own
+# loop over the layers), (kernel, layers that call it) at the tiny size
 MODELS = {
-    "lfm2": (lfm2, lambda: lfm2.Lfm2ForCausalLM(
+    "lfm2": (decoder_parts, lambda: lfm2.Lfm2ForCausalLM(
         lfm2.Lfm2Config.tiny(), attn_fn=FLASH), {"flash_attention_fwd": 1}),
     "phi4flash": (phi4flash, lambda: phi4flash.Phi4FlashForCausalLM(
         phi4flash.Phi4FlashConfig(
@@ -67,7 +68,7 @@ MODELS = {
         # Mamba 0, 2, 4; window 1, 3; full 5; gated memory 6; cross 7
         {"flash_attention_fwd": 4, "selective_scan_fwd": 3}),
     "granite_hybrid": (
-        granite_hybrid, lambda: granite_hybrid.GraniteHybridForCausalLM(
+        decoder_parts, lambda: granite_hybrid.GraniteHybridForCausalLM(
             granite_hybrid.GraniteHybridConfig(
                 vocab_size=96, hidden_size=32, shared_intermediate_size=48,
                 num_attention_heads=4, num_key_value_heads=2,
@@ -77,13 +78,13 @@ MODELS = {
         {"flash_attention_fwd": 1, "ssd_scan_fwd": 3}),
     # linear 0, 2; full 1, 3. (The preparation's kernel, whose outputs are
     # not kept, runs again with the rest of the layer: it is no recurrence.)
-    "qwen3_next": (qwen3_next, lambda: qwen3_next.Qwen3NextForCausalLM(
+    "qwen3_next": (decoder_parts, lambda: qwen3_next.Qwen3NextForCausalLM(
         dataclasses.replace(qwen3_next.Qwen3NextConfig.tiny(),
                             num_hidden_layers=4), attn_fn=FLASH),
         {"flash_attention_fwd": 2, "gated_delta_fwd": 2}),
     # global 0; under the window 1, 2, 3
     "smallthinker": (
-        smallthinker, lambda: smallthinker.SmallThinkerForCausalLM(
+        decoder_parts, lambda: smallthinker.SmallThinkerForCausalLM(
             smallthinker.SmallThinkerConfig.tiny(), attn_fn=FLASH),
         {"flash_attention_fwd": 4}),
 }

@@ -373,8 +373,8 @@ class TestFSDP:
         produce the same updated params as an unsharded single-device
         step — the sharding is residency layout, not math."""
         import optax
-        from sparkdl_tpu.models.llama import (LlamaConfig, LlamaModel,
-                                              causal_lm_loss_fn)
+        from sparkdl_tpu.models.llama import LlamaConfig, LlamaModel
+        from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
         from sparkdl_tpu.runner import TrainState, make_train_step
 
         mesh = runtime.make_mesh({"data": 4, "model": 2})

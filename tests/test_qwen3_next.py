@@ -307,18 +307,6 @@ def test_layers_kept_goes_by_the_published_index():
                                **TOL)
 
 
-def test_rope_turns_the_leading_quarter_of_a_head_and_no_more():
-    from sparkdl_tpu.models.lfm2 import rope_rotate_half
-    x = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 6, 16))
-    part = rope_rotate_half(x, 1e4, 4)
-    np.testing.assert_array_equal(part[..., 4:], x[..., 4:])
-    np.testing.assert_allclose(part[..., :4],
-                               rope_rotate_half(x[..., :4], 1e4), rtol=1e-6)
-    assert float(jnp.abs(part[:, :, 1:, :4] - x[:, :, 1:, :4]).max()) > 1e-2
-    np.testing.assert_array_equal(rope_rotate_half(x, 1e4, 16),
-                                  rope_rotate_half(x, 1e4))
-
-
 def test_the_seeded_states_halve_in_64_to_8192_positions():
     cfg, w = seeded(TINY, seed=7)
     for i, l in enumerate(TINY.layers):

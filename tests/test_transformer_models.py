@@ -13,9 +13,9 @@ from sparkdl_tpu.core import runtime
 from sparkdl_tpu.models.bert import (BertConfig, BertEncoder,
                                      BertForSequenceClassification,
                                      glue_loss_fn)
-from sparkdl_tpu.models.llama import (LlamaConfig, LlamaModel,
-                                      causal_lm_loss_fn, lora_mask,
+from sparkdl_tpu.models.llama import (LlamaConfig, LlamaModel, lora_mask,
                                       lora_optimizer)
+from sparkdl_tpu.models.lm_loss import causal_lm_loss_fn
 from sparkdl_tpu.parallel import (lora_rules, ring_attention, shard_params,
                                   transformer_tp_rules)
 from sparkdl_tpu.runner import TrainState, XlaRunner
